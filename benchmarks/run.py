@@ -29,6 +29,8 @@ def main() -> None:
                          "JSON file (CI uploads it as the perf-trajectory "
                          "artifact)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from . import (accuracy_parity, breakdown, e2e_speedup, embedding_cache,
                    embedding_host, embedding_sensitivity, mlp_quant,

@@ -34,11 +34,11 @@ import time
 
 import numpy as np
 import jax
-import jax.numpy as jnp
 
 from repro.configs import ctr_spec
 from repro.data.synthetic import CRITEO, zipf_ids
 from repro.embedding import CachedStore, HostBackedStore
+from repro.kernels.multi_table_lookup import pack_rows
 from repro.models.ctr import CTR_MODELS
 from repro.serving import (BucketedBatch, InferenceEngine, ServingRuntime,
                            SyntheticTrainer, TimeoutBatch)
@@ -114,13 +114,13 @@ def _cell(store_kind: str, row_dtype: str | None, n_requests: int,
     # dedupe rule), then served through a cold engine of the same tier
     ref_params = ref_model.init(jax.random.PRNGKey(0))
     key = ref_model.main_embedding_key
-    table = np.array(ref_params[key]["mega_table"])
+    table = np.array(ref_model.embedding.dense_view(ref_params[key]))
     replay = trainer.replay()
     while (batch := replay.next_batch()) is not None:
         b_ids, b_rows = batch
         table[b_ids] = b_rows
     ref_params = dict(ref_params)
-    ref_params[key] = {**ref_params[key], "mega_table": jnp.asarray(table)}
+    ref_params[key] = {**ref_params[key], "mega_table": pack_rows(table)}
     ref_store = (None if row_dtype is None else
                  _make_store(store_kind, espec,
                              capacity=max(64, max_field // 50),

@@ -1,9 +1,12 @@
 """Roofline assembly: three terms per (arch × shape × mesh) from the
 compiled dry-run artifact + the analytic model.
 
-  compute_s    = corrected per-device dot FLOPs / 197 TF/s
-  memory_s     = analytic per-device HBM bytes / 819 GB/s
-  collective_s = corrected per-device collective bytes / 50 GB/s per link
+  compute_s    = corrected per-device dot FLOPs / peak bf16 FLOP/s
+  memory_s     = analytic per-device HBM bytes / HBM bandwidth
+  collective_s = corrected per-device collective bytes / per-link ICI
+
+with the chip's peaks from ``hw.CHIPS`` (v5e: 197 TF/s, 819 GB/s,
+50 GB/s per link).
 
 Corrected = loop-trip multiplied (repro.analysis.hlo_parse); raw
 cost_analysis numbers are reported alongside for transparency. The
@@ -75,25 +78,29 @@ class RooflineReport:
 
 
 def analyze_cell(arch: str, shape: str, mesh_name: str, chips: int,
-                 compiled, n_micro: int = 1) -> RooflineReport:
+                 compiled, n_micro: int = 1,
+                 device_kind: str = "TPU v5 lite") -> RooflineReport:
+    """Roofline terms of one compiled cell against the peaks of
+    ``device_kind`` (``hw.CHIPS``; default: the v5e the dry runs
+    target)."""
+    peaks = hw.chip(device_kind)
     text = compiled.as_text()
     stats = parse_hlo(text)
-    from repro.compat import cost_analysis
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     ma = compiled.memory_analysis()
     an = analytic_cost(arch, shape, chips, n_micro)
 
-    compute_s = stats.dot_flops / hw.PEAK_FLOPS_BF16
-    memory_s = an.hbm_bytes_per_device / hw.HBM_BW
-    collective_s = stats.total_collective_bytes / hw.ICI_BW_PER_LINK
+    compute_s = stats.dot_flops / peaks.peak_flops_bf16
+    memory_s = an.hbm_bytes_per_device / peaks.hbm_bw
+    collective_s = stats.total_collective_bytes / peaks.ici_bw_per_link
 
     # int8 twin: matmuls at the doubled MXU peak, weight reads at ~1/4 the
     # bytes (the only HBM component quantized compute shrinks — activations
     # and embedding gathers are unchanged by the matmul dtype)
     w_read = float(an.components.get("weights_read", 0.0))
     hbm_int8 = an.hbm_bytes_per_device - 0.75 * w_read
-    compute_s_int8 = stats.dot_flops / hw.PEAK_OPS_INT8
-    memory_s_int8 = hbm_int8 / hw.HBM_BW
+    compute_s_int8 = stats.dot_flops / peaks.peak_ops_int8
+    memory_s_int8 = hbm_int8 / peaks.hbm_bw
     ai = (stats.dot_flops / an.hbm_bytes_per_device
           if an.hbm_bytes_per_device else 0.0)
     ai_int8 = stats.dot_flops / hbm_int8 if hbm_int8 else 0.0
@@ -122,7 +129,7 @@ def analyze_cell(arch: str, shape: str, mesh_name: str, chips: int,
         arg_bytes=ma.argument_size_in_bytes,
         temp_bytes=ma.temp_size_in_bytes,
         out_bytes=ma.output_size_in_bytes,
-        fits_hbm=live <= hw.HBM_BYTES,
+        fits_hbm=live <= peaks.hbm_bytes,
         n_micro=n_micro,
         compute_s_int8=compute_s_int8,
         memory_s_int8=memory_s_int8,

@@ -1,64 +1,19 @@
-"""Version-tolerance shims over the moving parts of the jax API.
+"""Mesh construction for the installed JAX (0.9.0).
 
-The repo targets the jax/Pallas toolchain baked into its image, but jax has
-renamed two surfaces this code relies on:
-
-* ``shard_map`` lives at ``jax.shard_map`` on new releases and at
-  ``jax.experimental.shard_map.shard_map`` on older ones, and its
-  replication-check kwarg was renamed ``check_rep`` → ``check_vma``.
-* ``jax.make_mesh`` grew an ``axis_types`` kwarg (with
-  ``jax.sharding.AxisType``) that older releases reject.
-
-Import from here instead of from jax directly; call sites may use either
-kwarg spelling and it is translated to whatever the installed jax accepts.
+``jax.make_mesh`` defaults to ``Explicit`` axis types there. The serving
+mesh path places tables with ``NamedSharding`` and lets GSPMD partition the
+program (``core/plan.py``), which needs ``Auto`` axes: build meshes here.
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
 
-__all__ = ["shard_map", "make_mesh", "cost_analysis"]
-
-try:                                      # jax >= 0.6
-    from jax import shard_map as _shard_map
-except ImportError:                       # older: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f, **kwargs):
-    """``shard_map(f, mesh=..., in_specs=..., out_specs=..., ...)``.
-
-    Accepts both ``check_vma`` (new) and ``check_rep`` (old) and forwards
-    the one the installed jax understands; drops the flag entirely if
-    neither name exists.
-    """
-    for ours, theirs in (("check_vma", "check_rep"),
-                         ("check_rep", "check_vma")):
-        if ours in kwargs and ours not in _SHARD_MAP_PARAMS:
-            val = kwargs.pop(ours)
-            if theirs in _SHARD_MAP_PARAMS:
-                kwargs.setdefault(theirs, val)
-    return _shard_map(f, **kwargs)
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict on every jax version
-    (older releases return a one-element list of per-program dicts)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+__all__ = ["make_mesh"]
 
 
 def make_mesh(axis_shapes, axis_names) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with ``Auto`` axis types when this jax has them."""
-    kwargs = {}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if (axis_type is not None and
-            "axis_types" in inspect.signature(jax.make_mesh).parameters):
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with ``Auto`` axis types."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))

@@ -126,6 +126,10 @@ class InferencePlan:
     #: slot_of_row replicated). A mesh-aware refresh MUST republish fresh
     #: tensors placed to exactly these.
     runtime_shardings: dict = dataclasses.field(default_factory=dict)
+    #: the AOT ``jax.stages.Compiled`` step at level "dual" (None at the
+    #: eager levels): ``as_text()`` / ``memory_analysis()`` of the program
+    #: every call runs
+    executable: Any = None
 
     @property
     def level(self) -> str:
@@ -318,13 +322,18 @@ def compile_plan(model, params: Any, level: str = "dual",
         rt_spec = {k: jax.ShapeDtypeStruct(v.shape, v.dtype,
                                            sharding=rt_shardings.get(k))
                    for k, v in runtime.items()}
-        compiled = step_env.lower(spec, rt_spec).compile()
+        from repro.kernels.ops import mesh_context
+        with mesh_context(mesh, model_axis):
+            compiled = step_env.lower(spec, rt_spec).compile()
+        executable = compiled
 
         def step(ids: jax.Array) -> jax.Array:
             return compiled(bind_inputs(ids), bind_runtime())
     else:
         # eager levels dispatch op-by-op on purpose; warm every per-op jit
         # so serving latency never includes compiles
+        executable = None
+
         def step(ids: jax.Array) -> jax.Array:
             return step_env(bind_inputs(ids), bind_runtime())
         jax.block_until_ready(
@@ -342,4 +351,5 @@ def compile_plan(model, params: Any, level: str = "dual",
                          donate=donate, compile_ms=compile_ms,
                          runtime_inputs=tuple(sorted(runtime)),
                          mesh=mesh, input_shardings=in_shardings,
-                         runtime_shardings=rt_shardings)
+                         runtime_shardings=rt_shardings,
+                         executable=executable)

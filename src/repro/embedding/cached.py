@@ -4,6 +4,8 @@ Two tiers, one index map:
 
   ``backing``     (rows, d)  the full mega-table (conceptually host/HBM).
   ``cache``       (C, d)     device-resident copies of the C hottest rows.
+                             (Both packed lane-dense, like every device
+                             table — ``EmbeddingStore.unpack``.)
   ``slot_of_row`` (rows,)    int32 index map: cache slot of each global
                              row, -1 when the row is not cached.
 
@@ -48,6 +50,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import quant
 from repro.kernels import ops as kops
+from repro.kernels.multi_table_lookup import pack_rows, set_rows
 
 from .spec import FusedEmbeddingSpec
 from .store import EmbeddingStore, validate_deltas
@@ -95,6 +98,8 @@ class CachedStore(EmbeddingStore):
             # refresh republishes them through the same recompile-free swap
             self.runtime_keys = ("cache", "cache_scale", "backing",
                                  "backing_scale", "slot_of_row")
+            self.mesh_leaves = ("cache", "slot_of_row", "cache_scale",
+                                "backing_scale", "backing")
 
     def _seed_map(self) -> np.ndarray:
         m = np.full(self.spec.rows, -1, dtype=np.int32)
@@ -103,7 +108,8 @@ class CachedStore(EmbeddingStore):
 
     # -- params ------------------------------------------------------------
     def init(self, key: jax.Array) -> dict:
-        return self.from_dense({"mega_table": self.init_dense_table(key)})
+        return self.from_dense(
+            {"mega_table": pack_rows(self.init_dense_table(key))})
 
     def from_dense(self, dense_params: dict) -> dict:
         """Adopt a DenseStore subtree (``{"mega_table": table}``) into the
@@ -121,17 +127,19 @@ class CachedStore(EmbeddingStore):
         if "backing" not in params:
             return self.from_dense(params)
         backing = params["backing"]
-        if self.quantized and backing.dtype != jnp.int8:
+        if self.quantized and backing.dtype == jnp.float32:
             backing, backing_scale = self._quantize_table(backing)
         else:
             backing_scale = params.get("backing_scale")
         return self._with_cache(backing, self._slot_of_row, backing_scale)
 
-    def _quantize_table(self, table: jax.Array
+    def _quantize_table(self, lines: jax.Array
                         ) -> tuple[jax.Array, jax.Array]:
+        """Packed fp32 table -> (packed int8 table, (rows, 1) scales)."""
+        table = lines.reshape(-1, self.spec.dim)
         q, scale = quant.quantize_rows(table)
         self.stats.quant_rows += int(table.shape[0])
-        return q, scale
+        return pack_rows(q), scale
 
     def _with_cache(self, backing: jax.Array, slot_of_row: np.ndarray,
                     backing_scale: jax.Array | None = None) -> dict:
@@ -142,7 +150,7 @@ class CachedStore(EmbeddingStore):
                              f"capacity is {self.capacity}")
         rows = jnp.asarray(cached_rows)
         out = {"backing": backing,
-               "cache": jnp.take(backing, rows, axis=0),
+               "cache": pack_rows(self.gather_rows(backing, rows)),
                "slot_of_row": jnp.asarray(slot_of_row)}
         if self.quantized:
             if backing_scale is None:
@@ -169,9 +177,9 @@ class CachedStore(EmbeddingStore):
             # the naive level / serial baselines want fp32 rows — rebuild
             # them from the int8 grid so every path sees identical values
             return quant.dequantize_rows(
-                params["backing"], params["backing_scale"]).astype(
-                    jnp.dtype(self.spec.dtype))
-        return params["backing"]
+                self.unpack(params["backing"]),
+                params["backing_scale"]).astype(jnp.dtype(self.spec.dtype))
+        return self.unpack(params["backing"])
 
     # -- lookup ------------------------------------------------------------
     def lookup(self, params: dict, ids: jax.Array, offsets: jax.Array, *,
@@ -182,10 +190,11 @@ class CachedStore(EmbeddingStore):
                 ids, params["cache"], params["cache_scale"],
                 params["backing"], params["backing_scale"],
                 params["slot_of_row"], offsets,
-                strategy=strategy, interpret=interpret)
+                strategy=strategy, interpret=interpret, dim=self.spec.dim)
         return kops.multi_table_lookup_cached(
             ids, params["cache"], params["backing"], params["slot_of_row"],
-            offsets, strategy=strategy, interpret=interpret)
+            offsets, strategy=strategy, interpret=interpret,
+            dim=self.spec.dim)
 
     def lookup_multihot(self, params: dict, ids: jax.Array, mask: jax.Array,
                         offsets: jax.Array, *, strategy: str = "auto",
@@ -195,11 +204,29 @@ class CachedStore(EmbeddingStore):
                 ids, mask, params["cache"], params["cache_scale"],
                 params["backing"], params["backing_scale"],
                 params["slot_of_row"], offsets,
-                strategy=strategy, interpret=interpret)
+                strategy=strategy, interpret=interpret, dim=self.spec.dim)
         return kops.multi_table_lookup_cached_multihot(
             ids, mask, params["cache"], params["backing"],
             params["slot_of_row"], offsets,
-            strategy=strategy, interpret=interpret)
+            strategy=strategy, interpret=interpret, dim=self.spec.dim)
+
+    mesh_leaves = ("cache", "slot_of_row", "backing")
+
+    def _route(self, env, rows, local, own, first):
+        # hits: the replicated cache, served by model shard 0 only;
+        # misses: the backing row, served by the shard holding it
+        slots = jnp.take(env["slot_of_row"], rows, axis=0)
+        hit = slots >= 0
+        tier = jnp.where(hit, jnp.where(first, 0, -1),
+                         jnp.where(own, 1, -1))
+        row = jnp.where(hit, slots, local)
+        scale = None
+        if self.quantized:
+            scale = jnp.where(
+                hit,
+                jnp.take(env["cache_scale"][:, 0], jnp.maximum(slots, 0)),
+                jnp.take(env["backing_scale"][:, 0], rows))
+        return tier, row, (env["cache"], env["backing"]), scale
 
     # -- traffic / cache management ---------------------------------------
     def observe(self, global_rows: np.ndarray) -> None:
@@ -251,19 +278,18 @@ class CachedStore(EmbeddingStore):
         if self.quantized:
             q, scale = quant.quantize_rows(np.asarray(vals))
             self.stats.quant_rows += n
-            wire = jnp.asarray(q)
-            out["backing"] = params["backing"].at[idx].set(wire)
+            wire = q
             out["backing_scale"] = \
                 params["backing_scale"].at[idx].set(jnp.asarray(scale))
         else:
-            wire = jnp.asarray(vals)
-            out["backing"] = params["backing"].at[idx].set(wire)
+            wire = np.asarray(vals)
+        out["backing"] = set_rows(params["backing"], rows_idx, wire)
         slots = self._slot_of_row[rows_idx]
         cached = np.flatnonzero(slots >= 0)
         if cached.size:
             cidx = jnp.asarray(slots[cached])
-            out["cache"] = params["cache"].at[cidx].set(
-                wire[jnp.asarray(cached)])
+            out["cache"] = set_rows(params["cache"], slots[cached],
+                                    wire[cached])
             if self.quantized:
                 out["cache_scale"] = params["cache_scale"].at[cidx].set(
                     jnp.asarray(scale[cached]))

@@ -12,10 +12,12 @@ backing table. The collection delegates parameter init/placement and every
 lookup to its store, so models, plans, and engines never see the tiers.
 
 Distribution: the dense table (or the cached store's backing tier) is
-*row-sharded* over the ``model`` mesh axis (vocab-parallel).
-``apply_sharded`` performs the masked-local-gather + psum pattern under
-``shard_map`` — the multi-chip generalization of Alg. 1; the same helper
-serves LM vocab embeddings (a 1-table degenerate case).
+*row-sharded* over the ``model`` mesh axis (vocab-parallel). Inside a mesh
+plan, :meth:`FusedEmbeddingCollection.apply` runs the store's
+``lookup_sharded``: the masked-local-gather + psum pattern under
+``shard_map`` — the multi-chip generalization of Alg. 1.
+:func:`sharded_vocab_lookup` is the same pattern for LM vocab embeddings
+(a 1-table degenerate case).
 """
 
 from __future__ import annotations
@@ -25,9 +27,6 @@ import dataclasses
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro.kernels import ops as kops
 
@@ -69,7 +68,15 @@ class FusedEmbeddingCollection:
     def apply(self, params: dict, ids: jax.Array, *,
               strategy: str = "auto", interpret: bool | None = None
               ) -> jax.Array:
-        """ids (b, k) -> (b, k*d)."""
+        """ids (b, k) -> (b, k*d). Inside ``kops.mesh_context`` (a plan
+        traced for a mesh with a model axis), stores with a vocab-parallel
+        lookup run it."""
+        ctx = kops.active_mesh()
+        if (ctx is not None and self.store.mesh_leaves
+                and ctx[1] in ctx[0].axis_names):
+            return self.store.lookup_sharded(params, ids, self._offsets,
+                                             *ctx, strategy=strategy,
+                                             interpret=interpret)
         return self.store.lookup(params, ids, self._offsets,
                                  strategy=strategy, interpret=interpret)
 
@@ -95,41 +102,6 @@ class FusedEmbeddingCollection:
         if ids.ndim == 1:
             ids = ids[None, :]
         self.store.observe(ids + self.spec.offsets[None, :])
-
-    # -- distributed lookup --------------------------------------------------
-    def apply_sharded(self, params: dict, ids: jax.Array, mesh: jax.sharding.Mesh,
-                      *, model_axis: str = "model",
-                      batch_axes: tuple[str, ...] = ("data",)) -> jax.Array:
-        """Vocab-parallel fused lookup over the row-sharded dense tier.
-
-        Each shard gathers locally (out-of-range rows masked to 0) and the
-        partial results are summed over the model axis — one psum replaces
-        k independent lookups' worth of gather traffic.
-        """
-        b, k = ids.shape
-        d = self.spec.dim
-        global_rows = (ids.astype(jnp.int32) + self._offsets[None, :])
-
-        def _local(rows, table):
-            axis_idx = jax.lax.axis_index(model_axis)
-            shard_rows = table.shape[0]
-            lo = axis_idx * shard_rows
-            local = rows - lo
-            valid = (local >= 0) & (local < shard_rows)
-            safe = jnp.where(valid, local, 0)
-            vals = jnp.take(table, safe.reshape(-1), axis=0)
-            vals = vals.reshape(*rows.shape, d)
-            vals = jnp.where(valid[..., None], vals, 0)
-            return jax.lax.psum(vals, axis_name=model_axis)
-
-        baxis = batch_axes if len(batch_axes) > 1 else batch_axes[0]
-        fn = shard_map(
-            _local, mesh=mesh,
-            in_specs=(P(baxis, None), P(model_axis, None)),
-            out_specs=P(baxis, None, None),
-            check_vma=False)
-        out = fn(global_rows, self.store.dense_view(params))
-        return out.reshape(b, k * d)
 
 
 def sharded_vocab_lookup(table: jax.Array, ids: jax.Array, *,

@@ -5,7 +5,8 @@ device memory, so the largest servable vocabulary is bounded by one chip's
 HBM. This store removes that ceiling — the HugeCTR hierarchical parameter
 server (arXiv:2210.08804 / 2210.08803) brought to the DPIFrame stack:
 
-  device   ``cache``       (C, d)   hot-row copies (admission-managed)
+  device   (tables packed lane-dense, see ``EmbeddingStore.unpack``)
+           ``cache``       (C, d)   hot-row copies (admission-managed)
            ``slot_of_row`` (rows,)  int32 cache map, -1 = uncached
            ``staging``     (S, d)   per-batch copies of this batch's misses
            ``staging_slot_of_row`` (rows,) int32 staging map, -1 = unstaged
@@ -45,6 +46,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import quant
 from repro.kernels import ops as kops
+from repro.kernels.multi_table_lookup import pack_rows, set_rows, unpack_rows
 
 from .prefetch import PrefetchPipeline, StagingOverflowError
 from .spec import FusedEmbeddingSpec
@@ -228,11 +230,14 @@ class HostBackedStore(EmbeddingStore):
                              "('backing') subtree — a host-backed subtree "
                              "has no table to adopt; use open()")
         leaf = np.asarray(leaf)
-        if leaf.dtype == np.int8 and "backing_scale" in params:
+        if "backing_scale" in params and leaf.dtype != np.float32:
             # an already-quantized cached subtree: reconstitute fp rows so
             # _set_backing canonicalizes (and re-quantizes on-grid values)
-            leaf = quant.dequantize_rows(leaf,
-                                         np.asarray(params["backing_scale"]))
+            leaf = quant.dequantize_rows(
+                unpack_rows(leaf, self.spec.dim, np.int8),
+                np.asarray(params["backing_scale"]))
+        else:
+            leaf = unpack_rows(leaf, self.spec.dim, self.spec.dtype)
         self._set_backing(leaf)
         return self.device_params()
 
@@ -246,7 +251,7 @@ class HostBackedStore(EmbeddingStore):
         if cached_rows.size != self.capacity:
             raise ValueError(f"index map holds {cached_rows.size} slots, "
                              f"capacity is {self.capacity}")
-        out = {"cache": jnp.asarray(backing[cached_rows]),
+        out = {"cache": jnp.asarray(pack_rows(backing[cached_rows])),
                "slot_of_row": jnp.asarray(self._slot_of_row),
                **self._staging_leaves()}
         if self.quantized:
@@ -278,7 +283,8 @@ class HostBackedStore(EmbeddingStore):
             put = lambda a: jax.device_put(a, self._staging_sharding)
         else:
             put = jnp.asarray
-        leaves = {"staging": put(buf), "staging_slot_of_row": put(smap)}
+        leaves = {"staging": put(pack_rows(buf)),
+                  "staging_slot_of_row": put(smap)}
         if sbuf is not None:
             leaves["staging_scale"] = put(sbuf)
         self._staged_dev = (version, leaves)
@@ -296,7 +302,7 @@ class HostBackedStore(EmbeddingStore):
             "no device-resident dense view (that ceiling is the point). "
             "Use host_view() for host-side access, or a DenseStore/"
             "CachedStore for paths that need the whole table on device "
-            "(serial baselines, the 'naive' level, apply_sharded).")
+            "(serial baselines, the 'naive' level).")
 
     # -- staging (the per-batch miss pipeline) -------------------------------
     def _global_rows(self, ids, mask=None) -> np.ndarray:
@@ -375,11 +381,12 @@ class HostBackedStore(EmbeddingStore):
                 ids, params["cache"], params["cache_scale"],
                 params["staging"], params["staging_scale"],
                 params["slot_of_row"], params["staging_slot_of_row"],
-                offsets, strategy=strategy, interpret=interpret)
+                offsets, strategy=strategy, interpret=interpret,
+                dim=self.spec.dim)
         return kops.multi_table_lookup_host(
             ids, params["cache"], params["staging"], params["slot_of_row"],
             params["staging_slot_of_row"], offsets,
-            strategy=strategy, interpret=interpret)
+            strategy=strategy, interpret=interpret, dim=self.spec.dim)
 
     def lookup_multihot(self, params: dict, ids: jax.Array, mask: jax.Array,
                         offsets: jax.Array, *, strategy: str = "auto",
@@ -389,11 +396,12 @@ class HostBackedStore(EmbeddingStore):
                 ids, mask, params["cache"], params["cache_scale"],
                 params["staging"], params["staging_scale"],
                 params["slot_of_row"], params["staging_slot_of_row"],
-                offsets, strategy=strategy, interpret=interpret)
+                offsets, strategy=strategy, interpret=interpret,
+                dim=self.spec.dim)
         return kops.multi_table_lookup_host_multihot(
             ids, mask, params["cache"], params["staging"],
             params["slot_of_row"], params["staging_slot_of_row"], offsets,
-            strategy=strategy, interpret=interpret)
+            strategy=strategy, interpret=interpret, dim=self.spec.dim)
 
     # -- traffic / cache management ------------------------------------------
     def observe(self, global_rows: np.ndarray) -> None:
@@ -477,8 +485,8 @@ class HostBackedStore(EmbeddingStore):
         cached = np.flatnonzero(slots >= 0)
         if cached.size:
             cidx = jnp.asarray(slots[cached])
-            out["cache"] = params["cache"].at[cidx].set(
-                jnp.asarray(wire[cached]))
+            out["cache"] = set_rows(params["cache"], slots[cached],
+                                    wire[cached])
             if self.quantized:
                 out["cache_scale"] = params["cache_scale"].at[cidx].set(
                     jnp.asarray(scale[cached]))

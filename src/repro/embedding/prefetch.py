@@ -90,6 +90,8 @@ class PrefetchPipeline:
         # counters (read under _lock; mirrored into StoreStats by the store)
         self.n_prefetched = 0      # rows staged by the async worker
         self.n_hinted_batches = 0
+        self.n_errors = 0          # hinted batches the worker failed on
+        self.last_error: BaseException | None = None
 
     # -- staging area --------------------------------------------------------
     @property
@@ -262,9 +264,12 @@ class PrefetchPipeline:
                 rows = self._q.popleft()
             try:
                 self._prefetch(rows)
-            except Exception:
-                # speculative work only — ensure() redoes anything missed
-                pass
+            except Exception as exc:
+                # speculative work only — ensure() redoes anything missed —
+                # but counted, so a serving run can fail on it
+                with self._lock:
+                    self.n_errors += 1
+                    self.last_error = exc
 
     def _prefetch(self, rows: np.ndarray) -> None:
         """Stage the cache misses of a hinted batch, capped at what fits."""

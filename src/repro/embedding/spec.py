@@ -8,6 +8,7 @@ Lives in the ``repro.embedding`` subsystem (it is the contract every
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import numpy as np
 
@@ -23,7 +24,9 @@ class FusedEmbeddingSpec:
         dim:         shared embedding dimension d.
         multi_hot:   max ids per field (1 = one-hot fields).
         dtype:       parameter dtype.
-        pad_rows_to: pad the mega-table height to a multiple (sharding).
+        pad_rows_to: pad the mega-table height to a multiple (sharding);
+                     the height is also rounded up to whole packed lines
+                     (:attr:`line_rows`).
         row_dtype:   *wire* dtype of stored rows — ``None`` (default) keeps
                      rows in ``dtype`` (bit-exact); ``"int8"`` stores rows
                      symmetrically quantized with one fp32 scale per row
@@ -48,11 +51,23 @@ class FusedEmbeddingSpec:
         return len(self.field_sizes)
 
     @property
+    def line_rows(self) -> int:
+        """Rows per 128-lane line of the packed table layout
+        (``kernels.multi_table_lookup.pack_rows``), common to the fp32 and
+        (where ``dim`` allows it) int8 formats, so every store's table
+        packs into whole lines without slicing."""
+        n = 1
+        for nbytes in (4 * self.dim, self.dim):     # fp32 rows, int8 rows
+            if nbytes % 4 == 0 and 512 % nbytes == 0:
+                n = math.lcm(n, 512 // nbytes)
+        return n
+
+    @property
     def rows(self) -> int:
         """Mega-table height: all fields + 1 zero row (multi-hot masking),
-        padded up for even sharding."""
+        padded up for even sharding and to whole packed lines."""
         n = int(sum(self.field_sizes)) + 1
-        pad = self.pad_rows_to
+        pad = math.lcm(self.pad_rows_to, self.line_rows)
         return ((n + pad - 1) // pad) * pad
 
     @property

@@ -27,6 +27,7 @@ is store-agnostic.
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import numpy as np
 import jax
@@ -34,6 +35,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops as kops
+from repro.kernels.multi_table_lookup import (pack_rows, rows_per_line,
+                                              unpack_rows)
 
 from .spec import FusedEmbeddingSpec
 
@@ -181,6 +184,30 @@ class EmbeddingStore:
         """Bytes one row moves on gather / host→device staging."""
         return self.spec.wire_row_bytes
 
+    @property
+    def wire_dtype(self) -> np.dtype:
+        """dtype of the stored rows: int8 when quantized, else the spec's."""
+        return np.dtype(np.int8 if self.quantized else self.spec.dtype)
+
+    # -- packed layout -----------------------------------------------------
+    # Every device table a store keeps is packed lane-dense (rows of
+    # ``wire_dtype`` as 128-lane lines of 32-bit words) — the layout the
+    # Pallas gathers DMA from, kept from init on so no call relayouts.
+    def unpack(self, lines):
+        """``(rows, d)`` view of a packed table (jnp or numpy)."""
+        return unpack_rows(lines, self.spec.dim, self.wire_dtype)
+
+    def gather_rows(self, lines, rows) -> jax.Array:
+        """Logical rows ``rows`` of a packed table (refresh-time cache
+        fills) — the Pallas gather on a TPU, so the table is never
+        relayouted; a plain take on its row view elsewhere."""
+        rows = jnp.asarray(rows, dtype=jnp.int32)
+        if kops.on_tpu():
+            from repro.kernels.multi_table_lookup import mtl_gather
+            return mtl_gather(rows, lines, dim=self.spec.dim,
+                              dtype=self.wire_dtype)
+        return jnp.take(self.unpack(lines), rows, axis=0)
+
     def _observe_traffic(self, rows: np.ndarray) -> None:
         """Wire-byte accounting shared by every tiered store's ``observe``:
         ``rows`` are the clipped global rows this batch gathered."""
@@ -263,6 +290,72 @@ class EmbeddingStore:
         """ids/mask (b, k, h) -> (b, k*d) sum-pooled."""
         raise NotImplementedError
 
+    #: param leaves :meth:`lookup_sharded` reads, the row-sharded table
+    #: last; empty for stores without a vocab-parallel lookup
+    mesh_leaves: tuple = ()
+
+    def _route(self, env: dict, rows: jax.Array, local: jax.Array,
+               own: jax.Array, first: jax.Array):
+        """Per-shard tier routing for :meth:`lookup_sharded`: given global
+        ``rows``, their ``local`` row in this shard's slice of the table
+        and whether this shard ``own``s them (``first`` = model shard 0),
+        return ``(tier, row, tables, scale)`` for ``kops.gather_tiers``."""
+        raise NotImplementedError
+
+    def lookup_sharded(self, params: dict, ids: jax.Array,
+                       offsets: jax.Array, mesh, model_axis: str = "model",
+                       *, strategy: str = "auto",
+                       interpret: bool | None = None) -> jax.Array:
+        """Vocab-parallel one-hot lookup over ``mesh``, ``(b, k) ->
+        (b, k*d)``, under ``shard_map``.
+
+        Every model shard gathers the rows its slice of the row-sharded
+        table holds (replicated tiers, such as a cache, serve from shard 0
+        only) and zeros the rest; one psum over the model axis assembles
+        the rows. Exactly one shard contributes each row, so the sum is
+        exact. The batch is split over the mesh's other axes when it
+        divides them.
+        """
+        from repro.distributed.sharding import fit_spec
+        leaves = tuple(params[k] for k in self.mesh_leaves)
+        specs = self.partition_spec(model_axis)
+        in_specs = [fit_spec(mesh, specs[k], v.shape)
+                    for k, v in zip(self.mesh_leaves, leaves)]
+        sharded = tuple(in_specs[-1])[:1] == (model_axis,)
+        data = tuple(a for a in mesh.axis_names if a != model_axis)
+        n_data = math.prod(mesh.shape[a] for a in data)
+        ids_spec = (P(data if len(data) > 1 else data[0], None)
+                    if data and ids.shape[0] % n_data == 0 else P())
+        dim = self.spec.dim
+        # per shard the gather is a plain single-device kernel again
+        local_strategy = (("pallas" if kops.on_tpu() else "jnp")
+                          if strategy == "auto" else strategy)
+
+        def local_lookup(ids, *vals):
+            env = dict(zip(self.mesh_leaves, vals))
+            table = env[self.mesh_leaves[-1]]
+            n_local = table.shape[0] * rows_per_line(dim, self.wire_dtype)
+            axis = jax.lax.axis_index(model_axis)
+            first = axis == 0
+            rows = (ids.astype(jnp.int32)
+                    + offsets[None, :].astype(jnp.int32)).reshape(-1)
+            lo = axis * n_local if sharded else 0
+            local = rows - lo
+            own = (local >= 0) & (local < n_local)
+            if not sharded:
+                own = own & first
+            tier, row, tables, scale = self._route(
+                env, rows, jnp.clip(local, 0, n_local - 1), own, first)
+            out = kops.gather_tiers(
+                tier, row, tables, scale,
+                dim=dim, dtype=self.wire_dtype, strategy=local_strategy,
+                interpret=interpret)
+            return jax.lax.psum(out.reshape(ids.shape[0], -1), model_axis)
+
+        return jax.shard_map(local_lookup, mesh=mesh,
+                             in_specs=(ids_spec, *in_specs),
+                             out_specs=ids_spec, check_vma=False)(ids, *leaves)
+
     # -- staging (only meaningful when ``needs_staging``) -------------------
     def stage(self, params: dict, ids, mask=None) -> dict:
         """Resolve this batch's misses into device-reachable tensors and
@@ -324,47 +417,53 @@ class EmbeddingStore:
 class DenseStore(EmbeddingStore):
     """The monolithic mega-table: everything in one fast-memory tier.
 
-    Param subtree: ``{"mega_table": (rows, d)}`` — exactly the layout the
-    repo used before stores existed, so older callers that hand-build
-    ``{"mega_table": table}`` dicts keep working unchanged.
+    Param subtree: ``{"mega_table": lines}`` — the ``(rows, d)`` table
+    packed lane-dense (``pack_rows``).
     """
 
     def init(self, key: jax.Array) -> dict:
-        return {"mega_table": self.init_dense_table(key)}
+        return {"mega_table": pack_rows(self.init_dense_table(key))}
 
     def adopt(self, params: dict) -> dict:
         if "mega_table" in params:
             return params
         backing = params["backing"]
-        if "backing_scale" in params and backing.dtype == jnp.int8:
+        if "backing_scale" in params and backing.dtype != jnp.float32:
             # a quantized tiered subtree: reconstitute full-precision rows
             # (lossy source — the int8 grid is all the values that remain)
             from repro import quant
-            backing = quant.dequantize_rows(
-                backing, params["backing_scale"]).astype(
-                    jnp.dtype(self.spec.dtype))
+            rows = unpack_rows(backing, self.spec.dim, np.int8)
+            backing = pack_rows(quant.dequantize_rows(
+                rows, params["backing_scale"]).astype(
+                    jnp.dtype(self.spec.dtype)))
         return {"mega_table": backing}
 
     def partition_spec(self, model_axis: str | None = "model") -> dict:
-        """Row-sharded (vocab-parallel) placement of the mega-table."""
+        """Row-sharded (vocab-parallel) placement of the mega-table's
+        packed lines."""
         return {"mega_table": P(model_axis, None)}
 
     def dense_view(self, params: dict) -> jax.Array:
-        return params["mega_table"]
+        return self.unpack(params["mega_table"])
 
     def lookup(self, params: dict, ids: jax.Array, offsets: jax.Array, *,
                strategy: str = "auto",
                interpret: bool | None = None) -> jax.Array:
         return kops.multi_table_lookup(
             ids, params["mega_table"], offsets,
-            strategy=strategy, interpret=interpret)
+            strategy=strategy, interpret=interpret, dim=self.spec.dim)
 
     def lookup_multihot(self, params: dict, ids: jax.Array, mask: jax.Array,
                         offsets: jax.Array, *, strategy: str = "auto",
                         interpret: bool | None = None) -> jax.Array:
         return kops.multi_table_lookup_multihot(
             ids, mask, params["mega_table"], offsets,
-            strategy=strategy, interpret=interpret)
+            strategy=strategy, interpret=interpret, dim=self.spec.dim)
+
+    mesh_leaves = ("mega_table",)
+
+    def _route(self, env, rows, local, own, first):
+        return (jnp.where(own, 0, -1), local, (env["mega_table"],), None)
 
     def describe(self) -> str:
         return f"dense(rows={self.spec.rows},d={self.spec.dim})"

@@ -8,7 +8,7 @@ mega-table — which is what the CPU benchmarks time).
 
 Strategies for ``multi_table_lookup``:
 
-  "auto"        pallas on TPU, jnp-fused elsewhere
+  "auto"        pallas on TPU (outside a mesh program), jnp elsewhere
   "pallas"      output-first Pallas gather (Alg. 1)          [C2+C3]
   "onehot"      one-hot MXU matmul (small fields)            [TPU-native]
   "jnp"         vectorized single-gather over the mega-table [C2 at XLA level]
@@ -18,6 +18,10 @@ Strategies for ``multi_table_lookup``:
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
+
+import numpy as np
 import jax
 import jax.numpy as jnp
 
@@ -29,6 +33,10 @@ from .fused_cross import fused_cross_v1, fused_cross_v2
 from .fused_fm import fused_fm_second_order
 from .multi_table_lookup import (
     mtl_gather,
+    mtl_gather_tiered,
+    pack_rows,
+    rows_per_line,
+    unpack_rows,
     mtl_gather_multihot,
     mtl_gather_three_level,
     mtl_gather_three_level_q8,
@@ -54,11 +62,62 @@ __all__ = [
     "fused_cross_v2",
     "fused_fm_second_order",
     "on_tpu",
+    "use_pallas",
+    "mesh_context",
+    "active_mesh",
 ]
 
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+#: (mesh, model_axis) while a plan for that mesh is being traced
+_MESH = contextvars.ContextVar("plan_mesh", default=None)
+
+
+@contextlib.contextmanager
+def mesh_context(mesh, model_axis: str = "model"):
+    """Mark the code traced inside as one GSPMD program over ``mesh``.
+
+    A Pallas kernel cannot be partitioned by GSPMD, so inside this context
+    ``strategy="auto"`` resolves to the jnp twins, and embedding lookups
+    run as vocab-parallel ``shard_map`` programs whose per-shard gathers
+    are the Pallas kernels again (``EmbeddingStore.lookup_sharded``).
+    ``compile_plan`` wraps a mesh plan's lowering in this."""
+    token = _MESH.set(None if mesh is None else (mesh, model_axis))
+    try:
+        yield
+    finally:
+        _MESH.reset(token)
+
+
+def active_mesh():
+    """``(mesh, model_axis)`` inside :func:`mesh_context`, else None."""
+    return _MESH.get()
+
+
+def use_pallas() -> bool:
+    """What ``strategy="auto"`` means here: the Pallas kernels on a TPU,
+    outside a mesh program; the jnp twins otherwise."""
+    return on_tpu() and active_mesh() is None
+
+
+def _packed(table: jax.Array, dim: int | None) -> tuple[jax.Array, int]:
+    """``(lines, d)`` for the Pallas gathers. Stores hand over tables
+    already packed (``dim`` given, see ``multi_table_lookup.pack_rows``);
+    a logical ``(N, d)`` table (``dim=None``) is packed here, per call."""
+    if dim is None:
+        return pack_rows(table), table.shape[1]
+    return table, dim
+
+
+def _logical(table: jax.Array, dim: int | None, dtype=None) -> jax.Array:
+    """The ``(N, d)`` row view of a packed (``dim`` given) or logical
+    table, for the jnp twins. ``dtype`` defaults to the packed dtype."""
+    if dim is None:
+        return table
+    return unpack_rows(table, dim, dtype or table.dtype)
 
 
 def _flat_rows(ids: jax.Array, offsets: jax.Array) -> jax.Array:
@@ -68,50 +127,80 @@ def _flat_rows(ids: jax.Array, offsets: jax.Array) -> jax.Array:
 
 def multi_table_lookup(ids: jax.Array, mega_table: jax.Array,
                        offsets: jax.Array, *, strategy: str = "auto",
-                       interpret: bool | None = None) -> jax.Array:
+                       interpret: bool | None = None,
+                       dim: int | None = None) -> jax.Array:
     """Fused multi-table embedding lookup (paper Algorithm 1).
 
     Args:
         ids:        (b, k) int32 per-field local ids.
-        mega_table: (N, d) concatenated tables.
+        mega_table: (N, d) concatenated tables, or their packed lines
+                    (``multi_table_lookup.pack_rows``) when ``dim`` is given.
         offsets:    (k,) int32 starting row of each table.
         strategy:   see module docstring.
         interpret:  force Pallas interpret mode (defaults to not-on-TPU).
+        dim:        row width d of a packed ``mega_table``.
 
     Returns:
         (b, k*d) embedding output.
     """
     b, k = ids.shape
-    d = mega_table.shape[1]
     if interpret is None:
         interpret = not on_tpu()
     if strategy == "auto":
-        strategy = "pallas" if on_tpu() else "jnp"
+        strategy = "pallas" if use_pallas() else "jnp"
 
-    if strategy == "jnp":
-        return ref.ref_multi_table_lookup(ids, mega_table, offsets, k)
     if strategy == "pallas":
+        lines, d = _packed(mega_table, dim)
         rows = _flat_rows(ids, offsets)
-        return mtl_gather(rows, mega_table, interpret=interpret).reshape(b, k * d)
+        return mtl_gather(rows, lines, dim=d,
+                          interpret=interpret).reshape(b, k * d)
+    table = _logical(mega_table, dim)
+    if strategy == "jnp":
+        return ref.ref_multi_table_lookup(ids, table, offsets, k)
     if strategy == "input_first":
         rows = _flat_rows(ids, offsets)
-        return mtl_input_first(rows, mega_table, k=k, interpret=interpret)
+        return mtl_input_first(rows, table, k=k, interpret=interpret)
     if strategy == "serial":
-        # reconstruct per-field tables views (baseline semantics; the extra
-        # slicing is free under jit — the k separate gathers are the cost)
-        sizes = jnp.diff(jnp.concatenate([offsets, jnp.array([mega_table.shape[0]])]))
-        del sizes  # views below keep it simple: slice lazily per field
+        # per-field gathers off the concatenated table (baseline semantics;
+        # the k separate gathers are the cost)
         cols = []
         for i in range(k):
-            cols.append(jnp.take(mega_table, ids[:, i] + offsets[i], axis=0))
+            cols.append(jnp.take(table, ids[:, i] + offsets[i], axis=0))
         return jnp.concatenate(cols, axis=1)
     raise ValueError(f"unknown strategy {strategy!r}")
+
+
+def _resolve(strategy: str, interpret: bool | None) -> tuple[str, bool]:
+    if interpret is None:
+        interpret = not on_tpu()
+    if strategy == "auto":
+        strategy = "pallas" if use_pallas() else "jnp"
+    if strategy not in ("jnp", "pallas"):
+        raise ValueError(f"unknown strategy {strategy!r}")
+    return strategy, interpret
+
+
+def _masked_rows(ids: jax.Array, mask: jax.Array, offsets: jax.Array,
+                 zero_row: int) -> jax.Array:
+    """(b, k, h) local ids -> flat global rows, masked slots redirected to
+    the mega-table's all-zero row."""
+    rows = ids.astype(jnp.int32) + offsets[None, :, None].astype(jnp.int32)
+    return jnp.where(mask.astype(bool), rows, zero_row).reshape(-1)
+
+
+def _pool(vals: jax.Array, mask: jax.Array, b: int, k: int, h: int,
+          d: int) -> jax.Array:
+    """The jnp twins' multi-hot pooling: mask-multiply, sum over slots."""
+    return jnp.sum(vals.reshape(b, k, h, d)
+                   * mask.reshape(b, k, h, 1).astype(vals.dtype),
+                   axis=2).reshape(b, k * d)
 
 
 def multi_table_lookup_cached(ids: jax.Array, cache: jax.Array,
                               backing: jax.Array, slot_of_row: jax.Array,
                               offsets: jax.Array, *, strategy: str = "auto",
-                              interpret: bool | None = None) -> jax.Array:
+                              interpret: bool | None = None,
+                              dim: int | None = None) -> jax.Array:
     """Fused lookup through a tiered (cache + backing) embedding store.
 
     The CachedStore analogue of :func:`multi_table_lookup`: one two-level
@@ -121,30 +210,29 @@ def multi_table_lookup_cached(ids: jax.Array, cache: jax.Array,
 
     Args:
         ids:         (b, k) int32 per-field local ids.
-        cache:       (C, d) hot-row copies.
-        backing:     (N, d) full mega-table.
+        cache:       (C, d) hot-row copies (packed when ``dim`` is given).
+        backing:     (N, d) full mega-table (packed when ``dim`` is given).
         slot_of_row: (N,) int32 cache slot per global row, -1 = uncached.
         offsets:     (k,) int32 starting row of each table.
+        dim:         row width d of packed tiers.
 
     Returns:
         (b, k*d) embedding output.
     """
     b, k = ids.shape
-    d = backing.shape[1]
-    if interpret is None:
-        interpret = not on_tpu()
-    if strategy == "auto":
-        strategy = "pallas" if on_tpu() else "jnp"
+    strategy, interpret = _resolve(strategy, interpret)
     rows = _flat_rows(ids, offsets)
     if strategy == "jnp":
-        out = ref.ref_two_level_gather(rows, slot_of_row, cache, backing)
-    elif strategy == "pallas":
-        slots = jnp.take(slot_of_row, rows, axis=0)
-        out = mtl_gather_two_level(rows, slots, cache, backing,
-                                   interpret=interpret)
+        out = ref.ref_two_level_gather(rows, slot_of_row,
+                                       _logical(cache, dim),
+                                       _logical(backing, dim))
     else:
-        raise ValueError(f"unknown strategy {strategy!r}")
-    return out.reshape(b, k * d)
+        cache, d = _packed(cache, dim)
+        backing, _ = _packed(backing, dim)
+        slots = jnp.take(slot_of_row, rows, axis=0)
+        out = mtl_gather_two_level(rows, slots, cache, backing, dim=d,
+                                   interpret=interpret)
+    return out.reshape(b, -1)
 
 
 def multi_table_lookup_cached_multihot(ids: jax.Array, mask: jax.Array,
@@ -152,7 +240,8 @@ def multi_table_lookup_cached_multihot(ids: jax.Array, mask: jax.Array,
                                        slot_of_row: jax.Array,
                                        offsets: jax.Array, *,
                                        strategy: str = "auto",
-                                       interpret: bool | None = None
+                                       interpret: bool | None = None,
+                                       dim: int | None = None
                                        ) -> jax.Array:
     """Multi-hot (pooled) fused lookup through a tiered store.
 
@@ -164,36 +253,32 @@ def multi_table_lookup_cached_multihot(ids: jax.Array, mask: jax.Array,
     Args:
         ids:         (b, k, h) local ids; invalid slots arbitrary.
         mask:        (b, k, h) 1 for valid slots, 0 otherwise.
-        cache:       (C, d) hot-row copies.
+        cache:       (C, d) hot-row copies (packed when ``dim`` is given).
         backing:     (N, d) full mega-table **with a trailing all-zero row**.
         slot_of_row: (N,) int32 index map.
         offsets:     (k,) table starts.
+        dim:         row width d of packed tiers.
 
     Returns:
         (b, k*d) pooled output.
     """
     b, k, h = ids.shape
-    d = backing.shape[1]
-    if interpret is None:
-        interpret = not on_tpu()
-    if strategy == "auto":
-        strategy = "pallas" if on_tpu() else "jnp"
+    strategy, interpret = _resolve(strategy, interpret)
+    zero_row = slot_of_row.shape[0] - 1
     if strategy == "jnp":
         rows = (ids.astype(jnp.int32)
                 + offsets[None, :, None].astype(jnp.int32)).reshape(-1)
-        vals = ref.ref_two_level_gather(rows, slot_of_row, cache, backing)
-        pooled = jnp.sum(vals.reshape(b, k, h, d)
-                         * mask[..., None].astype(backing.dtype), axis=2)
-        return pooled.reshape(b, k * d)
-    if strategy == "pallas":
-        zero_row = backing.shape[0] - 1
-        rows = ids.astype(jnp.int32) + offsets[None, :, None].astype(jnp.int32)
-        rows = jnp.where(mask.astype(bool), rows, zero_row).reshape(-1)
-        slots = jnp.take(slot_of_row, rows, axis=0)
-        out = mtl_gather_two_level(rows, slots, cache, backing, hot=h,
-                                   interpret=interpret)
-        return out.reshape(b, k * d)
-    raise ValueError(f"unknown strategy {strategy!r}")
+        vals = ref.ref_two_level_gather(rows, slot_of_row,
+                                        _logical(cache, dim),
+                                        _logical(backing, dim))
+        return _pool(vals, mask, b, k, h, vals.shape[-1])
+    cache, d = _packed(cache, dim)
+    backing, _ = _packed(backing, dim)
+    rows = _masked_rows(ids, mask, offsets, zero_row)
+    slots = jnp.take(slot_of_row, rows, axis=0)
+    out = mtl_gather_two_level(rows, slots, cache, backing, dim=d, hot=h,
+                               interpret=interpret)
+    return out.reshape(b, k * d)
 
 
 def multi_table_lookup_cached_q8(ids: jax.Array, cache: jax.Array,
@@ -201,7 +286,8 @@ def multi_table_lookup_cached_q8(ids: jax.Array, cache: jax.Array,
                                  backing_scale: jax.Array,
                                  slot_of_row: jax.Array, offsets: jax.Array,
                                  *, strategy: str = "auto",
-                                 interpret: bool | None = None) -> jax.Array:
+                                 interpret: bool | None = None,
+                                 dim: int | None = None) -> jax.Array:
     """Quantized tiered lookup: int8 cache/backing rows, per-row fp32
     scales, dequantization inside the gather.
 
@@ -212,34 +298,32 @@ def multi_table_lookup_cached_q8(ids: jax.Array, cache: jax.Array,
 
     Args:
         ids:           (b, k) int32 per-field local ids.
-        cache:         (C, d) int8 hot-row copies.
+        cache:         (C, d) int8 hot-row copies (packed when ``dim``).
         cache_scale:   (C, 1) fp32 per-row scales.
-        backing:       (N, d) int8 full mega-table.
+        backing:       (N, d) int8 full mega-table (packed when ``dim``).
         backing_scale: (N, 1) fp32 per-row scales.
         slot_of_row:   (N,) int32 cache slot per global row, -1 = uncached.
         offsets:       (k,) int32 starting row of each table.
+        dim:           row width d of packed tiers.
 
     Returns:
         (b, k*d) float32 embedding output.
     """
     b, k = ids.shape
-    d = backing.shape[1]
-    if interpret is None:
-        interpret = not on_tpu()
-    if strategy == "auto":
-        strategy = "pallas" if on_tpu() else "jnp"
+    strategy, interpret = _resolve(strategy, interpret)
     rows = _flat_rows(ids, offsets)
     if strategy == "jnp":
-        out = ref.ref_two_level_gather_q8(rows, slot_of_row, cache,
-                                          cache_scale, backing, backing_scale)
-    elif strategy == "pallas":
+        out = ref.ref_two_level_gather_q8(
+            rows, slot_of_row, _logical(cache, dim, jnp.int8), cache_scale,
+            _logical(backing, dim, jnp.int8), backing_scale)
+    else:
+        cache, d = _packed(cache, dim)
+        backing, _ = _packed(backing, dim)
         slots = jnp.take(slot_of_row, rows, axis=0)
         out = mtl_gather_two_level_q8(rows, slots, cache, cache_scale,
-                                      backing, backing_scale,
+                                      backing, backing_scale, dim=d,
                                       interpret=interpret)
-    else:
-        raise ValueError(f"unknown strategy {strategy!r}")
-    return out.reshape(b, k * d)
+    return out.reshape(b, -1)
 
 
 def multi_table_lookup_cached_q8_multihot(ids: jax.Array, mask: jax.Array,
@@ -250,7 +334,8 @@ def multi_table_lookup_cached_q8_multihot(ids: jax.Array, mask: jax.Array,
                                           slot_of_row: jax.Array,
                                           offsets: jax.Array, *,
                                           strategy: str = "auto",
-                                          interpret: bool | None = None
+                                          interpret: bool | None = None,
+                                          dim: int | None = None
                                           ) -> jax.Array:
     """Multi-hot (pooled) quantized tiered lookup.
 
@@ -262,46 +347,40 @@ def multi_table_lookup_cached_q8_multihot(ids: jax.Array, mask: jax.Array,
     Args:
         ids:           (b, k, h) local ids; invalid slots arbitrary.
         mask:          (b, k, h) 1 for valid slots, 0 otherwise.
-        cache:         (C, d) int8 hot-row copies.
+        cache:         (C, d) int8 hot-row copies (packed when ``dim``).
         cache_scale:   (C, 1) fp32 per-row scales.
         backing:       (N, d) int8 mega-table **with a trailing zero row**.
         backing_scale: (N, 1) fp32 per-row scales.
         slot_of_row:   (N,) int32 index map.
         offsets:       (k,) table starts.
+        dim:           row width d of packed tiers.
 
     Returns:
         (b, k*d) float32 pooled output.
     """
     b, k, h = ids.shape
-    d = backing.shape[1]
-    if interpret is None:
-        interpret = not on_tpu()
-    if strategy == "auto":
-        strategy = "pallas" if on_tpu() else "jnp"
-    zero_row = backing.shape[0] - 1
-    rows = ids.astype(jnp.int32) + offsets[None, :, None].astype(jnp.int32)
-    rows = jnp.where(mask.astype(bool), rows, zero_row).reshape(-1)
+    strategy, interpret = _resolve(strategy, interpret)
+    rows = _masked_rows(ids, mask, offsets, slot_of_row.shape[0] - 1)
     if strategy == "jnp":
-        vals = ref.ref_two_level_gather_q8(rows, slot_of_row, cache,
-                                           cache_scale, backing,
-                                           backing_scale)
-        pooled = jnp.sum(vals.reshape(b, k, h, d)
-                         * mask[..., None].astype(vals.dtype), axis=2)
-        return pooled.reshape(b, k * d)
-    if strategy == "pallas":
-        slots = jnp.take(slot_of_row, rows, axis=0)
-        out = mtl_gather_two_level_q8(rows, slots, cache, cache_scale,
-                                      backing, backing_scale, hot=h,
-                                      interpret=interpret)
-        return out.reshape(b, k * d)
-    raise ValueError(f"unknown strategy {strategy!r}")
+        vals = ref.ref_two_level_gather_q8(
+            rows, slot_of_row, _logical(cache, dim, jnp.int8), cache_scale,
+            _logical(backing, dim, jnp.int8), backing_scale)
+        return _pool(vals, mask, b, k, h, vals.shape[-1])
+    cache, d = _packed(cache, dim)
+    backing, _ = _packed(backing, dim)
+    slots = jnp.take(slot_of_row, rows, axis=0)
+    out = mtl_gather_two_level_q8(rows, slots, cache, cache_scale,
+                                  backing, backing_scale, dim=d, hot=h,
+                                  interpret=interpret)
+    return out.reshape(b, k * d)
 
 
 def multi_table_lookup_host(ids: jax.Array, cache: jax.Array,
                             staging: jax.Array, slot_of_row: jax.Array,
                             staging_slot_of_row: jax.Array,
                             offsets: jax.Array, *, strategy: str = "auto",
-                            interpret: bool | None = None) -> jax.Array:
+                            interpret: bool | None = None,
+                            dim: int | None = None) -> jax.Array:
     """Fused lookup through an out-of-HBM (cache + staging) store.
 
     The HostBackedStore analogue of :func:`multi_table_lookup_cached` with
@@ -313,33 +392,32 @@ def multi_table_lookup_host(ids: jax.Array, cache: jax.Array,
 
     Args:
         ids:                 (b, k) int32 per-field local ids.
-        cache:               (C, d) hot-row copies.
-        staging:             (S, d) staged miss rows of this batch.
+        cache:               (C, d) hot-row copies (packed when ``dim``).
+        staging:             (S, d) staged miss rows (packed when ``dim``).
         slot_of_row:         (N,) int32 cache slot per row, -1 = uncached.
         staging_slot_of_row: (N,) int32 staging slot per row, -1 = unstaged.
         offsets:             (k,) int32 starting row of each table.
+        dim:                 row width d of packed tiers.
 
     Returns:
         (b, k*d) embedding output.
     """
     b, k = ids.shape
-    d = cache.shape[1]
-    if interpret is None:
-        interpret = not on_tpu()
-    if strategy == "auto":
-        strategy = "pallas" if on_tpu() else "jnp"
+    strategy, interpret = _resolve(strategy, interpret)
     rows = _flat_rows(ids, offsets)
     if strategy == "jnp":
         out = ref.ref_three_level_gather(rows, slot_of_row,
-                                         staging_slot_of_row, cache, staging)
-    elif strategy == "pallas":
+                                         staging_slot_of_row,
+                                         _logical(cache, dim),
+                                         _logical(staging, dim))
+    else:
+        cache, d = _packed(cache, dim)
+        staging, _ = _packed(staging, dim)
         cslots = jnp.take(slot_of_row, rows, axis=0)
         sslots = jnp.take(staging_slot_of_row, rows, axis=0)
-        out = mtl_gather_three_level(cslots, sslots, cache, staging,
+        out = mtl_gather_three_level(cslots, sslots, cache, staging, dim=d,
                                      interpret=interpret)
-    else:
-        raise ValueError(f"unknown strategy {strategy!r}")
-    return out.reshape(b, k * d)
+    return out.reshape(b, -1)
 
 
 def multi_table_lookup_host_multihot(ids: jax.Array, mask: jax.Array,
@@ -348,7 +426,8 @@ def multi_table_lookup_host_multihot(ids: jax.Array, mask: jax.Array,
                                      staging_slot_of_row: jax.Array,
                                      offsets: jax.Array, *,
                                      strategy: str = "auto",
-                                     interpret: bool | None = None
+                                     interpret: bool | None = None,
+                                     dim: int | None = None
                                      ) -> jax.Array:
     """Multi-hot (pooled) fused lookup through an out-of-HBM store.
 
@@ -361,38 +440,32 @@ def multi_table_lookup_host_multihot(ids: jax.Array, mask: jax.Array,
     Args:
         ids:                 (b, k, h) local ids; invalid slots arbitrary.
         mask:                (b, k, h) 1 for valid slots, 0 otherwise.
-        cache:               (C, d) hot-row copies.
-        staging:             (S, d) staged miss rows of this batch.
+        cache:               (C, d) hot-row copies (packed when ``dim``).
+        staging:             (S, d) staged miss rows (packed when ``dim``).
         slot_of_row:         (N,) int32 cache index map.
         staging_slot_of_row: (N,) int32 staging index map.
         offsets:             (k,) table starts.
+        dim:                 row width d of packed tiers.
 
     Returns:
         (b, k*d) pooled output.
     """
     b, k, h = ids.shape
-    d = cache.shape[1]
-    if interpret is None:
-        interpret = not on_tpu()
-    if strategy == "auto":
-        strategy = "pallas" if on_tpu() else "jnp"
-    zero_row = slot_of_row.shape[0] - 1
-    rows = ids.astype(jnp.int32) + offsets[None, :, None].astype(jnp.int32)
-    rows = jnp.where(mask.astype(bool), rows, zero_row).reshape(-1)
+    strategy, interpret = _resolve(strategy, interpret)
+    rows = _masked_rows(ids, mask, offsets, slot_of_row.shape[0] - 1)
     if strategy == "jnp":
         vals = ref.ref_three_level_gather(rows, slot_of_row,
-                                          staging_slot_of_row, cache, staging)
-        pooled = jnp.sum(vals.reshape(b, k, h, d)
-                         * mask.reshape(b, k, h, 1).astype(cache.dtype),
-                         axis=2)
-        return pooled.reshape(b, k * d)
-    if strategy == "pallas":
-        cslots = jnp.take(slot_of_row, rows, axis=0)
-        sslots = jnp.take(staging_slot_of_row, rows, axis=0)
-        out = mtl_gather_three_level(cslots, sslots, cache, staging, hot=h,
-                                     interpret=interpret)
-        return out.reshape(b, k * d)
-    raise ValueError(f"unknown strategy {strategy!r}")
+                                          staging_slot_of_row,
+                                          _logical(cache, dim),
+                                          _logical(staging, dim))
+        return _pool(vals, mask, b, k, h, vals.shape[-1])
+    cache, d = _packed(cache, dim)
+    staging, _ = _packed(staging, dim)
+    cslots = jnp.take(slot_of_row, rows, axis=0)
+    sslots = jnp.take(staging_slot_of_row, rows, axis=0)
+    out = mtl_gather_three_level(cslots, sslots, cache, staging, dim=d,
+                                 hot=h, interpret=interpret)
+    return out.reshape(b, k * d)
 
 
 def multi_table_lookup_host_q8(ids: jax.Array, cache: jax.Array,
@@ -401,7 +474,8 @@ def multi_table_lookup_host_q8(ids: jax.Array, cache: jax.Array,
                                slot_of_row: jax.Array,
                                staging_slot_of_row: jax.Array,
                                offsets: jax.Array, *, strategy: str = "auto",
-                               interpret: bool | None = None) -> jax.Array:
+                               interpret: bool | None = None,
+                               dim: int | None = None) -> jax.Array:
     """Quantized out-of-HBM lookup: int8 cache/staging rows, fp32 scales,
     in-gather dequant, zero-guard intact (q = 0 dequantizes to 0.0).
 
@@ -411,37 +485,36 @@ def multi_table_lookup_host_q8(ids: jax.Array, cache: jax.Array,
 
     Args:
         ids:                 (b, k) int32 per-field local ids.
-        cache:               (C, d) int8 hot-row copies.
+        cache:               (C, d) int8 hot-row copies (packed when ``dim``).
         cache_scale:         (C, 1) fp32 per-row scales.
-        staging:             (S, d) int8 staged miss rows.
+        staging:             (S, d) int8 staged miss rows (packed when
+                             ``dim``).
         staging_scale:       (S, 1) fp32 per-row scales.
         slot_of_row:         (N,) int32 cache slot per row, -1 = uncached.
         staging_slot_of_row: (N,) int32 staging slot per row, -1 = unstaged.
         offsets:             (k,) int32 starting row of each table.
+        dim:                 row width d of packed tiers.
 
     Returns:
         (b, k*d) float32 embedding output.
     """
     b, k = ids.shape
-    d = cache.shape[1]
-    if interpret is None:
-        interpret = not on_tpu()
-    if strategy == "auto":
-        strategy = "pallas" if on_tpu() else "jnp"
+    strategy, interpret = _resolve(strategy, interpret)
     rows = _flat_rows(ids, offsets)
     if strategy == "jnp":
         out = ref.ref_three_level_gather_q8(
             rows, slot_of_row, staging_slot_of_row,
-            cache, cache_scale, staging, staging_scale)
-    elif strategy == "pallas":
+            _logical(cache, dim, jnp.int8), cache_scale,
+            _logical(staging, dim, jnp.int8), staging_scale)
+    else:
+        cache, d = _packed(cache, dim)
+        staging, _ = _packed(staging, dim)
         cslots = jnp.take(slot_of_row, rows, axis=0)
         sslots = jnp.take(staging_slot_of_row, rows, axis=0)
         out = mtl_gather_three_level_q8(cslots, sslots, cache, cache_scale,
-                                        staging, staging_scale,
+                                        staging, staging_scale, dim=d,
                                         interpret=interpret)
-    else:
-        raise ValueError(f"unknown strategy {strategy!r}")
-    return out.reshape(b, k * d)
+    return out.reshape(b, -1)
 
 
 def multi_table_lookup_host_q8_multihot(ids: jax.Array, mask: jax.Array,
@@ -453,7 +526,8 @@ def multi_table_lookup_host_q8_multihot(ids: jax.Array, mask: jax.Array,
                                         staging_slot_of_row: jax.Array,
                                         offsets: jax.Array, *,
                                         strategy: str = "auto",
-                                        interpret: bool | None = None
+                                        interpret: bool | None = None,
+                                        dim: int | None = None
                                         ) -> jax.Array:
     """Multi-hot (pooled) quantized out-of-HBM lookup.
 
@@ -464,42 +538,59 @@ def multi_table_lookup_host_q8_multihot(ids: jax.Array, mask: jax.Array,
     Args:
         ids:                 (b, k, h) local ids; invalid slots arbitrary.
         mask:                (b, k, h) 1 for valid slots, 0 otherwise.
-        cache:               (C, d) int8 hot-row copies.
+        cache:               (C, d) int8 hot-row copies (packed when ``dim``).
         cache_scale:         (C, 1) fp32 per-row scales.
-        staging:             (S, d) int8 staged miss rows.
+        staging:             (S, d) int8 staged miss rows (packed when
+                             ``dim``).
         staging_scale:       (S, 1) fp32 per-row scales.
         slot_of_row:         (N,) int32 cache index map.
         staging_slot_of_row: (N,) int32 staging index map.
         offsets:             (k,) table starts.
+        dim:                 row width d of packed tiers.
 
     Returns:
         (b, k*d) float32 pooled output.
     """
     b, k, h = ids.shape
-    d = cache.shape[1]
-    if interpret is None:
-        interpret = not on_tpu()
-    if strategy == "auto":
-        strategy = "pallas" if on_tpu() else "jnp"
-    zero_row = slot_of_row.shape[0] - 1
-    rows = ids.astype(jnp.int32) + offsets[None, :, None].astype(jnp.int32)
-    rows = jnp.where(mask.astype(bool), rows, zero_row).reshape(-1)
+    strategy, interpret = _resolve(strategy, interpret)
+    rows = _masked_rows(ids, mask, offsets, slot_of_row.shape[0] - 1)
     if strategy == "jnp":
         vals = ref.ref_three_level_gather_q8(
             rows, slot_of_row, staging_slot_of_row,
-            cache, cache_scale, staging, staging_scale)
-        pooled = jnp.sum(vals.reshape(b, k, h, d)
-                         * mask.reshape(b, k, h, 1).astype(vals.dtype),
-                         axis=2)
-        return pooled.reshape(b, k * d)
-    if strategy == "pallas":
-        cslots = jnp.take(slot_of_row, rows, axis=0)
-        sslots = jnp.take(staging_slot_of_row, rows, axis=0)
-        out = mtl_gather_three_level_q8(cslots, sslots, cache, cache_scale,
-                                        staging, staging_scale, hot=h,
-                                        interpret=interpret)
-        return out.reshape(b, k * d)
-    raise ValueError(f"unknown strategy {strategy!r}")
+            _logical(cache, dim, jnp.int8), cache_scale,
+            _logical(staging, dim, jnp.int8), staging_scale)
+        return _pool(vals, mask, b, k, h, vals.shape[-1])
+    cache, d = _packed(cache, dim)
+    staging, _ = _packed(staging, dim)
+    cslots = jnp.take(slot_of_row, rows, axis=0)
+    sslots = jnp.take(staging_slot_of_row, rows, axis=0)
+    out = mtl_gather_three_level_q8(cslots, sslots, cache, cache_scale,
+                                    staging, staging_scale, dim=d, hot=h,
+                                    interpret=interpret)
+    return out.reshape(b, k * d)
+
+
+def gather_tiers(tier: jax.Array, row: jax.Array, tables: tuple,
+                 scale: jax.Array | None = None, *, dim: int, dtype,
+                 strategy: str = "auto",
+                 interpret: bool | None = None) -> jax.Array:
+    """One-hot gather over explicit tiers: slot ``i`` reads logical row
+    ``row[i]`` of packed table ``tables[tier[i]]`` (zero when
+    ``tier[i] < 0``), dequantizing int8 rows by ``scale``. The building
+    block of the vocab-parallel mesh lookup, where each shard routes its
+    own rows (``EmbeddingStore.lookup_sharded``).
+
+    Returns:
+        (R, dim) rows of ``dtype`` (fp32 when ``scale`` is given).
+    """
+    strategy, interpret = _resolve(strategy, interpret)
+    if strategy == "jnp":
+        return ref.ref_tiered_gather(
+            tier, row, tuple(unpack_rows(t, dim, dtype) for t in tables),
+            scale)
+    return mtl_gather_tiered(tier, row, tuple(tables), scale, hot=1,
+                             dim=dim, dtype=np.dtype(dtype),
+                             interpret=interpret)
 
 
 # jitted so the epilogue's fp32 multiply-add chain contracts exactly like
@@ -539,7 +630,7 @@ def dense_matmul_q8(h: jax.Array, wq: jax.Array, wscale: jax.Array,
     if interpret is None:
         interpret = not on_tpu()
     if strategy == "auto":
-        strategy = "pallas" if on_tpu() else "jnp"
+        strategy = "pallas" if use_pallas() else "jnp"
     hscale = quant.absmax_scale(h, axis=-1)
     hq = quant.quantize(h, hscale)
     bias2d = bias.reshape(1, -1)
@@ -563,31 +654,29 @@ def multi_table_lookup_onehot(ids: jax.Array, stacked_tables: jax.Array, *,
 def multi_table_lookup_multihot(ids: jax.Array, mask: jax.Array,
                                 mega_table: jax.Array, offsets: jax.Array, *,
                                 strategy: str = "auto",
-                                interpret: bool | None = None) -> jax.Array:
+                                interpret: bool | None = None,
+                                dim: int | None = None) -> jax.Array:
     """Multi-hot (pooled) fused lookup.
 
     Args:
         ids:        (b, k, h) local ids; invalid slots arbitrary.
         mask:       (b, k, h) 1 for valid slots, 0 otherwise.
         mega_table: (N, d) concatenated tables **with a trailing all-zero
-                    row** at index N-1 (ops appends it in FusedEmbedding).
+                    row** at index N-1 (packed when ``dim`` is given).
         offsets:    (k,) table starts.
+        dim:        row width d of a packed ``mega_table``.
 
     Returns:
         (b, k*d) pooled output.
     """
     b, k, h = ids.shape
-    d = mega_table.shape[1]
-    if interpret is None:
-        interpret = not on_tpu()
-    if strategy == "auto":
-        strategy = "pallas" if on_tpu() else "jnp"
+    strategy, interpret = _resolve(strategy, interpret)
     if strategy == "jnp":
-        return ref.ref_multi_hot_lookup(ids, mask, mega_table, offsets)
-    if strategy == "pallas":
-        zero_row = mega_table.shape[0] - 1
-        rows = ids.astype(jnp.int32) + offsets[None, :, None].astype(jnp.int32)
-        rows = jnp.where(mask.astype(bool), rows, zero_row).reshape(-1)
-        out = mtl_gather_multihot(rows, mega_table, hot=h, interpret=interpret)
-        return out.reshape(b, k * d)
-    raise ValueError(f"unknown strategy {strategy!r}")
+        return ref.ref_multi_hot_lookup(ids, mask, _logical(mega_table, dim),
+                                        offsets)
+    lines, d = _packed(mega_table, dim)
+    # the last row (the zero row, or zero padding after it) is all-zero
+    zero_row = lines.shape[0] * rows_per_line(d, lines.dtype) - 1
+    rows = _masked_rows(ids, mask, offsets, zero_row)
+    out = mtl_gather_multihot(rows, lines, hot=h, dim=d, interpret=interpret)
+    return out.reshape(b, k * d)

@@ -218,6 +218,29 @@ def ref_three_level_gather_q8(flat_rows, slot_of_row, staging_slot_of_row,
     return q * s
 
 
+def ref_tiered_gather(tier, row, tables, scale=None):
+    """Oracle of ``mtl_gather_tiered`` (one-hot): slot ``i`` reads row
+    ``row[i]`` of ``tables[tier[i]]``, or zero when ``tier[i] < 0``;
+    int8 tables dequantize by the per-slot ``scale``.
+
+    Args:
+        tier:   (R,) int32 table index per slot, -1 = zero.
+        row:    (R,) int32 row within that table.
+        tables: (n_i, d) row tables (logical, not packed).
+        scale:  (R,) fp32 per-slot scales (int8 tables only).
+
+    Returns:
+        (R, d) rows (float32 when dequantizing).
+    """
+    out = jnp.zeros((tier.shape[0], tables[0].shape[1]), tables[0].dtype)
+    for t, table in enumerate(tables):
+        vals = jnp.take(table, row, axis=0, mode="clip")
+        out = jnp.where((tier == t)[:, None], vals, out)
+    if scale is not None:
+        out = out.astype(jnp.float32) * scale[:, None]
+    return jnp.where((tier >= 0)[:, None], out, jnp.zeros_like(out))
+
+
 def ref_dense_matmul_q8(hq, hscale, wq, wscale, bias, relu: bool = True):
     """Quantized dense-layer oracle — the int8 MLP matmul.
 

@@ -53,8 +53,7 @@ def run_cell(arch: str, shape: str, mesh_name: str, out_dir: str | None,
         },
     }
     print(compiled.memory_analysis())
-    from repro.compat import cost_analysis
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     print({k: ca[k] for k in ("flops", "bytes accessed")
            if k in ca})
     if roofline:

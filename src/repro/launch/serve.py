@@ -298,6 +298,8 @@ def main() -> None:
                          "uniform random ids)")
     ap.add_argument("--max-new", type=int, default=16)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.mode == "ctr":
         serve_ctr(args)
     else:

@@ -204,9 +204,10 @@ def bce_loss(logits: jax.Array, labels: jax.Array) -> jax.Array:
 
 def _dispatch(pallas_fn: Callable, jnp_fn: Callable) -> Callable:
     """Use the Pallas kernel on TPU, identical jnp math elsewhere (the CPU
-    benchmarks must not time interpret mode)."""
+    benchmarks must not time interpret mode, and GSPMD cannot partition a
+    kernel inside a mesh plan)."""
     def f(*args):
-        if kops.on_tpu():
+        if kops.use_pallas():
             return pallas_fn(*args)
         return jnp_fn(*args)
     return f
@@ -216,7 +217,7 @@ def _cross_v2_tail(x0, xw, x=None):
     # layer 0 dedups x_l == x0 into a 2-arg call
     if x is None:
         x = x0
-    if kops.on_tpu():
+    if kops.use_pallas():
         return kops.fused_cross_v2(x0, xw, x)
     return kref.ref_cross_v2_elementwise(x0, xw, x)
 

@@ -92,7 +92,7 @@ def _make_v1_kernel(bias, first: bool):
     def f(x0, xlw, x=None):
         if x is None:
             x = x0
-        if kops.on_tpu():
+        if kops.use_pallas():
             return kops.fused_cross_v1(x0, xlw, bias, x)
         return kref.ref_cross_v1_elementwise(x0, xlw, bias, x)
     return f

@@ -20,7 +20,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
+from jax import shard_map
 
 Shard = Callable[[jax.Array, tuple[str | None, ...]], jax.Array]
 

@@ -869,7 +869,8 @@ class InferenceEngine:
         return self
 
     def stop(self, flush: bool = True) -> None:
-        """Stop the worker (joins the thread). With ``flush`` (default),
+        """Stop the worker (joins the thread, and a staging store's
+        prefetch worker). With ``flush`` (default),
         force-drain whatever is still queued so no future is left
         unresolved. Re-raises the last error a background drain swallowed
         (the failing batch's futures were already failed at the time;
@@ -883,6 +884,11 @@ class InferenceEngine:
             worker.join()
         if flush:
             self.flush()
+        staging = self._staging_store
+        if staging is not None:
+            # join the prefetch worker too: no thread may outlive the
+            # engine mid-upload (later hints restart it)
+            staging.pipeline.stop()
         err, self.worker_error = self.worker_error, None
         if err is not None:
             raise err

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import quant
-from repro.compat import shard_map
+from jax import shard_map
 
 __all__ = ["compressed_psum_mean", "make_compressed_dp_step", "BLOCK"]
 

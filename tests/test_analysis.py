@@ -2,12 +2,12 @@
 with known ground truth."""
 
 import numpy as np
+import pytest
 import jax
 import jax.numpy as jnp
 
 from repro.analysis.hlo_parse import parse_hlo
 from repro.analysis.analytic import model_flops, param_stats
-from repro.compat import cost_analysis
 
 
 def test_dot_flops_loop_corrected():
@@ -25,7 +25,7 @@ def test_dot_flops_loop_corrected():
     expect = 2 * n**3 * L
     assert abs(st.dot_flops - expect) / expect < 0.01
     # raw cost_analysis counts the body once — the analyzer must not
-    assert cost_analysis(c)["flops"] < expect / 2
+    assert c.cost_analysis()["flops"] < expect / 2
     assert st.trip_counts == [L]
 
 
@@ -94,7 +94,7 @@ def test_moe_active_params():
 
 
 def test_analyze_cell_int8_companion_terms():
-    """The int8 twin of each roofline cell: matmuls at the doubled MXU
+    """The int8 twin of each roofline cell: matmuls at the int8 MXU
     peak, the weights-read HBM component at ~1/4 bytes, both arithmetic
     intensities populated (int8 strictly higher — same FLOPs over fewer
     bytes)."""
@@ -110,14 +110,16 @@ def test_analyze_cell_int8_companion_terms():
         jax.ShapeDtypeStruct((n, n), jnp.float32)).compile()
     rep = analyze_cell("llama3-8b", "train_4k", "pod", 512, c)
 
-    np.testing.assert_allclose(rep.compute_s_int8, rep.compute_s / 2.0,
-                               rtol=1e-12)
+    v5e = hw.chip("TPU v5 lite")
+    np.testing.assert_allclose(
+        rep.compute_s_int8,
+        rep.compute_s * v5e.peak_flops_bf16 / v5e.peak_ops_int8, rtol=1e-12)
     an = analytic_cost("llama3-8b", "train_4k", 512, rep.n_micro)
     w_read = an.components["weights_read"]
     assert w_read > 0
     np.testing.assert_allclose(
         rep.memory_s_int8,
-        (an.hbm_bytes_per_device - 0.75 * w_read) / hw.HBM_BW, rtol=1e-12)
+        (an.hbm_bytes_per_device - 0.75 * w_read) / v5e.hbm_bw, rtol=1e-12)
     assert rep.memory_s_int8 < rep.memory_s
     assert rep.arith_intensity_int8 > rep.arith_intensity > 0.0
     # the dry-run record schema: new fields serialize with the rest
@@ -125,3 +127,15 @@ def test_analyze_cell_int8_companion_terms():
     for k in ("compute_s_int8", "memory_s_int8", "arith_intensity",
               "arith_intensity_int8"):
         assert k in d
+
+
+def test_unknown_device_kind_is_an_error():
+    """Peaks come from the published table, keyed by device kind; a chip
+    missing from it must fail loudly, never fall back to v5e numbers."""
+    from repro.analysis import hw
+    v5e = hw.chip("TPU v5 lite")
+    assert (v5e.peak_flops_bf16, v5e.peak_ops_int8, v5e.hbm_bw) == \
+        (197e12, 393e12, 819e9)
+    assert v5e.source
+    with pytest.raises(ValueError, match="no published peaks"):
+        hw.chip("TPU v99")

@@ -214,6 +214,21 @@ def test_prefetch_worker_resolves_hinted_misses():
     assert store.stats.prefetched_rows >= miss.size
 
 
+def test_prefetch_worker_counts_its_errors():
+    """A failing speculative prefetch is not swallowed silently: the
+    worker counts it (serving runs fail on the count) and keeps going."""
+    _, _, _, _, store = make_pair(capacity=4, staging_capacity=128)
+
+    def broken(rows):
+        raise RuntimeError("backing unreadable")
+    store.pipeline._prefetch = broken
+    store.prefetch_hint(np.asarray(traffic(batch=4, seed=1)))
+    assert store.pipeline.wait_idle(timeout=10.0)
+    store.pipeline.stop()
+    assert store.pipeline.n_errors == 1
+    assert "backing unreadable" in str(store.pipeline.last_error)
+
+
 def test_refresh_promotes_hot_staged_rows_out_of_staging():
     _, _, hosted, ph, store = make_pair(capacity=4, staging_capacity=64)
     # ids whose global rows all miss the seeded cache (rows 0..3)

@@ -179,9 +179,9 @@ def test_dense_store_adopts_quantized_subtree():
     pq = store.init(jax.random.PRNGKey(2))
     dense = DenseStore(SPEC)
     pd = dense.adopt(pq)
-    want = quant.dequantize_rows(np.asarray(pq["backing"]),
+    want = quant.dequantize_rows(np.asarray(store.unpack(pq["backing"])),
                                  np.asarray(pq["backing_scale"]))
-    np.testing.assert_array_equal(np.asarray(pd["mega_table"]), want)
+    np.testing.assert_array_equal(np.asarray(dense.dense_view(pd)), want)
 
 
 def test_collection_accepts_quantized_store_over_fp32_spec():

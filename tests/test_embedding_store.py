@@ -196,7 +196,11 @@ def test_cached_model_on_mesh_matches_dense(model_name):
         spec, store=CachedStore(spec.embedding_spec(), capacity=128))
     cparams = cmodel.init(jax.random.PRNGKey(0))
     got = compile_plan(cmodel, cparams, "dual", 16).predict(ids)
-    np.testing.assert_array_equal(got, want)
+    # same rows, but two different programs: the dense plan compiles its
+    # table in as a constant, the cached plan takes it as an argument, and
+    # XLA fuses the dcnv2 dense net differently around each — scores may
+    # differ in the last bit or two (seen: 1 ulp = 5.96e-8 on 2 of 16)
+    np.testing.assert_allclose(got, want, rtol=4e-7, atol=0)
 
     mesh = make_mesh((1, 1), ("data", "model"))
     got_mesh = compile_plan(cmodel, cparams, "dual", 16,
@@ -274,3 +278,38 @@ def test_dense_engine_refresh_every_is_a_noop():
     assert eng.stats.cache_misses == 1           # compiled exactly once
     assert eng.stats.emb_cache_refreshes == 0
     assert eng.stats.emb_cached_traffic_fraction == 0.0
+
+
+def _mesh_or_skip(shape):
+    n = int(np.prod(shape))
+    if jax.device_count() < n:
+        pytest.skip(f"needs {n} devices (run under XLA_FLAGS="
+                    "--xla_force_host_platform_device_count=8)")
+    return make_mesh(shape, ("data", "model"))
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+@pytest.mark.parametrize("kind", ["dense", "cached", "cached_int8"])
+def test_lookup_sharded_pallas_matches_single_device(kind, mesh_shape):
+    """The vocab-parallel shard_map lookup (what a mesh plan runs, so the
+    Pallas gather never sees a row-sharded operand) with the Pallas
+    kernels in interpret mode equals the single-device lookup bitwise:
+    each row comes from exactly one shard, the psum adds only zeros."""
+    mesh = _mesh_or_skip(mesh_shape)
+    dense = DenseStore(SPEC)
+    params = dense.init(jax.random.PRNGKey(3))
+    store = {"dense": dense,
+             "cached": CachedStore(SPEC, capacity=48),
+             "cached_int8": CachedStore(SPEC, capacity=48,
+                                        row_dtype="int8")}[kind]
+    params = store.adopt(params)
+    offsets = jnp.asarray(SPEC.offsets)
+    ids = jnp.asarray(np.stack(
+        [np.random.default_rng(i).integers(0, n, size=8)
+         for i, n in enumerate(SPEC.field_sizes)], axis=1), jnp.int32)
+    want = store.lookup(params, ids, offsets, strategy="pallas",
+                        interpret=True)
+    placed = store.place(params, mesh)
+    got = jax.jit(lambda p, i: store.lookup_sharded(
+        p, i, offsets, mesh, strategy="pallas", interpret=True))(placed, ids)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

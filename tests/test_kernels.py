@@ -12,9 +12,9 @@ import jax.numpy as jnp
 from repro.kernels import ops, ref
 from repro.kernels.multi_table_lookup import (
     mtl_gather,
-    mtl_gather_multihot,
-    mtl_input_first,
     mtl_onehot,
+    pack_rows,
+    unpack_rows,
 )
 
 TOL = dict(rtol=1e-5, atol=1e-5)
@@ -64,7 +64,8 @@ def test_mtl_gather_sweep(b, k, d, dtype):
     ids = make_ids(rng, sizes, b)
     want = ref.ref_multi_table_lookup(ids, mega, offsets, k)
     rows = (ids + offsets[None, :]).reshape(-1)
-    got = mtl_gather(rows, mega, interpret=True).reshape(b, k * d)
+    got = mtl_gather(rows, pack_rows(mega), dim=d, dtype=dtype,
+                     interpret=True).reshape(b, k * d)
     tol = BF16_TOL if dtype == jnp.bfloat16 else TOL
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **tol)
@@ -571,3 +572,161 @@ def test_dense_matmul_q8_batch_grid_tiling():
     tiled = dmm_q8(hq, hscale, wq, wscale, bias.reshape(1, -1),
                    block_b=8, interpret=True)
     np.testing.assert_array_equal(np.asarray(one), np.asarray(tiled))
+
+
+# ---------------------------------------------------------------------------
+# packed (lane-dense) table layout + every production gather vs its twin
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype,d", [(jnp.float32, 1), (jnp.float32, 16),
+                                     (jnp.float32, 32), (jnp.int8, 16),
+                                     (jnp.int8, 32), (jnp.bfloat16, 8)])
+def test_pack_rows_round_trip(dtype, d):
+    """pack -> unpack is the identity on every row (plus zero padding up
+    to whole 128-lane lines), for jax and numpy tables alike."""
+    rng = np.random.default_rng(d)
+    n = 77
+    rows = np.asarray(rng.normal(size=(n, d)) * 50).astype(
+        np.dtype(dtype))
+    for table in (jnp.asarray(rows), rows):
+        lines = pack_rows(table)
+        assert lines.shape[1] == 128 and lines.dtype.itemsize == 4
+        back = np.asarray(unpack_rows(lines, d, np.dtype(dtype)))
+        np.testing.assert_array_equal(back[:n], rows)
+        assert not back[n:].any()
+
+
+def test_set_rows_touches_only_the_given_rows():
+    from repro.kernels.multi_table_lookup import set_rows
+    rng = np.random.default_rng(0)
+    table = rng.normal(size=(64, 16)).astype(np.float32)
+    ids = np.array([0, 5, 7, 63])
+    new = rng.normal(size=(4, 16)).astype(np.float32)
+    got = unpack_rows(set_rows(pack_rows(jnp.asarray(table)), ids, new),
+                      16, np.float32)
+    table[ids] = new
+    np.testing.assert_array_equal(np.asarray(got), table)
+
+
+def _tiered_case(rng, d, fmt):
+    sizes = [300, 45, 700]
+    n = sum(sizes) + 1
+    table = (rng.normal(size=(n, d)) * 0.05).astype(np.float32)
+    table[-1] = 0
+    scale = None
+    if fmt == "int8":
+        from repro import quant
+        q, scale = quant.quantize_rows(table)
+        table, scale = np.asarray(q), np.asarray(scale)
+    offsets = jnp.asarray(np.cumsum([0] + sizes[:-1]), jnp.int32)
+    pick = rng.choice(n, size=240, replace=False)
+    hot, warm = pick[:96], pick[96:]
+    cmap = np.full(n, -1, np.int32)
+    cmap[hot] = np.arange(hot.size)
+    smap = np.full(n, -1, np.int32)
+    smap[warm] = np.arange(warm.size)
+    ids = jnp.asarray(np.stack([rng.integers(0, s, size=40) for s in sizes],
+                               axis=1), jnp.int32)
+    return table, scale, offsets, hot, warm, cmap, smap, ids
+
+
+@pytest.mark.parametrize("d", [16, 32])
+@pytest.mark.parametrize("fmt", ["fp32", "int8"])
+@pytest.mark.parametrize("tier", ["dense", "two_level", "three_level"])
+def test_gather_matches_ref_twin_bitwise(tier, fmt, d):
+    """Every production gather over *packed* tables (the store layout),
+    interpret mode, is bitwise equal to its jnp twin in ``kernels/ref.py``
+    at the paper's widths d ∈ {16, 32}."""
+    rng = np.random.default_rng(d + len(tier) + len(fmt))
+    table, scale, offsets, hot, warm, cmap, smap, ids = _tiered_case(
+        rng, d, fmt)
+    rows = (ids + offsets[None, :]).reshape(-1)
+    pk = lambda a: pack_rows(jnp.asarray(a))
+    if tier == "dense":
+        got = mtl_gather(rows, pk(table), dim=d, dtype=table.dtype,
+                         interpret=True)
+        want = jnp.take(jnp.asarray(table), rows, axis=0)
+    elif tier == "two_level" and fmt == "fp32":
+        got = ops.multi_table_lookup_cached(
+            ids, pk(table[hot]), pk(table), jnp.asarray(cmap), offsets,
+            strategy="pallas", interpret=True, dim=d)
+        want = ref.ref_two_level_gather(rows, jnp.asarray(cmap),
+                                        jnp.asarray(table[hot]),
+                                        jnp.asarray(table))
+    elif tier == "two_level":
+        got = ops.multi_table_lookup_cached_q8(
+            ids, pk(table[hot]), jnp.asarray(scale[hot]), pk(table),
+            jnp.asarray(scale), jnp.asarray(cmap), offsets,
+            strategy="pallas", interpret=True, dim=d)
+        want = ref.ref_two_level_gather_q8(
+            rows, jnp.asarray(cmap), jnp.asarray(table[hot]),
+            jnp.asarray(scale[hot]), jnp.asarray(table), jnp.asarray(scale))
+    elif fmt == "fp32":
+        got = ops.multi_table_lookup_host(
+            ids, pk(table[hot]), pk(table[warm]), jnp.asarray(cmap),
+            jnp.asarray(smap), offsets, strategy="pallas", interpret=True,
+            dim=d)
+        want = ref.ref_three_level_gather(
+            rows, jnp.asarray(cmap), jnp.asarray(smap),
+            jnp.asarray(table[hot]), jnp.asarray(table[warm]))
+    else:
+        got = ops.multi_table_lookup_host_q8(
+            ids, pk(table[hot]), jnp.asarray(scale[hot]), pk(table[warm]),
+            jnp.asarray(scale[warm]), jnp.asarray(cmap), jnp.asarray(smap),
+            offsets, strategy="pallas", interpret=True, dim=d)
+        want = ref.ref_three_level_gather_q8(
+            rows, jnp.asarray(cmap), jnp.asarray(smap),
+            jnp.asarray(table[hot]), jnp.asarray(scale[hot]),
+            jnp.asarray(table[warm]), jnp.asarray(scale[warm]))
+    np.testing.assert_array_equal(np.asarray(got).reshape(want.shape),
+                                  np.asarray(want))
+
+
+@pytest.mark.parametrize("d", [16, 32])
+@pytest.mark.parametrize("tier,fmt", [("dense", "fp32"),
+                                      ("two_level", "fp32"),
+                                      ("three_level", "fp32")])
+def test_pooled_gather_matches_ref_twin_bitwise(tier, fmt, d):
+    """The multi-hot (sum-pooled) fp32 gathers over packed tables equal
+    their jnp twins bitwise: both add a row's slots in slot order, and a
+    masked slot adds an exact zero. (The int8 forms differ from their
+    twins in the last bits in interpret mode on CPU: XLA's CPU backend
+    fuses the kernel's dequantizing multiply and the pooled add into one
+    multiply-add, rounded once. Their tests above compare at rtol 1e-6;
+    on a TPU they matched bitwise.)"""
+    rng = np.random.default_rng(d + len(tier) + len(fmt))
+    table, scale, offsets, hot, warm, cmap, smap, _ = _tiered_case(
+        rng, d, fmt)
+    h = 3
+    ids = jnp.asarray(np.stack(
+        [rng.integers(0, s, size=(40, h)) for s in (300, 45, 700)], axis=1),
+        jnp.int32)
+    mask = jnp.asarray(rng.integers(0, 2, size=ids.shape), jnp.float32)
+    pk = lambda a: pack_rows(jnp.asarray(a))
+    cm, sm = jnp.asarray(cmap), jnp.asarray(smap)
+    if fmt == "int8":
+        cs, ss, bs = (jnp.asarray(scale[hot]), jnp.asarray(scale[warm]),
+                      jnp.asarray(scale))
+
+    def run(strategy):
+        kw = dict(strategy=strategy, interpret=True, dim=d)
+        if tier == "dense":
+            return ops.multi_table_lookup_multihot(ids, mask, pk(table),
+                                                   offsets, **kw)
+        if tier == "two_level" and fmt == "fp32":
+            return ops.multi_table_lookup_cached_multihot(
+                ids, mask, pk(table[hot]), pk(table), cm, offsets, **kw)
+        if tier == "two_level":
+            return ops.multi_table_lookup_cached_q8_multihot(
+                ids, mask, pk(table[hot]), cs, pk(table), bs, cm, offsets,
+                **kw)
+        if fmt == "fp32":
+            return ops.multi_table_lookup_host_multihot(
+                ids, mask, pk(table[hot]), pk(table[warm]), cm, sm, offsets,
+                **kw)
+        return ops.multi_table_lookup_host_q8_multihot(
+            ids, mask, pk(table[hot]), cs, pk(table[warm]), ss, cm, sm,
+            offsets, **kw)
+
+    np.testing.assert_array_equal(np.asarray(run("pallas")),
+                                  np.asarray(run("jnp")))

@@ -18,12 +18,12 @@ import threading
 import numpy as np
 import pytest
 import jax
-import jax.numpy as jnp
 
 from repro.configs import ctr_spec
 from repro.data.synthetic import CRITEO, zipf_ids
 from repro.embedding import CachedStore, DenseStore, HostBackedStore
 from repro.embedding.store import validate_deltas
+from repro.kernels.multi_table_lookup import pack_rows
 from repro.models.ctr import CTR_MODELS
 from repro.serving import (DeltaBuffer, FixedBatch, InferenceEngine,
                            ServingRuntime, SyntheticTrainer)
@@ -140,14 +140,15 @@ def test_pushed_scores_bitexact_with_rebuilt_dense_reference(store_cls):
     eng.predict(ids)                          # pin the plan first
 
     ref_model, ref_params = fresh_model()
-    table = np.array(ref_params[ref_model.main_embedding_key]["mega_table"])
+    table = np.array(ref_model.embedding.dense_view(
+        ref_params[ref_model.main_embedding_key]))
     for seed in range(3):
         d_ids, d_rows = deltas(32, seed=seed)
         eng.push_update(d_ids, d_rows)
         table[d_ids] = d_rows
     key = ref_model.main_embedding_key
     ref_params = {**ref_params,
-                  key: {**ref_params[key], "mega_table": jnp.asarray(table)}}
+                  key: {**ref_params[key], "mega_table": pack_rows(table)}}
     ref = InferenceEngine(ref_model, ref_params, policy=FixedBatch(16))
     np.testing.assert_array_equal(eng.predict(ids), ref.predict(ids))
     assert eng.stats.emb_version == 3
@@ -164,7 +165,8 @@ def test_int8_requant_parity_with_cold_store(store_cls):
     quant_before = eng.store.stats.quant_rows
 
     ref_model, ref_params = fresh_model()
-    table = np.array(ref_params[ref_model.main_embedding_key]["mega_table"])
+    table = np.array(ref_model.embedding.dense_view(
+        ref_params[ref_model.main_embedding_key]))
     d_ids, d_rows = deltas(48, seed=7)
     eng.push_update(d_ids, d_rows)
     table[d_ids] = d_rows
@@ -172,7 +174,7 @@ def test_int8_requant_parity_with_cold_store(store_cls):
 
     key = ref_model.main_embedding_key
     ref_params = {**ref_params,
-                  key: {**ref_params[key], "mega_table": jnp.asarray(table)}}
+                  key: {**ref_params[key], "mega_table": pack_rows(table)}}
     ref = InferenceEngine(ref_model, ref_params, policy=FixedBatch(16),
                           store=store_cls(ESPEC, capacity=64,
                                           row_dtype="int8"))

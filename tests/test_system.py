@@ -161,3 +161,27 @@ def test_data_pipeline_determinism():
     assert np.array_equal(np.asarray(a["ids"]), np.asarray(b["ids"]))
     c = synthetic_batch(SCHEMA, 8, 32)
     assert not np.array_equal(np.asarray(a["ids"]), np.asarray(c["ids"]))
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch):
+    """Entry points turn on the persistent compile cache: a set
+    JAX_COMPILATION_CACHE_DIR is left to JAX (nothing set in code);
+    otherwise a fixed directory in the checkout, never a temp name."""
+    import pathlib
+    from repro.compile_cache import MAX_BYTES, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    size_before = jax.config.jax_compilation_cache_max_size
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "cache-from-env")
+        assert enable_compile_cache() == "cache-from-env"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = enable_compile_cache()
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert path == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert jax.config.jax_compilation_cache_max_size == MAX_BYTES
+        assert enable_compile_cache() == path           # stable per checkout
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_compilation_cache_max_size", size_before)
