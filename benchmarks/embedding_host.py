@@ -48,6 +48,7 @@ import jax
 from repro.configs import ctr_spec
 from repro.data.synthetic import CRITEO, zipf_ids
 from repro.embedding import HostBackedStore
+from repro.kernels.multi_table_lookup import rows_per_line
 from repro.models.ctr import CTR_MODELS
 from repro.serving import FixedBatch, InferenceEngine
 
@@ -114,8 +115,11 @@ def _cell(vocab: int, capacity: int, exponent: float, n: int, batch: int,
     key = eng.model.main_embedding_key
     dev_bytes = store.device_bytes(eng.params[key])
     row_bytes = store.wire_row_bytes              # dtype-aware (d+4 for int8)
-    budget = ((store.capacity + store.staging_capacity) * row_bytes
-              + 2 * store.spec.rows * 4)          # the two int32 maps
+    # cache and staging rows are stored in whole 128-lane packed lines
+    rpl = rows_per_line(store.spec.dim, store.wire_dtype)
+    slots = sum(-(-n // rpl) * rpl
+                for n in (store.capacity, store.staging_capacity))
+    budget = slots * row_bytes + 2 * store.spec.rows * 4   # + the int32 maps
     out_of_hbm = store.spec.rows > store.capacity + store.staging_capacity
     if out_of_hbm:
         assert dev_bytes <= budget, (dev_bytes, budget)

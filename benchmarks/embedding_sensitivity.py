@@ -30,7 +30,7 @@ def _pair(emb, params, ids, tag: str) -> float:
     # params passed as arguments (a closure would bake the mega-table into
     # the executable as multi-GB constants)
     fused = jax.jit(lambda p, i: emb.apply(p, i, strategy="jnp"))
-    serial = jax.jit(lambda p, i: emb.apply(p, i, strategy="serial"))
+    serial = jax.jit(emb.apply_serial)
     tf = time_fn(fused, params, ids, reps=3, warmup=1)
     ts = time_fn(serial, params, ids, reps=3, warmup=1)
     emit(f"emb/{tag}/serial", ts)

@@ -77,13 +77,16 @@ class RooflineReport:
         return json.dumps(dataclasses.asdict(self), indent=1, default=str)
 
 
+#: device kind of the production pods the dry runs describe (v5e). The
+#: dry runs compile on the host, so the executable cannot name its chip.
+TARGET = "TPU v5 lite"
+
+
 def analyze_cell(arch: str, shape: str, mesh_name: str, chips: int,
-                 compiled, n_micro: int = 1,
-                 device_kind: str = "TPU v5 lite") -> RooflineReport:
+                 compiled, n_micro: int = 1) -> RooflineReport:
     """Roofline terms of one compiled cell against the peaks of
-    ``device_kind`` (``hw.CHIPS``; default: the v5e the dry runs
-    target)."""
-    peaks = hw.chip(device_kind)
+    :data:`TARGET` (``hw.CHIPS``)."""
+    peaks = hw.chip(TARGET)
     text = compiled.as_text()
     stats = parse_hlo(text)
     ca = compiled.cost_analysis()

@@ -16,10 +16,10 @@ __all__ = ["enable_compile_cache"]
 
 #: the checkout root (``src/repro/compile_cache.py`` -> ``.``)
 _CHECKOUT = pathlib.Path(__file__).resolve().parents[2]
-#: size cap of the checkout's cache. A ``DenseStore`` plan compiles its
-#: table in as a constant (2.4 GB per bucket at full Criteo, d=32): such
-#: entries are skipped rather than written into the checkout; every other
-#: plan is tens of MB.
+#: size cap of the checkout's cache: an executable that compiles a full
+#: table in as a constant (a ``DenseStore`` plan; on a v5e at full Criteo,
+#: d=32, its step reports 870,556,160 B of generated code) can be close to
+#: the table's size, while the tiered plans are tens of MB
 MAX_BYTES = 1 << 30
 
 

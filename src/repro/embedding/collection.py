@@ -90,9 +90,8 @@ class FusedEmbeddingCollection:
 
     def apply_serial(self, params: dict, ids: jax.Array) -> jax.Array:
         """Baseline: k separate gathers + concat (PyTorch-A analogue)."""
-        return kops.multi_table_lookup(
-            ids, self.store.dense_view(params), self._offsets,
-            strategy="serial")
+        return kops.multi_table_lookup_serial(
+            ids, self.store.dense_view(params), self._offsets)
 
     # -- traffic observation -------------------------------------------------
     def observe(self, ids: np.ndarray) -> None:

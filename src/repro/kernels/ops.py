@@ -6,14 +6,19 @@ compiled; on this CPU container the same math runs either through
 fused path (identical algorithm at the XLA level — one gather over the
 mega-table — which is what the CPU benchmarks time).
 
-Strategies for ``multi_table_lookup``:
+Strategies for the lookup wrappers:
 
   "auto"        pallas on TPU (outside a mesh program), jnp elsewhere
   "pallas"      output-first Pallas gather (Alg. 1)          [C2+C3]
-  "onehot"      one-hot MXU matmul (small fields)            [TPU-native]
   "jnp"         vectorized single-gather over the mega-table [C2 at XLA level]
-  "serial"      per-field loop + concat (the paper's PyTorch baseline)
-  "input_first" Fig.-11 strawman (field-major writes + transpose)
+
+Every lookup wrapper takes its tables packed lane-dense (the layout the
+stores keep, ``multi_table_lookup.pack_rows``) plus their row width
+``dim``. The benchmark strawmen take a logical ``(N, d)`` table instead:
+:func:`multi_table_lookup_serial` (per-field loop + concat, the paper's
+PyTorch baseline), :func:`multi_table_lookup_input_first` (Fig.-11
+field-major writes + transpose) and :func:`multi_table_lookup_onehot`
+(one-hot MXU matmul over small stacked fields).
 """
 
 from __future__ import annotations
@@ -34,7 +39,6 @@ from .fused_fm import fused_fm_second_order
 from .multi_table_lookup import (
     mtl_gather,
     mtl_gather_tiered,
-    pack_rows,
     rows_per_line,
     unpack_rows,
     mtl_gather_multihot,
@@ -48,6 +52,9 @@ from .multi_table_lookup import (
 
 __all__ = [
     "multi_table_lookup",
+    "multi_table_lookup_serial",
+    "multi_table_lookup_input_first",
+    "multi_table_lookup_onehot",
     "multi_table_lookup_multihot",
     "multi_table_lookup_cached",
     "multi_table_lookup_cached_multihot",
@@ -103,71 +110,15 @@ def use_pallas() -> bool:
     return on_tpu() and active_mesh() is None
 
 
-def _packed(table: jax.Array, dim: int | None) -> tuple[jax.Array, int]:
-    """``(lines, d)`` for the Pallas gathers. Stores hand over tables
-    already packed (``dim`` given, see ``multi_table_lookup.pack_rows``);
-    a logical ``(N, d)`` table (``dim=None``) is packed here, per call."""
-    if dim is None:
-        return pack_rows(table), table.shape[1]
-    return table, dim
-
-
-def _logical(table: jax.Array, dim: int | None, dtype=None) -> jax.Array:
-    """The ``(N, d)`` row view of a packed (``dim`` given) or logical
-    table, for the jnp twins. ``dtype`` defaults to the packed dtype."""
-    if dim is None:
-        return table
+def _logical(table: jax.Array, dim: int, dtype=None) -> jax.Array:
+    """The ``(N, d)`` row view of a packed table, for the jnp twins.
+    ``dtype`` defaults to the packed dtype."""
     return unpack_rows(table, dim, dtype or table.dtype)
 
 
 def _flat_rows(ids: jax.Array, offsets: jax.Array) -> jax.Array:
     """Alg. 1 lines 6–7 vectorized: local id -> global mega-table row."""
     return (ids.astype(jnp.int32) + offsets[None, :].astype(jnp.int32)).reshape(-1)
-
-
-def multi_table_lookup(ids: jax.Array, mega_table: jax.Array,
-                       offsets: jax.Array, *, strategy: str = "auto",
-                       interpret: bool | None = None,
-                       dim: int | None = None) -> jax.Array:
-    """Fused multi-table embedding lookup (paper Algorithm 1).
-
-    Args:
-        ids:        (b, k) int32 per-field local ids.
-        mega_table: (N, d) concatenated tables, or their packed lines
-                    (``multi_table_lookup.pack_rows``) when ``dim`` is given.
-        offsets:    (k,) int32 starting row of each table.
-        strategy:   see module docstring.
-        interpret:  force Pallas interpret mode (defaults to not-on-TPU).
-        dim:        row width d of a packed ``mega_table``.
-
-    Returns:
-        (b, k*d) embedding output.
-    """
-    b, k = ids.shape
-    if interpret is None:
-        interpret = not on_tpu()
-    if strategy == "auto":
-        strategy = "pallas" if use_pallas() else "jnp"
-
-    if strategy == "pallas":
-        lines, d = _packed(mega_table, dim)
-        rows = _flat_rows(ids, offsets)
-        return mtl_gather(rows, lines, dim=d,
-                          interpret=interpret).reshape(b, k * d)
-    table = _logical(mega_table, dim)
-    if strategy == "jnp":
-        return ref.ref_multi_table_lookup(ids, table, offsets, k)
-    if strategy == "input_first":
-        rows = _flat_rows(ids, offsets)
-        return mtl_input_first(rows, table, k=k, interpret=interpret)
-    if strategy == "serial":
-        # per-field gathers off the concatenated table (baseline semantics;
-        # the k separate gathers are the cost)
-        cols = []
-        for i in range(k):
-            cols.append(jnp.take(table, ids[:, i] + offsets[i], axis=0))
-        return jnp.concatenate(cols, axis=1)
-    raise ValueError(f"unknown strategy {strategy!r}")
 
 
 def _resolve(strategy: str, interpret: bool | None) -> tuple[str, bool]:
@@ -178,6 +129,56 @@ def _resolve(strategy: str, interpret: bool | None) -> tuple[str, bool]:
     if strategy not in ("jnp", "pallas"):
         raise ValueError(f"unknown strategy {strategy!r}")
     return strategy, interpret
+
+
+def multi_table_lookup(ids: jax.Array, mega_table: jax.Array,
+                       offsets: jax.Array, *, dim: int,
+                       strategy: str = "auto",
+                       interpret: bool | None = None) -> jax.Array:
+    """Fused multi-table embedding lookup (paper Algorithm 1).
+
+    Args:
+        ids:        (b, k) int32 per-field local ids.
+        mega_table: the (N, d) concatenated tables, packed
+                    (``multi_table_lookup.pack_rows``).
+        offsets:    (k,) int32 starting row of each table.
+        dim:        row width d.
+        strategy:   see module docstring.
+        interpret:  force Pallas interpret mode (defaults to not-on-TPU).
+
+    Returns:
+        (b, k*d) embedding output.
+    """
+    b, k = ids.shape
+    strategy, interpret = _resolve(strategy, interpret)
+    if strategy == "jnp":
+        return ref.ref_multi_table_lookup(ids, _logical(mega_table, dim),
+                                          offsets, k)
+    return mtl_gather(_flat_rows(ids, offsets), mega_table, dim=dim,
+                      interpret=interpret).reshape(b, k * dim)
+
+
+def multi_table_lookup_serial(ids: jax.Array, table: jax.Array,
+                              offsets: jax.Array) -> jax.Array:
+    """Baseline: per-field gathers off a logical ``(N, d)`` table + concat
+    (the paper's PyTorch baseline semantics; the k separate gathers are
+    the cost)."""
+    cols = [jnp.take(table, ids[:, i] + offsets[i], axis=0)
+            for i in range(ids.shape[1])]
+    return jnp.concatenate(cols, axis=1)
+
+
+def multi_table_lookup_input_first(ids: jax.Array, table: jax.Array,
+                                   offsets: jax.Array, *,
+                                   interpret: bool | None = None
+                                   ) -> jax.Array:
+    """The Fig.-11 input-first strawman over a logical ``(N, d)`` table
+    (benchmark comparisons only; Mosaic refuses its ``(1, d)`` row blocks,
+    so it runs in interpret mode)."""
+    if interpret is None:
+        interpret = not on_tpu()
+    return mtl_input_first(_flat_rows(ids, offsets), table, k=ids.shape[1],
+                           interpret=interpret)
 
 
 def _masked_rows(ids: jax.Array, mask: jax.Array, offsets: jax.Array,
@@ -200,7 +201,7 @@ def multi_table_lookup_cached(ids: jax.Array, cache: jax.Array,
                               backing: jax.Array, slot_of_row: jax.Array,
                               offsets: jax.Array, *, strategy: str = "auto",
                               interpret: bool | None = None,
-                              dim: int | None = None) -> jax.Array:
+                              dim: int) -> jax.Array:
     """Fused lookup through a tiered (cache + backing) embedding store.
 
     The CachedStore analogue of :func:`multi_table_lookup`: one two-level
@@ -210,11 +211,11 @@ def multi_table_lookup_cached(ids: jax.Array, cache: jax.Array,
 
     Args:
         ids:         (b, k) int32 per-field local ids.
-        cache:       (C, d) hot-row copies (packed when ``dim`` is given).
-        backing:     (N, d) full mega-table (packed when ``dim`` is given).
+        cache:       (C, d) hot-row copies, packed.
+        backing:     (N, d) full mega-table, packed.
         slot_of_row: (N,) int32 cache slot per global row, -1 = uncached.
         offsets:     (k,) int32 starting row of each table.
-        dim:         row width d of packed tiers.
+        dim:         row width d.
 
     Returns:
         (b, k*d) embedding output.
@@ -227,10 +228,8 @@ def multi_table_lookup_cached(ids: jax.Array, cache: jax.Array,
                                        _logical(cache, dim),
                                        _logical(backing, dim))
     else:
-        cache, d = _packed(cache, dim)
-        backing, _ = _packed(backing, dim)
         slots = jnp.take(slot_of_row, rows, axis=0)
-        out = mtl_gather_two_level(rows, slots, cache, backing, dim=d,
+        out = mtl_gather_two_level(rows, slots, cache, backing, dim=dim,
                                    interpret=interpret)
     return out.reshape(b, -1)
 
@@ -241,7 +240,7 @@ def multi_table_lookup_cached_multihot(ids: jax.Array, mask: jax.Array,
                                        offsets: jax.Array, *,
                                        strategy: str = "auto",
                                        interpret: bool | None = None,
-                                       dim: int | None = None
+                                       dim: int
                                        ) -> jax.Array:
     """Multi-hot (pooled) fused lookup through a tiered store.
 
@@ -253,11 +252,11 @@ def multi_table_lookup_cached_multihot(ids: jax.Array, mask: jax.Array,
     Args:
         ids:         (b, k, h) local ids; invalid slots arbitrary.
         mask:        (b, k, h) 1 for valid slots, 0 otherwise.
-        cache:       (C, d) hot-row copies (packed when ``dim`` is given).
+        cache:       (C, d) hot-row copies, packed.
         backing:     (N, d) full mega-table **with a trailing all-zero row**.
         slot_of_row: (N,) int32 index map.
         offsets:     (k,) table starts.
-        dim:         row width d of packed tiers.
+        dim:         row width d.
 
     Returns:
         (b, k*d) pooled output.
@@ -272,13 +271,11 @@ def multi_table_lookup_cached_multihot(ids: jax.Array, mask: jax.Array,
                                         _logical(cache, dim),
                                         _logical(backing, dim))
         return _pool(vals, mask, b, k, h, vals.shape[-1])
-    cache, d = _packed(cache, dim)
-    backing, _ = _packed(backing, dim)
     rows = _masked_rows(ids, mask, offsets, zero_row)
     slots = jnp.take(slot_of_row, rows, axis=0)
-    out = mtl_gather_two_level(rows, slots, cache, backing, dim=d, hot=h,
+    out = mtl_gather_two_level(rows, slots, cache, backing, dim=dim, hot=h,
                                interpret=interpret)
-    return out.reshape(b, k * d)
+    return out.reshape(b, k * dim)
 
 
 def multi_table_lookup_cached_q8(ids: jax.Array, cache: jax.Array,
@@ -287,7 +284,7 @@ def multi_table_lookup_cached_q8(ids: jax.Array, cache: jax.Array,
                                  slot_of_row: jax.Array, offsets: jax.Array,
                                  *, strategy: str = "auto",
                                  interpret: bool | None = None,
-                                 dim: int | None = None) -> jax.Array:
+                                 dim: int) -> jax.Array:
     """Quantized tiered lookup: int8 cache/backing rows, per-row fp32
     scales, dequantization inside the gather.
 
@@ -298,13 +295,13 @@ def multi_table_lookup_cached_q8(ids: jax.Array, cache: jax.Array,
 
     Args:
         ids:           (b, k) int32 per-field local ids.
-        cache:         (C, d) int8 hot-row copies (packed when ``dim``).
+        cache:         (C, d) int8 hot-row copies, packed.
         cache_scale:   (C, 1) fp32 per-row scales.
-        backing:       (N, d) int8 full mega-table (packed when ``dim``).
+        backing:       (N, d) int8 full mega-table, packed.
         backing_scale: (N, 1) fp32 per-row scales.
         slot_of_row:   (N,) int32 cache slot per global row, -1 = uncached.
         offsets:       (k,) int32 starting row of each table.
-        dim:           row width d of packed tiers.
+        dim:           row width d.
 
     Returns:
         (b, k*d) float32 embedding output.
@@ -317,11 +314,9 @@ def multi_table_lookup_cached_q8(ids: jax.Array, cache: jax.Array,
             rows, slot_of_row, _logical(cache, dim, jnp.int8), cache_scale,
             _logical(backing, dim, jnp.int8), backing_scale)
     else:
-        cache, d = _packed(cache, dim)
-        backing, _ = _packed(backing, dim)
         slots = jnp.take(slot_of_row, rows, axis=0)
         out = mtl_gather_two_level_q8(rows, slots, cache, cache_scale,
-                                      backing, backing_scale, dim=d,
+                                      backing, backing_scale, dim=dim,
                                       interpret=interpret)
     return out.reshape(b, -1)
 
@@ -335,7 +330,7 @@ def multi_table_lookup_cached_q8_multihot(ids: jax.Array, mask: jax.Array,
                                           offsets: jax.Array, *,
                                           strategy: str = "auto",
                                           interpret: bool | None = None,
-                                          dim: int | None = None
+                                          dim: int
                                           ) -> jax.Array:
     """Multi-hot (pooled) quantized tiered lookup.
 
@@ -347,13 +342,13 @@ def multi_table_lookup_cached_q8_multihot(ids: jax.Array, mask: jax.Array,
     Args:
         ids:           (b, k, h) local ids; invalid slots arbitrary.
         mask:          (b, k, h) 1 for valid slots, 0 otherwise.
-        cache:         (C, d) int8 hot-row copies (packed when ``dim``).
+        cache:         (C, d) int8 hot-row copies, packed.
         cache_scale:   (C, 1) fp32 per-row scales.
         backing:       (N, d) int8 mega-table **with a trailing zero row**.
         backing_scale: (N, 1) fp32 per-row scales.
         slot_of_row:   (N,) int32 index map.
         offsets:       (k,) table starts.
-        dim:           row width d of packed tiers.
+        dim:           row width d.
 
     Returns:
         (b, k*d) float32 pooled output.
@@ -366,13 +361,11 @@ def multi_table_lookup_cached_q8_multihot(ids: jax.Array, mask: jax.Array,
             rows, slot_of_row, _logical(cache, dim, jnp.int8), cache_scale,
             _logical(backing, dim, jnp.int8), backing_scale)
         return _pool(vals, mask, b, k, h, vals.shape[-1])
-    cache, d = _packed(cache, dim)
-    backing, _ = _packed(backing, dim)
     slots = jnp.take(slot_of_row, rows, axis=0)
     out = mtl_gather_two_level_q8(rows, slots, cache, cache_scale,
-                                  backing, backing_scale, dim=d, hot=h,
+                                  backing, backing_scale, dim=dim, hot=h,
                                   interpret=interpret)
-    return out.reshape(b, k * d)
+    return out.reshape(b, k * dim)
 
 
 def multi_table_lookup_host(ids: jax.Array, cache: jax.Array,
@@ -380,7 +373,7 @@ def multi_table_lookup_host(ids: jax.Array, cache: jax.Array,
                             staging_slot_of_row: jax.Array,
                             offsets: jax.Array, *, strategy: str = "auto",
                             interpret: bool | None = None,
-                            dim: int | None = None) -> jax.Array:
+                            dim: int) -> jax.Array:
     """Fused lookup through an out-of-HBM (cache + staging) store.
 
     The HostBackedStore analogue of :func:`multi_table_lookup_cached` with
@@ -392,12 +385,12 @@ def multi_table_lookup_host(ids: jax.Array, cache: jax.Array,
 
     Args:
         ids:                 (b, k) int32 per-field local ids.
-        cache:               (C, d) hot-row copies (packed when ``dim``).
-        staging:             (S, d) staged miss rows (packed when ``dim``).
+        cache:               (C, d) hot-row copies, packed.
+        staging:             (S, d) staged miss rows, packed.
         slot_of_row:         (N,) int32 cache slot per row, -1 = uncached.
         staging_slot_of_row: (N,) int32 staging slot per row, -1 = unstaged.
         offsets:             (k,) int32 starting row of each table.
-        dim:                 row width d of packed tiers.
+        dim:                 row width d.
 
     Returns:
         (b, k*d) embedding output.
@@ -411,11 +404,9 @@ def multi_table_lookup_host(ids: jax.Array, cache: jax.Array,
                                          _logical(cache, dim),
                                          _logical(staging, dim))
     else:
-        cache, d = _packed(cache, dim)
-        staging, _ = _packed(staging, dim)
         cslots = jnp.take(slot_of_row, rows, axis=0)
         sslots = jnp.take(staging_slot_of_row, rows, axis=0)
-        out = mtl_gather_three_level(cslots, sslots, cache, staging, dim=d,
+        out = mtl_gather_three_level(cslots, sslots, cache, staging, dim=dim,
                                      interpret=interpret)
     return out.reshape(b, -1)
 
@@ -427,7 +418,7 @@ def multi_table_lookup_host_multihot(ids: jax.Array, mask: jax.Array,
                                      offsets: jax.Array, *,
                                      strategy: str = "auto",
                                      interpret: bool | None = None,
-                                     dim: int | None = None
+                                     dim: int
                                      ) -> jax.Array:
     """Multi-hot (pooled) fused lookup through an out-of-HBM store.
 
@@ -440,12 +431,12 @@ def multi_table_lookup_host_multihot(ids: jax.Array, mask: jax.Array,
     Args:
         ids:                 (b, k, h) local ids; invalid slots arbitrary.
         mask:                (b, k, h) 1 for valid slots, 0 otherwise.
-        cache:               (C, d) hot-row copies (packed when ``dim``).
-        staging:             (S, d) staged miss rows (packed when ``dim``).
+        cache:               (C, d) hot-row copies, packed.
+        staging:             (S, d) staged miss rows, packed.
         slot_of_row:         (N,) int32 cache index map.
         staging_slot_of_row: (N,) int32 staging index map.
         offsets:             (k,) table starts.
-        dim:                 row width d of packed tiers.
+        dim:                 row width d.
 
     Returns:
         (b, k*d) pooled output.
@@ -459,13 +450,11 @@ def multi_table_lookup_host_multihot(ids: jax.Array, mask: jax.Array,
                                           _logical(cache, dim),
                                           _logical(staging, dim))
         return _pool(vals, mask, b, k, h, vals.shape[-1])
-    cache, d = _packed(cache, dim)
-    staging, _ = _packed(staging, dim)
     cslots = jnp.take(slot_of_row, rows, axis=0)
     sslots = jnp.take(staging_slot_of_row, rows, axis=0)
-    out = mtl_gather_three_level(cslots, sslots, cache, staging, dim=d,
+    out = mtl_gather_three_level(cslots, sslots, cache, staging, dim=dim,
                                  hot=h, interpret=interpret)
-    return out.reshape(b, k * d)
+    return out.reshape(b, k * dim)
 
 
 def multi_table_lookup_host_q8(ids: jax.Array, cache: jax.Array,
@@ -475,7 +464,7 @@ def multi_table_lookup_host_q8(ids: jax.Array, cache: jax.Array,
                                staging_slot_of_row: jax.Array,
                                offsets: jax.Array, *, strategy: str = "auto",
                                interpret: bool | None = None,
-                               dim: int | None = None) -> jax.Array:
+                               dim: int) -> jax.Array:
     """Quantized out-of-HBM lookup: int8 cache/staging rows, fp32 scales,
     in-gather dequant, zero-guard intact (q = 0 dequantizes to 0.0).
 
@@ -485,15 +474,14 @@ def multi_table_lookup_host_q8(ids: jax.Array, cache: jax.Array,
 
     Args:
         ids:                 (b, k) int32 per-field local ids.
-        cache:               (C, d) int8 hot-row copies (packed when ``dim``).
+        cache:               (C, d) int8 hot-row copies, packed.
         cache_scale:         (C, 1) fp32 per-row scales.
-        staging:             (S, d) int8 staged miss rows (packed when
-                             ``dim``).
+        staging:             (S, d) int8 staged miss rows (packed).
         staging_scale:       (S, 1) fp32 per-row scales.
         slot_of_row:         (N,) int32 cache slot per row, -1 = uncached.
         staging_slot_of_row: (N,) int32 staging slot per row, -1 = unstaged.
         offsets:             (k,) int32 starting row of each table.
-        dim:                 row width d of packed tiers.
+        dim:                 row width d.
 
     Returns:
         (b, k*d) float32 embedding output.
@@ -507,12 +495,10 @@ def multi_table_lookup_host_q8(ids: jax.Array, cache: jax.Array,
             _logical(cache, dim, jnp.int8), cache_scale,
             _logical(staging, dim, jnp.int8), staging_scale)
     else:
-        cache, d = _packed(cache, dim)
-        staging, _ = _packed(staging, dim)
         cslots = jnp.take(slot_of_row, rows, axis=0)
         sslots = jnp.take(staging_slot_of_row, rows, axis=0)
         out = mtl_gather_three_level_q8(cslots, sslots, cache, cache_scale,
-                                        staging, staging_scale, dim=d,
+                                        staging, staging_scale, dim=dim,
                                         interpret=interpret)
     return out.reshape(b, -1)
 
@@ -527,7 +513,7 @@ def multi_table_lookup_host_q8_multihot(ids: jax.Array, mask: jax.Array,
                                         offsets: jax.Array, *,
                                         strategy: str = "auto",
                                         interpret: bool | None = None,
-                                        dim: int | None = None
+                                        dim: int
                                         ) -> jax.Array:
     """Multi-hot (pooled) quantized out-of-HBM lookup.
 
@@ -538,15 +524,14 @@ def multi_table_lookup_host_q8_multihot(ids: jax.Array, mask: jax.Array,
     Args:
         ids:                 (b, k, h) local ids; invalid slots arbitrary.
         mask:                (b, k, h) 1 for valid slots, 0 otherwise.
-        cache:               (C, d) int8 hot-row copies (packed when ``dim``).
+        cache:               (C, d) int8 hot-row copies, packed.
         cache_scale:         (C, 1) fp32 per-row scales.
-        staging:             (S, d) int8 staged miss rows (packed when
-                             ``dim``).
+        staging:             (S, d) int8 staged miss rows (packed).
         staging_scale:       (S, 1) fp32 per-row scales.
         slot_of_row:         (N,) int32 cache index map.
         staging_slot_of_row: (N,) int32 staging index map.
         offsets:             (k,) table starts.
-        dim:                 row width d of packed tiers.
+        dim:                 row width d.
 
     Returns:
         (b, k*d) float32 pooled output.
@@ -560,14 +545,12 @@ def multi_table_lookup_host_q8_multihot(ids: jax.Array, mask: jax.Array,
             _logical(cache, dim, jnp.int8), cache_scale,
             _logical(staging, dim, jnp.int8), staging_scale)
         return _pool(vals, mask, b, k, h, vals.shape[-1])
-    cache, d = _packed(cache, dim)
-    staging, _ = _packed(staging, dim)
     cslots = jnp.take(slot_of_row, rows, axis=0)
     sslots = jnp.take(staging_slot_of_row, rows, axis=0)
     out = mtl_gather_three_level_q8(cslots, sslots, cache, cache_scale,
-                                    staging, staging_scale, dim=d, hot=h,
+                                    staging, staging_scale, dim=dim, hot=h,
                                     interpret=interpret)
-    return out.reshape(b, k * d)
+    return out.reshape(b, k * dim)
 
 
 def gather_tiers(tier: jax.Array, row: jax.Array, tables: tuple,
@@ -655,16 +638,16 @@ def multi_table_lookup_multihot(ids: jax.Array, mask: jax.Array,
                                 mega_table: jax.Array, offsets: jax.Array, *,
                                 strategy: str = "auto",
                                 interpret: bool | None = None,
-                                dim: int | None = None) -> jax.Array:
+                                dim: int) -> jax.Array:
     """Multi-hot (pooled) fused lookup.
 
     Args:
         ids:        (b, k, h) local ids; invalid slots arbitrary.
         mask:       (b, k, h) 1 for valid slots, 0 otherwise.
         mega_table: (N, d) concatenated tables **with a trailing all-zero
-                    row** at index N-1 (packed when ``dim`` is given).
+                    row** at index N-1, packed.
         offsets:    (k,) table starts.
-        dim:        row width d of a packed ``mega_table``.
+        dim:        row width d.
 
     Returns:
         (b, k*d) pooled output.
@@ -674,9 +657,9 @@ def multi_table_lookup_multihot(ids: jax.Array, mask: jax.Array,
     if strategy == "jnp":
         return ref.ref_multi_hot_lookup(ids, mask, _logical(mega_table, dim),
                                         offsets)
-    lines, d = _packed(mega_table, dim)
     # the last row (the zero row, or zero padding after it) is all-zero
-    zero_row = lines.shape[0] * rows_per_line(d, lines.dtype) - 1
+    zero_row = mega_table.shape[0] * rows_per_line(dim, mega_table.dtype) - 1
     rows = _masked_rows(ids, mask, offsets, zero_row)
-    out = mtl_gather_multihot(rows, lines, hot=h, dim=d, interpret=interpret)
-    return out.reshape(b, k * d)
+    out = mtl_gather_multihot(rows, mega_table, hot=h, dim=dim,
+                              interpret=interpret)
+    return out.reshape(b, k * dim)

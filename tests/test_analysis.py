@@ -102,7 +102,7 @@ def test_analyze_cell_int8_companion_terms():
 
     from repro.analysis import hw
     from repro.analysis.analytic import analytic_cost
-    from repro.analysis.roofline import analyze_cell
+    from repro.analysis.roofline import TARGET, analyze_cell
 
     n = 64
     c = jax.jit(lambda a, b: a @ b).lower(
@@ -110,7 +110,7 @@ def test_analyze_cell_int8_companion_terms():
         jax.ShapeDtypeStruct((n, n), jnp.float32)).compile()
     rep = analyze_cell("llama3-8b", "train_4k", "pod", 512, c)
 
-    v5e = hw.chip("TPU v5 lite")
+    v5e = hw.chip(TARGET)
     np.testing.assert_allclose(
         rep.compute_s_int8,
         rep.compute_s * v5e.peak_flops_bf16 / v5e.peak_ops_int8, rtol=1e-12)

@@ -78,8 +78,14 @@ def test_ops_dispatch_equivalence(strategy):
     _, mega, offsets = make_tables(rng, sizes, d, jnp.float32)
     ids = make_ids(rng, sizes, b)
     want = ref.ref_multi_table_lookup(ids, mega, offsets, len(sizes))
-    got = ops.multi_table_lookup(ids, mega, offsets, strategy=strategy,
-                                 interpret=True)
+    if strategy == "serial":
+        got = ops.multi_table_lookup_serial(ids, mega, offsets)
+    elif strategy == "input_first":
+        got = ops.multi_table_lookup_input_first(ids, mega, offsets,
+                                                 interpret=True)
+    else:
+        got = ops.multi_table_lookup(ids, pack_rows(mega), offsets, dim=d,
+                                     strategy=strategy, interpret=True)
     np.testing.assert_allclose(got, want, **TOL)
 
 
@@ -89,10 +95,10 @@ def test_input_first_matches_output_first():
     sizes, d, b = [9, 21], 8, 10
     _, mega, offsets = make_tables(rng, sizes, d, jnp.float32)
     ids = make_ids(rng, sizes, b)
-    a = ops.multi_table_lookup(ids, mega, offsets, strategy="pallas",
-                               interpret=True)
-    z = ops.multi_table_lookup(ids, mega, offsets, strategy="input_first",
-                               interpret=True)
+    a = ops.multi_table_lookup(ids, pack_rows(mega), offsets, dim=d,
+                               strategy="pallas", interpret=True)
+    z = ops.multi_table_lookup_input_first(ids, mega, offsets,
+                                           interpret=True)
     np.testing.assert_allclose(a, z, **TOL)
 
 
@@ -131,8 +137,9 @@ def test_multihot(h):
         dtype=jnp.int32)
     mask = jnp.asarray(rng.integers(0, 2, size=(b, k, h)), dtype=jnp.float32)
     want = ref.ref_multi_hot_lookup(ids, mask, mega_z, offsets)
-    got = ops.multi_table_lookup_multihot(ids, mask, mega_z, offsets,
-                                          strategy="pallas", interpret=True)
+    got = ops.multi_table_lookup_multihot(ids, mask, pack_rows(mega_z),
+                                          offsets, dim=d, strategy="pallas",
+                                          interpret=True)
     np.testing.assert_allclose(got, want, **TOL)
 
 
@@ -159,13 +166,14 @@ def test_two_level_gather_matches_dense(capacity):
     ids = jnp.asarray(
         np.stack([rng.integers(0, n, size=b) for n in sizes], axis=1),
         dtype=jnp.int32)
-    want = ops.multi_table_lookup(ids, mega, offsets, strategy="jnp")
+    mega, cache = pack_rows(mega), pack_rows(cache)
+    want = ops.multi_table_lookup(ids, mega, offsets, dim=d, strategy="jnp")
     got = ops.multi_table_lookup_cached(ids, cache, mega, slot_of_row,
-                                        offsets, strategy="jnp")
+                                        offsets, strategy="jnp", dim=d)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     got_pl = ops.multi_table_lookup_cached(ids, cache, mega, slot_of_row,
                                            offsets, strategy="pallas",
-                                           interpret=True)
+                                           interpret=True, dim=d)
     np.testing.assert_array_equal(np.asarray(got_pl), np.asarray(want))
 
 
@@ -181,17 +189,19 @@ def test_two_level_multihot_matches_dense(h):
         np.stack([rng.integers(0, n, size=(b, h)) for n in sizes], axis=1),
         dtype=jnp.int32)
     mask = jnp.asarray(rng.integers(0, 2, size=(b, k, h)), dtype=jnp.float32)
+    mega_z, cache = pack_rows(mega_z), pack_rows(cache)
     want_jnp = ops.multi_table_lookup_multihot(ids, mask, mega_z, offsets,
-                                               strategy="jnp")
+                                               strategy="jnp", dim=d)
     got_jnp = ops.multi_table_lookup_cached_multihot(
-        ids, mask, cache, mega_z, slot_of_row, offsets, strategy="jnp")
+        ids, mask, cache, mega_z, slot_of_row, offsets, strategy="jnp",
+        dim=d)
     np.testing.assert_array_equal(np.asarray(got_jnp), np.asarray(want_jnp))
     want_pl = ops.multi_table_lookup_multihot(ids, mask, mega_z, offsets,
                                               strategy="pallas",
-                                              interpret=True)
+                                              interpret=True, dim=d)
     got_pl = ops.multi_table_lookup_cached_multihot(
         ids, mask, cache, mega_z, slot_of_row, offsets, strategy="pallas",
-        interpret=True)
+        interpret=True, dim=d)
     np.testing.assert_array_equal(np.asarray(got_pl), np.asarray(want_pl))
 
 
@@ -226,13 +236,14 @@ def test_three_level_gather_matches_dense_when_fully_staged(capacity):
     ids = jnp.asarray(
         np.stack([rng.integers(0, n, size=b) for n in sizes], axis=1),
         dtype=jnp.int32)
-    want = ops.multi_table_lookup(ids, mega, offsets, strategy="jnp")
+    mega, cache, staging = (pack_rows(t) for t in (mega, cache, staging))
+    want = ops.multi_table_lookup(ids, mega, offsets, dim=d, strategy="jnp")
     got = ops.multi_table_lookup_host(ids, cache, staging, slot_of_row,
-                                      smap, offsets, strategy="jnp")
+                                      smap, offsets, strategy="jnp", dim=d)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     got_pl = ops.multi_table_lookup_host(ids, cache, staging, slot_of_row,
                                          smap, offsets, strategy="pallas",
-                                         interpret=True)
+                                         interpret=True, dim=d)
     np.testing.assert_array_equal(np.asarray(got_pl), np.asarray(want))
 
 
@@ -244,17 +255,20 @@ def test_three_level_gather_zero_guards_unresolved_rows():
     ids = jnp.asarray(
         np.stack([rng.integers(0, n, size=b) for n in sizes], axis=1),
         dtype=jnp.int32)
+    mega, cache, staging = (pack_rows(t) for t in (mega, cache, staging))
     for strategy in ("jnp", "pallas"):
         got = np.asarray(ops.multi_table_lookup_host(
             ids, cache, staging, slot_of_row, smap, offsets,
-            strategy=strategy, interpret=True)).reshape(b, len(sizes), d)
+            strategy=strategy, interpret=True,
+            dim=d)).reshape(b, len(sizes), d)
         rows = np.asarray(ids) + np.asarray(offsets)[None, :]
         unresolved = ((np.asarray(slot_of_row)[rows] < 0)
                       & (np.asarray(smap)[rows] < 0))
         assert unresolved.any()
         assert np.all(got[unresolved] == 0.0)
         want = np.asarray(ops.multi_table_lookup(
-            ids, mega, offsets, strategy="jnp")).reshape(b, len(sizes), d)
+            ids, mega, offsets, dim=d,
+            strategy="jnp")).reshape(b, len(sizes), d)
         np.testing.assert_array_equal(got[~unresolved], want[~unresolved])
 
 
@@ -271,14 +285,15 @@ def test_three_level_multihot_matches_dense(h):
         np.stack([rng.integers(0, n, size=(b, h)) for n in sizes], axis=1),
         dtype=jnp.int32)
     mask = jnp.asarray(rng.integers(0, 2, size=(b, k, h)), dtype=jnp.float32)
+    mega_z, cache, staging = (pack_rows(t) for t in (mega_z, cache, staging))
     # compare per strategy: jnp and pallas pool in different f32 orders
     for strategy in ("jnp", "pallas"):
         want = ops.multi_table_lookup_multihot(ids, mask, mega_z, offsets,
                                                strategy=strategy,
-                                               interpret=True)
+                                               interpret=True, dim=d)
         got = ops.multi_table_lookup_host_multihot(
             ids, mask, cache, staging, slot_of_row, smap, offsets,
-            strategy=strategy, interpret=True)
+            strategy=strategy, interpret=True, dim=d)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -340,7 +355,8 @@ def _q8_split_cache(rng, mega, capacity):
     slot_of_row[hot] = np.arange(capacity, dtype=np.int32)
     cache = jnp.take(q, jnp.asarray(hot), axis=0)
     cache_scale = jnp.take(scale, jnp.asarray(hot), axis=0)
-    return q, scale, cache, cache_scale, jnp.asarray(slot_of_row)
+    return (pack_rows(q), scale, pack_rows(cache), cache_scale,
+            jnp.asarray(slot_of_row))
 
 
 @pytest.mark.parametrize("capacity", [1, 16, 48])
@@ -354,10 +370,11 @@ def test_two_level_q8_round_trip_bound(capacity):
         rng, mega, capacity)
     ids = make_ids(rng, sizes, b)
     want = np.asarray(ops.multi_table_lookup(
-        ids, mega, offsets, strategy="jnp")).reshape(b, len(sizes), d)
+        ids, pack_rows(mega), offsets, dim=d,
+        strategy="jnp")).reshape(b, len(sizes), d)
     got = np.asarray(ops.multi_table_lookup_cached_q8(
         ids, cache, cscale, q, scale, slot_of_row, offsets,
-        strategy="jnp")).reshape(b, len(sizes), d)
+        strategy="jnp", dim=d)).reshape(b, len(sizes), d)
     rows = np.asarray(ids) + np.asarray(offsets)[None, :]
     bound = np.asarray(scale)[rows] * 0.5 + 1e-7      # (b, k, 1) per row
     assert np.all(np.abs(got - want) <= bound)
@@ -374,10 +391,11 @@ def test_two_level_q8_kernel_matches_ref(capacity):
         rng, mega, capacity)
     ids = make_ids(rng, sizes, b)
     got_jnp = ops.multi_table_lookup_cached_q8(
-        ids, cache, cscale, q, scale, slot_of_row, offsets, strategy="jnp")
+        ids, cache, cscale, q, scale, slot_of_row, offsets, strategy="jnp",
+        dim=d)
     got_pl = ops.multi_table_lookup_cached_q8(
         ids, cache, cscale, q, scale, slot_of_row, offsets,
-        strategy="pallas", interpret=True)
+        strategy="pallas", interpret=True, dim=d)
     np.testing.assert_array_equal(np.asarray(got_pl), np.asarray(got_jnp))
 
 
@@ -397,11 +415,12 @@ def test_two_level_q8_multihot_pooled(h):
         dtype=jnp.int32)
     mask = jnp.asarray(rng.integers(0, 2, size=(b, k, h)), dtype=jnp.float32)
     want = np.asarray(ops.multi_table_lookup_multihot(
-        ids, mask, mega_z, offsets, strategy="jnp")).reshape(b, k, d)
+        ids, mask, pack_rows(mega_z), offsets, strategy="jnp",
+        dim=d)).reshape(b, k, d)
     for strategy in ("jnp", "pallas"):
         got = np.asarray(ops.multi_table_lookup_cached_q8_multihot(
             ids, mask, cache, cscale, q, scale, slot_of_row, offsets,
-            strategy=strategy, interpret=True)).reshape(b, k, d)
+            strategy=strategy, interpret=True, dim=d)).reshape(b, k, d)
         rows = np.asarray(ids) + np.asarray(offsets)[None, :, None]
         row_scale = np.asarray(scale)[rows][..., 0]    # (b, k, h)
         bound = ((row_scale * 0.5 + 1e-7)
@@ -424,19 +443,21 @@ def test_three_level_q8_staged_round_trip(capacity):
     slot_of_row[hot] = np.arange(capacity, dtype=np.int32)
     smap = np.full(n, -1, dtype=np.int32)
     smap[warm] = np.arange(n - capacity, dtype=np.int32)
-    cache = jnp.take(q, jnp.asarray(hot), axis=0)
+    cache = pack_rows(jnp.take(q, jnp.asarray(hot), axis=0))
     cscale = jnp.take(scale, jnp.asarray(hot), axis=0)
-    staging = jnp.take(q, jnp.asarray(warm), axis=0)
+    staging = pack_rows(jnp.take(q, jnp.asarray(warm), axis=0))
     sscale = jnp.take(scale, jnp.asarray(warm), axis=0)
     ids = make_ids(rng, sizes, b)
     want = np.asarray(ops.multi_table_lookup(
-        ids, mega, offsets, strategy="jnp")).reshape(b, len(sizes), d)
+        ids, pack_rows(mega), offsets, dim=d,
+        strategy="jnp")).reshape(b, len(sizes), d)
     got_jnp = ops.multi_table_lookup_host_q8(
         ids, cache, cscale, staging, sscale, jnp.asarray(slot_of_row),
-        jnp.asarray(smap), offsets, strategy="jnp")
+        jnp.asarray(smap), offsets, strategy="jnp", dim=d)
     got_pl = ops.multi_table_lookup_host_q8(
         ids, cache, cscale, staging, sscale, jnp.asarray(slot_of_row),
-        jnp.asarray(smap), offsets, strategy="pallas", interpret=True)
+        jnp.asarray(smap), offsets, strategy="pallas", interpret=True,
+        dim=d)
     np.testing.assert_array_equal(np.asarray(got_pl), np.asarray(got_jnp))
     rows = np.asarray(ids) + np.asarray(offsets)[None, :]
     bound = np.asarray(scale)[rows] * 0.5 + 1e-7
@@ -456,16 +477,16 @@ def test_three_level_q8_zero_guards_unresolved_rows():
     slot_of_row[hot] = np.arange(8, dtype=np.int32)
     smap = np.full(n, -1, dtype=np.int32)
     smap[warm] = np.arange(8, dtype=np.int32)
-    cache = jnp.take(q, jnp.asarray(hot), axis=0)
+    cache = pack_rows(jnp.take(q, jnp.asarray(hot), axis=0))
     cscale = jnp.take(scale, jnp.asarray(hot), axis=0)
-    staging = jnp.take(q, jnp.asarray(warm), axis=0)
+    staging = pack_rows(jnp.take(q, jnp.asarray(warm), axis=0))
     sscale = jnp.take(scale, jnp.asarray(warm), axis=0)
     ids = make_ids(rng, sizes, b)
     for strategy in ("jnp", "pallas"):
         got = np.asarray(ops.multi_table_lookup_host_q8(
             ids, cache, cscale, staging, sscale, jnp.asarray(slot_of_row),
             jnp.asarray(smap), offsets, strategy=strategy,
-            interpret=True)).reshape(b, len(sizes), d)
+            interpret=True, dim=d)).reshape(b, len(sizes), d)
         rows = np.asarray(ids) + np.asarray(offsets)[None, :]
         unresolved = (slot_of_row[rows] < 0) & (smap[rows] < 0)
         assert unresolved.any()
@@ -487,9 +508,9 @@ def test_three_level_q8_multihot_matches_jnp_twin(h):
     slot_of_row[hot] = np.arange(16, dtype=np.int32)
     smap = np.full(n, -1, dtype=np.int32)
     smap[warm] = np.arange(n - 16, dtype=np.int32)
-    cache = jnp.take(q, jnp.asarray(hot), axis=0)
+    cache = pack_rows(jnp.take(q, jnp.asarray(hot), axis=0))
     cscale = jnp.take(scale, jnp.asarray(hot), axis=0)
-    staging = jnp.take(q, jnp.asarray(warm), axis=0)
+    staging = pack_rows(jnp.take(q, jnp.asarray(warm), axis=0))
     sscale = jnp.take(scale, jnp.asarray(warm), axis=0)
     ids = jnp.asarray(
         np.stack([rng.integers(0, n_, size=(b, h)) for n_ in sizes], axis=1),
@@ -497,10 +518,10 @@ def test_three_level_q8_multihot_matches_jnp_twin(h):
     mask = jnp.asarray(rng.integers(0, 2, size=(b, k, h)), dtype=jnp.float32)
     got_jnp = ops.multi_table_lookup_host_q8_multihot(
         ids, mask, cache, cscale, staging, sscale, jnp.asarray(slot_of_row),
-        jnp.asarray(smap), offsets, strategy="jnp")
+        jnp.asarray(smap), offsets, strategy="jnp", dim=d)
     got_pl = ops.multi_table_lookup_host_q8_multihot(
         ids, mask, cache, cscale, staging, sscale, jnp.asarray(slot_of_row),
-        jnp.asarray(smap), offsets, strategy="pallas", interpret=True)
+        jnp.asarray(smap), offsets, strategy="pallas", interpret=True, dim=d)
     np.testing.assert_allclose(np.asarray(got_pl), np.asarray(got_jnp),
                                rtol=1e-6, atol=1e-6)
 
