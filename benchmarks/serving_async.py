@@ -17,7 +17,7 @@ Measurements on the same zipf request stream:
   4. **many-model sweep** (models × offered load): the same round-robin
      traffic served twice — through one shared ``DeviceScheduler`` pool
      and through per-engine worker threads. Reports p99, thread-count
-     delta, and per-model device-time share; hard-asserts the shared
+     delta, and per-model dispatch wall-time share; hard-asserts the shared
      mode's thread budget (≤ pool_size + 1 new threads however many
      models are hosted) and score bit-exactness across modes.
 
@@ -167,7 +167,7 @@ def _sweep_cell(n_models: int, n_requests: int, ladder, max_field: int,
             "threads_per_engine": delta_p,
             "sched_dispatches": int(agg_s.sched_dispatches),
             "preempted_slack_ms": agg_s.sched_preempted_slack_ms,
-            "device_time_share": shares,
+            "dispatch_wall_share": shares,
         },
     }
 

@@ -23,6 +23,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Sequence
 
+import jax
+
 __all__ = ["Op", "FusedOp", "OpGraph", "register_fused_kernel",
            "fuse_non_gemm", "op_outputs"]
 
@@ -148,11 +150,14 @@ class OpGraph:
     # -- execution ---------------------------------------------------------
     def execute(self, env: dict[str, Any],
                 order: Sequence[str] | None = None) -> dict[str, Any]:
-        """Run ops (in graph order or an explicit schedule) over ``env``."""
+        """Run ops (in graph order or an explicit schedule) over ``env``.
+        Each op runs in ``jax.named_scope(op.name)``, so a traced step's
+        operations carry their op's name in their metadata."""
         env = dict(env)
         ops = self.ops if order is None else [self.op(n) for n in order]
         for op in ops:
-            res = op.fn(*[env[e] for e in op.inputs])
+            with jax.named_scope(op.name):
+                res = op.fn(*[env[e] for e in op.inputs])
             if isinstance(op, FusedOp):
                 if len(op.outputs) == 1:
                     env[op.outputs[0]] = res
@@ -177,7 +182,8 @@ def _compose(sub_ops: list[Op], external: tuple[str, ...],
     def fused_fn(*args):
         env = dict(zip(external, args))
         for op in sub_ops:
-            env[op.output] = op.fn(*[env[e] for e in op.inputs])
+            with jax.named_scope(op.name):
+                env[op.output] = op.fn(*[env[e] for e in op.inputs])
         if single:
             return env[exposed[0]]
         return tuple(env[e] for e in exposed)

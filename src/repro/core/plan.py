@@ -38,12 +38,14 @@ user code should not need to touch it directly anymore.
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 from typing import Any, Callable
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from .dual_parallel import (BRANCH_ORDERS, LEVELS, DualParallelExecutor,
                             ExecutorStats)
@@ -91,6 +93,21 @@ def plan_key_for(model, level: str, batch_size: int,
                    compute_dtype=compute_dtype)
 
 
+class StageClock(threading.local):
+    """Running totals, in ms, of one thread's plan calls by stage:
+    ``dispatch`` (pad, host-to-device copy, step and sigmoid dispatched),
+    ``wait`` (until the scores are ready on the device) and ``readback``
+    (device-to-host copy and slice). Kept per thread, as several threads
+    may call one plan at once; a caller takes the difference of two
+    :meth:`totals` around its own calls."""
+    dispatch_ms = 0.0
+    wait_ms = 0.0
+    readback_ms = 0.0
+
+    def totals(self) -> tuple[float, float, float]:
+        return self.dispatch_ms, self.wait_ms, self.readback_ms
+
+
 @dataclasses.dataclass(frozen=True)
 class InferencePlan:
     """One compiled, batch-shape-specific inference artifact.
@@ -130,6 +147,9 @@ class InferencePlan:
     #: eager levels): ``as_text()`` / ``memory_analysis()`` of the program
     #: every call runs
     executable: Any = None
+    #: running stage times of this plan's calls, per calling thread
+    clock: StageClock = dataclasses.field(default_factory=StageClock,
+                                          compare=False, repr=False)
 
     @property
     def level(self) -> str:
@@ -142,25 +162,51 @@ class InferencePlan:
     def __call__(self, ids: jax.Array) -> jax.Array:
         return self.step(ids)
 
+    def launch(self, ids) -> tuple[jax.Array, int]:
+        """First half of :meth:`predict`: pad ``ids`` to the plan's batch
+        shape, copy them to the device and dispatch the step and the
+        sigmoid, without waiting for either. Returns the scores still on
+        the device (padding rows included) and the count of real rows."""
+        t0 = time.perf_counter()
+        with TraceAnnotation("plan.dispatch"):
+            ids = np.asarray(ids, dtype=np.int32)
+            if ids.ndim == 1:
+                ids = ids[None, :]
+            b = ids.shape[0]
+            if b > self.batch_size:
+                raise ValueError(
+                    f"{b} rows > plan batch_size {self.batch_size}; use an "
+                    "InferenceEngine (it batches) or compile a bigger plan")
+            if b < self.batch_size:
+                pad = np.zeros((self.batch_size - b, ids.shape[1]),
+                               dtype=ids.dtype)
+                ids = np.concatenate([ids, pad])
+            logits = self.step(jnp.asarray(ids))
+            scores = jax.nn.sigmoid(jnp.reshape(jnp.asarray(logits), (-1,)))
+        self.clock.dispatch_ms += (time.perf_counter() - t0) * 1e3
+        return scores, b
+
+    def fetch(self, scores: jax.Array, b: int) -> np.ndarray:
+        """Second half of :meth:`predict`: wait until ``scores`` are ready
+        on the device, then copy them to the host and keep the first
+        ``b``."""
+        t0 = time.perf_counter()
+        with TraceAnnotation("plan.wait"):
+            scores.block_until_ready()
+        t1 = time.perf_counter()
+        with TraceAnnotation("plan.readback"):
+            out = np.asarray(scores)[:b]
+        t2 = time.perf_counter()
+        clock = self.clock
+        clock.wait_ms += (t1 - t0) * 1e3
+        clock.readback_ms += (t2 - t1) * 1e3
+        return out
+
     def predict(self, ids) -> np.ndarray:
         """Sigmoid scores for ``ids`` ((n_fields,) or (b, n_fields) with
         b ≤ batch_size); pads up to the plan's batch shape and slices the
         padding back off."""
-        ids = np.asarray(ids, dtype=np.int32)
-        if ids.ndim == 1:
-            ids = ids[None, :]
-        b = ids.shape[0]
-        if b > self.batch_size:
-            raise ValueError(
-                f"{b} rows > plan batch_size {self.batch_size}; use an "
-                "InferenceEngine (it batches) or compile a bigger plan")
-        if b < self.batch_size:
-            pad = np.zeros((self.batch_size - b, ids.shape[1]),
-                           dtype=ids.dtype)
-            ids = np.concatenate([ids, pad])
-        logits = self.step(jnp.asarray(ids))
-        return np.asarray(
-            jax.nn.sigmoid(jnp.reshape(jnp.asarray(logits), (-1,))))[:b]
+        return self.fetch(*self.launch(ids))
 
 
 def _shard_params(params: Any, mesh: jax.sharding.Mesh, model_axis: str,
@@ -272,72 +318,73 @@ def compile_plan(model, params: Any, level: str = "dual",
     executor = DualParallelExecutor(builder, level=level,
                                     branch_order=branch_order)
     t0 = time.perf_counter()
-    graph, order = executor.prepare(params)
-    step_env = executor.make_step(graph, order, donate=donate)
-    n_fields = model.spec.k
+    with TraceAnnotation("plan.compile"):
+        graph, order = executor.prepare(params)
+        step_env = executor.make_step(graph, order, donate=donate)
+        n_fields = model.spec.k
 
-    # runtime store tensors (refreshable tiers only): extra step inputs,
-    # re-read from the provider each call instead of baked into the program
-    runtime = (model.store_runtime_env(params)
-               if hasattr(model, "store_runtime_env") else {})
-    provider = runtime_provider if runtime_provider is not None \
-        else (lambda: runtime)
+        # runtime store tensors (refreshable tiers only): extra step inputs,
+        # re-read from the provider each call instead of baked into the program
+        runtime = (model.store_runtime_env(params)
+                   if hasattr(model, "store_runtime_env") else {})
+        provider = runtime_provider if runtime_provider is not None \
+            else (lambda: runtime)
 
-    # resolved shardings (the multi-chip serving contract, recorded on the
-    # plan): per-call inputs batch-sharded over the mesh's data axis with
-    # fit_spec fallback for batch sizes the axis doesn't divide; runtime
-    # store tensors carry the placement place_params gave them (backing/
-    # mega row-sharded over model, cache + slot_of_row replicated)
-    in_shardings: dict = {}
-    rt_shardings: dict = {}
-    if mesh is not None:
-        from repro.distributed.sharding import input_shardings
-        in_shardings = input_shardings(
-            mesh, {"ids": jax.ShapeDtypeStruct((batch_size, n_fields),
-                                               jnp.int32)})
-        rt_shardings = {k: v.sharding for k, v in runtime.items()}
+        # resolved shardings (the multi-chip serving contract, recorded on the
+        # plan): per-call inputs batch-sharded over the mesh's data axis with
+        # fit_spec fallback for batch sizes the axis doesn't divide; runtime
+        # store tensors carry the placement place_params gave them (backing/
+        # mega row-sharded over model, cache + slot_of_row replicated)
+        in_shardings: dict = {}
+        rt_shardings: dict = {}
+        if mesh is not None:
+            from repro.distributed.sharding import input_shardings
+            in_shardings = input_shardings(
+                mesh, {"ids": jax.ShapeDtypeStruct((batch_size, n_fields),
+                                                   jnp.int32)})
+            rt_shardings = {k: v.sharding for k, v in runtime.items()}
 
-    def bind_inputs(ids: jax.Array) -> dict:
-        if in_shardings:
-            ids = jax.device_put(ids, in_shardings["ids"])
-        return {"ids": ids}
+        def bind_inputs(ids: jax.Array) -> dict:
+            if in_shardings:
+                ids = jax.device_put(ids, in_shardings["ids"])
+            return {"ids": ids}
 
-    def bind_runtime() -> dict:
-        env = provider()
-        if rt_shardings:
-            # no-op for tensors already placed (the refresh path places
-            # before publishing); a safety net for callers that swap in
-            # raw host arrays
-            env = {k: jax.device_put(v, rt_shardings[k])
-                   for k, v in env.items()}
-        return env
+        def bind_runtime() -> dict:
+            env = provider()
+            if rt_shardings:
+                # no-op for tensors already placed (the refresh path places
+                # before publishing); a safety net for callers that swap in
+                # raw host arrays
+                env = {k: jax.device_put(v, rt_shardings[k])
+                       for k, v in env.items()}
+            return env
 
-    if level == "dual":
-        # AOT: lower + compile the whole-graph program now, not on first
-        # use — with the resolved input/runtime shardings baked into the
-        # lowered avals so GSPMD partitions the program for the mesh
-        spec = {"ids": jax.ShapeDtypeStruct(
-            (batch_size, n_fields), jnp.int32,
-            sharding=in_shardings.get("ids"))}
-        rt_spec = {k: jax.ShapeDtypeStruct(v.shape, v.dtype,
-                                           sharding=rt_shardings.get(k))
-                   for k, v in runtime.items()}
-        from repro.kernels.ops import mesh_context
-        with mesh_context(mesh, model_axis):
-            compiled = step_env.lower(spec, rt_spec).compile()
-        executable = compiled
+        if level == "dual":
+            # AOT: lower + compile the whole-graph program now, not on first
+            # use — with the resolved input/runtime shardings baked into the
+            # lowered avals so GSPMD partitions the program for the mesh
+            spec = {"ids": jax.ShapeDtypeStruct(
+                (batch_size, n_fields), jnp.int32,
+                sharding=in_shardings.get("ids"))}
+            rt_spec = {k: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                               sharding=rt_shardings.get(k))
+                       for k, v in runtime.items()}
+            from repro.kernels.ops import mesh_context
+            with mesh_context(mesh, model_axis):
+                compiled = step_env.lower(spec, rt_spec).compile()
+            executable = compiled
 
-        def step(ids: jax.Array) -> jax.Array:
-            return compiled(bind_inputs(ids), bind_runtime())
-    else:
-        # eager levels dispatch op-by-op on purpose; warm every per-op jit
-        # so serving latency never includes compiles
-        executable = None
+            def step(ids: jax.Array) -> jax.Array:
+                return compiled(bind_inputs(ids), bind_runtime())
+        else:
+            # eager levels dispatch op-by-op on purpose; warm every per-op jit
+            # so serving latency never includes compiles
+            executable = None
 
-        def step(ids: jax.Array) -> jax.Array:
-            return step_env(bind_inputs(ids), bind_runtime())
-        jax.block_until_ready(
-            step(jnp.zeros((batch_size, n_fields), dtype=jnp.int32)))
+            def step(ids: jax.Array) -> jax.Array:
+                return step_env(bind_inputs(ids), bind_runtime())
+            jax.block_until_ready(
+                step(jnp.zeros((batch_size, n_fields), dtype=jnp.int32)))
     compile_ms = (time.perf_counter() - t0) * 1e3
 
     key = plan_key_for(model, level, batch_size, branch_order,

@@ -72,4 +72,5 @@ def dmm_q8(hq: jax.Array, hscale: jax.Array, wq: jax.Array,
         out_specs=pl.BlockSpec((bm, fan_out), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, fan_out), jnp.float32),
         interpret=interpret,
+        name="dmm_q8",
     )(hq, hscale, wq, wscale, bias)

@@ -37,6 +37,7 @@ def fused_cross_v2(x0: jax.Array, xw_plus: jax.Array, x: jax.Array, *,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((b, dim), x0.dtype),
         interpret=interpret,
+        name="fused_cross_v2",
     )(x0, xw_plus, x)
 
 
@@ -65,4 +66,5 @@ def fused_cross_v1(x0: jax.Array, xlw: jax.Array, bias: jax.Array,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((b, dim), x0.dtype),
         interpret=interpret,
+        name="fused_cross_v1",
     )(x0, xlw, bias.reshape(1, dim), x)

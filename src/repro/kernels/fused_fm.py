@@ -48,4 +48,5 @@ def fused_fm_second_order(v: jax.Array, *, block_b: int = 128,
         out_specs=pl.BlockSpec((bm, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, 1), v.dtype),
         interpret=interpret,
+        name="fused_fm_second_order",
     )(v)

@@ -281,6 +281,7 @@ def mtl_gather_tiered(tier: jax.Array, row: jax.Array, tables: tuple,
         scratch_shapes=[pltpu.VMEM((hot, block, LANES), word_dtype),
                         pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
+        name="mtl_gather_tiered",
     )(*args, *tables)[:r]
     if q8 or np.dtype(dtype).itemsize == 4:
         return out
@@ -514,6 +515,7 @@ def mtl_onehot(ids: jax.Array, stacked_tables: jax.Array, *,
         out_specs=pl.BlockSpec((bm, 1, d), lambda i, f: (i, f, 0)),
         out_shape=jax.ShapeDtypeStruct((b, k, d), stacked_tables.dtype),
         interpret=interpret,
+        name="mtl_onehot",
     )(ids, stacked_tables)
 
 
@@ -551,6 +553,7 @@ def mtl_input_first(flat_rows: jax.Array, mega_table: jax.Array, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((k, b, d), mega_table.dtype),
         interpret=interpret,
+        name="mtl_input_first",
     )(flat_rows, mega_table)
     # the extra reorganization pass input-first designs pay for:
     return jnp.transpose(out_fmajor, (1, 0, 2)).reshape(b, k * d)

@@ -66,6 +66,7 @@ from typing import Callable, Sequence
 
 import numpy as np
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.core.plan import (InferencePlan, PlanKey, compile_plan,
                              place_params, plan_key_for)
@@ -119,7 +120,10 @@ AGGREGATED_COUNTERS = (
     "emb_delta_pushes", "emb_delta_rows", "rows_behind",
     "mlp_quant_matmuls", "mlp_quant_weight_bytes",
     "mlp_quant_weight_bytes_saved",
-    "sched_dispatches", "sched_preempted_slack_ms", "device_time_share",
+    "sched_dispatches", "sched_preempted_slack_ms", "dispatch_wall_share",
+    "queue_wait_ms_total", "batch_ms_total", "stack_ms_total",
+    "observe_ms_total", "dispatch_ms_total", "device_wait_ms_total",
+    "readback_ms_total", "resolve_ms_total",
 )
 # emb_version and seconds_behind are aggregated by MAX, not sum — the
 # runtime handles them as customs (a sum of versions means nothing).
@@ -293,9 +297,23 @@ class EngineStats:
     accumulates how many milliseconds past their SLO deadline this
     engine's due partial batches sat while the device worked other models
     (contention-burned slack — 0 means every deadline was picked up on
-    time), and ``device_time_share`` is this engine's fraction of all
-    device time the scheduler has dispatched (shares over one scheduler's
-    engines sum to 1).
+    time), and ``dispatch_wall_share`` is this engine's fraction of the
+    host wall time the pool spent in dispatches (shares over one
+    scheduler's engines sum to 1; a host clock, not device time).
+
+    The stage counters split each served batch's host time, one update
+    per batch (``float`` ms totals of ``time.perf_counter``):
+    ``queue_wait_ms_total`` sums, over the batch's requests, the time
+    from submit to the pop that took them; ``batch_ms_total`` runs from
+    that pop to the last future resolved, and within it
+    ``stack_ms_total`` (rows stacked), ``observe_ms_total`` (the store's
+    admission counters fed), ``dispatch_ms_total`` /
+    ``device_wait_ms_total`` / ``readback_ms_total`` (the plan call's
+    stages, ``InferencePlan.clock``) and ``resolve_ms_total`` (counters
+    updated and every future resolved, callbacks included). Each has the
+    profiler span of its stage (``engine.*``, ``plan.*``; see
+    ``docs/operations.md``). ``compute_ms_total`` is the host clock
+    around the whole plan call, staging included.
     """
     n_requests: int = 0
     n_batches: int = 0
@@ -304,8 +322,16 @@ class EngineStats:
     n_worker_errors: int = 0
     sched_dispatches: int = 0
     sched_preempted_slack_ms: float = 0.0
-    device_time_share: float = 0.0
+    dispatch_wall_share: float = 0.0
     compute_ms_total: float = 0.0
+    queue_wait_ms_total: float = 0.0
+    batch_ms_total: float = 0.0
+    stack_ms_total: float = 0.0
+    observe_ms_total: float = 0.0
+    dispatch_ms_total: float = 0.0
+    device_wait_ms_total: float = 0.0
+    readback_ms_total: float = 0.0
+    resolve_ms_total: float = 0.0
     latency_window: int = 8192
     latency_ms: deque = None
     cache_hits: int = 0
@@ -573,12 +599,14 @@ class InferenceEngine:
             return plan.predict(rows)
         key = getattr(self.model, "main_embedding_key", "emb")
         try:
-            staged = store.stage(self.params[key], rows)
+            with TraceAnnotation("engine.stage"):
+                staged = store.stage(self.params[key], rows)
         except StagingOverflowError:
             self._mirror_store_stats()
             outs = []
             for chunk in store.split_for_staging(rows):
-                staged = store.stage(self.params[key], chunk)
+                with TraceAnnotation("engine.stage"):
+                    staged = store.stage(self.params[key], chunk)
                 self.params = {**self.params, key: staged}
                 self._bump_mlp_quant(plan)
                 outs.append(plan.predict(chunk))
@@ -632,7 +660,7 @@ class InferenceEngine:
         # index map, hit/miss stats) from being rebuilt mid-observe when a
         # refresh comes from outside the drain loop (ServingRuntime's
         # shared admission, a manual call); re-entrant for auto-refresh
-        with self._drain_lock:
+        with self._drain_lock, TraceAnnotation("engine.refresh"):
             key = getattr(self.model, "main_embedding_key", "emb")
             fresh = store.refresh(self.params[key])   # built on the side
             if self.mesh is not None:
@@ -986,43 +1014,76 @@ class InferenceEngine:
                          for _ in range(decision.take)]
                 with self.stats.lock:
                     self.stats.queue_depth = len(self._queue)
-            t_submit = [it[0] for it in items]
-            try:
-                # inside the try: a malformed row (ragged shape) must
-                # fail its batch's futures, not strand them unresolved
+            t_pop = time.perf_counter()
+            with TraceAnnotation("engine.batch"):
+                scores = self._run_batch(items, decision.bucket, t_pop)
+            self._maybe_auto_refresh()
+            return scores
+
+    def _run_batch(self, items: list, bucket: int, t_pop: float
+                   ) -> np.ndarray:
+        """Serve the requests ``items`` popped at ``t_pop`` as one batch
+        of ``bucket`` rows: stack, observe, plan call, counters, resolve.
+        Each stage is a profiler span of its own, timed into the stage
+        counters of ``EngineStats``; nothing here is timed per request."""
+        t_submit = [it[0] for it in items]
+        try:
+            # inside the try: a malformed row (ragged shape) must
+            # fail its batch's futures, not strand them unresolved
+            with TraceAnnotation("engine.stack"):
                 rows = np.stack([it[1] for it in items])
+            t_stacked = time.perf_counter()
+            with TraceAnnotation("engine.observe"):
                 self._observe_traffic(rows)
-                plan = self.plan_for(decision.bucket)
-                # batch t+1's ids go to the async prefetch worker now,
-                # so its host-side miss gather overlaps batch t's
-                # stage+compute below (no-op for non-staging stores)
-                self._hint_upcoming()
-                t0 = time.perf_counter()
-                # plan.predict pads to the bucket shape and slices the
-                # padding back off — one output transform shared with
-                # the one-shot path; _predict_staged resolves staging
-                # stores' misses first (pass-through otherwise)
-                scores = self._predict_staged(plan, rows)
-                t1 = time.perf_counter()
-            except Exception as exc:
-                for _, _, fut in items:
-                    fut._fail(exc)
-                raise
+            t_observed = time.perf_counter()
+            plan = self.plan_for(bucket)
+            # batch t+1's ids go to the async prefetch worker now,
+            # so its host-side miss gather overlaps batch t's
+            # stage+compute below (no-op for non-staging stores)
+            self._hint_upcoming()
+            stages0 = plan.clock.totals()
+            t0 = time.perf_counter()
+            # plan.predict pads to the bucket shape and slices the
+            # padding back off — one output transform shared with
+            # the one-shot path; _predict_staged resolves staging
+            # stores' misses first (pass-through otherwise)
+            scores = self._predict_staged(plan, rows)
+            t1 = time.perf_counter()
+        except Exception as exc:
+            for _, _, fut in items:
+                fut._fail(exc)
+            raise
+        dispatch, wait, readback = (
+            b - a for a, b in zip(stages0, plan.clock.totals()))
+        take = len(items)
+        st = self.stats
+        with TraceAnnotation("engine.resolve"):
             lat = [(t1 - ts) * 1e3 for ts in t_submit]
-            st = self.stats
             with st.lock:
-                st.n_requests += decision.take
+                st.n_requests += take
                 st.n_batches += 1
-                st.batches_per_bucket[decision.bucket] = (
-                    st.batches_per_bucket.get(decision.bucket, 0) + 1)
-                st.padded_rows_total += decision.bucket - decision.take
+                st.batches_per_bucket[bucket] = (
+                    st.batches_per_bucket.get(bucket, 0) + 1)
+                st.padded_rows_total += bucket - take
                 st.compute_ms_total += (t1 - t0) * 1e3
                 st.latency_ms.extend(lat)
+                st.queue_wait_ms_total += (take * t_pop - sum(t_submit)) * 1e3
+                st.stack_ms_total += (t_stacked - t_pop) * 1e3
+                st.observe_ms_total += (t_observed - t_stacked) * 1e3
+                st.dispatch_ms_total += dispatch
+                st.device_wait_ms_total += wait
+                st.readback_ms_total += readback
             # futures resolve in submit order (items popped FIFO)
             for (_, _, fut), score, l in zip(items, scores, lat):
                 fut._resolve(float(score), l)
-            self._maybe_auto_refresh()
-            return scores
+        t_end = time.perf_counter()
+        # a second, per-batch update: the batch's end is known only once
+        # its futures resolved, and its other counters must be visible
+        # before they resolve (callers read stats after ``result()``)
+        with st.lock:
+            st.resolve_ms_total += (t_end - t1) * 1e3
+            st.batch_ms_total += (t_end - t_pop) * 1e3
+        return scores
 
     # -- one-shot --------------------------------------------------------------
     def predict(self, ids) -> np.ndarray:

@@ -67,12 +67,13 @@ class RuntimeStats:
     stats, taken under that engine's lock (``EngineStats.snapshot``) —
     drill-down counters are consistent and never mutate under the
     reader; re-call :meth:`ServingRuntime.stats` for fresh numbers.
-    ``device_time_share`` sums the per-engine shares, so it reads ~1.0
+    ``dispatch_wall_share`` sums the per-engine shares, so it reads ~1.0
     when a shared scheduler has dispatched anything and 0.0 in
-    per-engine-worker mode. Every counter named in
-    ``engine.AGGREGATED_COUNTERS`` is a field here — :meth:`stats` sums
-    them generically, and the import-time check below keeps the two
-    definitions from drifting.
+    per-engine-worker mode. The stage totals (``queue_wait_ms_total``
+    to ``resolve_ms_total``, see ``EngineStats``) sum over engines.
+    Every counter named in ``engine.AGGREGATED_COUNTERS`` is a field
+    here — :meth:`stats` sums them generically, and the import-time
+    check below keeps the two definitions from drifting.
 
     Online-update staleness: ``emb_delta_pushes``/``emb_delta_rows`` and
     ``rows_behind`` sum across engines, while ``emb_version`` and
@@ -110,7 +111,15 @@ class RuntimeStats:
     mlp_quant_weight_bytes_saved: int
     sched_dispatches: int
     sched_preempted_slack_ms: float
-    device_time_share: float
+    dispatch_wall_share: float
+    queue_wait_ms_total: float
+    batch_ms_total: float
+    stack_ms_total: float
+    observe_ms_total: float
+    dispatch_ms_total: float
+    device_wait_ms_total: float
+    readback_ms_total: float
+    resolve_ms_total: float
     per_model: dict[str, EngineStats]
 
 

@@ -26,8 +26,8 @@ servers use:
   everything submitted between the readiness poll and the pick — from
   any number of submitter threads — rides the same device batch
   (possibly upgrading it to a larger bucket);
-* per-model **device-time accounting**: every dispatch's wall time is
-  charged to its engine, published as ``stats.device_time_share``
+* per-model **dispatch accounting**: every dispatch's host wall time is
+  charged to its engine, published as ``stats.dispatch_wall_share``
   (shares over one scheduler's engines sum to 1), alongside
   ``sched_dispatches`` and ``sched_preempted_slack_ms`` (milliseconds a
   due batch sat past its deadline while other models held the device).
@@ -58,6 +58,8 @@ from __future__ import annotations
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 from .engine import InferenceEngine, ReadyBatch
 
 __all__ = ["DeviceScheduler"]
@@ -80,8 +82,8 @@ class DeviceScheduler:
 
     Attributes:
         n_dispatches: total batches dispatched across all engines.
-        device_ms: per-engine accumulated dispatch wall time (a copy).
-        shares: per-engine fraction of total dispatched device time
+        dispatch_ms: per-engine accumulated dispatch wall time (a copy).
+        shares: per-engine fraction of the total dispatch wall time
             (sums to 1 once anything has dispatched).
     """
 
@@ -90,11 +92,11 @@ class DeviceScheduler:
             raise ValueError(f"pool_size must be >= 1, got {pool_size}")
         self.pool_size = pool_size
         self._engines: dict[str, InferenceEngine] = {}
-        # guards _engines/_busy/_device_ms/n_dispatches and is the pool's
+        # guards _engines/_busy/_dispatch_ms/n_dispatches and is the pool's
         # wait target; never held across a dispatch (device compute)
         self._cv = threading.Condition(threading.Lock())
         self._busy: set[str] = set()
-        self._device_ms: dict[str, float] = {}
+        self._dispatch_ms: dict[str, float] = {}
         self._workers: list[threading.Thread] = []
         self._running = False
         self.n_dispatches = 0
@@ -115,7 +117,7 @@ class DeviceScheduler:
                 raise ValueError(f"engine {name!r} already attached to "
                                  "another scheduler")
             self._engines[name] = engine
-            self._device_ms.setdefault(name, 0.0)
+            self._dispatch_ms.setdefault(name, 0.0)
             engine._scheduler = self
             self._cv.notify_all()
         return engine
@@ -126,16 +128,16 @@ class DeviceScheduler:
             return tuple(self._engines)
 
     @property
-    def device_ms(self) -> dict[str, float]:
+    def dispatch_ms(self) -> dict[str, float]:
         with self._cv:
-            return dict(self._device_ms)
+            return dict(self._dispatch_ms)
 
     @property
     def shares(self) -> dict[str, float]:
         with self._cv:
-            total = sum(self._device_ms.values())
+            total = sum(self._dispatch_ms.values())
             return {n: (ms / total if total else 0.0)
-                    for n, ms in self._device_ms.items()}
+                    for n, ms in self._dispatch_ms.items()}
 
     # -- lifecycle ------------------------------------------------------------
     def start(self) -> "DeviceScheduler":
@@ -199,23 +201,39 @@ class DeviceScheduler:
                            else min(wait_ms, c.slack_ms))
         return best_name, best, wait_ms
 
+    def _claim(self):
+        """Wait until some idle engine has a batch due, claim it and
+        return ``(name, candidate)``; None once the pool stops. Caller
+        holds ``_cv``. The first poll is a ``sched.pick`` span; if it
+        finds nothing to claim, one ``sched.wait`` span covers the waits
+        and polls up to the claim — every submit wakes an idle pool
+        thread, so a span per wake-up would be a span per request."""
+        if not self._running:
+            return None
+        with TraceAnnotation("sched.pick"):
+            name, cand, wait_ms = self._pick(time.perf_counter())
+        if name is None:
+            with TraceAnnotation("sched.wait"):
+                while name is None:
+                    self._cv.wait(_MAX_WAIT_S if wait_ms is None
+                                  else min(max(wait_ms / 1e3, 1e-4),
+                                           _MAX_WAIT_S))
+                    if not self._running:
+                        return None
+                    name, cand, wait_ms = self._pick(time.perf_counter())
+        # claim: one pool thread per engine at a time, so the queue
+        # drains FIFO exactly as a dedicated worker would (bit-exact
+        # scores, ordered futures)
+        self._busy.add(name)
+        return name, cand
+
     def _pool_loop(self) -> None:
         while True:
             with self._cv:
-                while True:
-                    if not self._running:
-                        return
-                    name, cand, wait_ms = self._pick(time.perf_counter())
-                    if name is not None:
-                        # claim: one pool thread per engine at a time, so
-                        # the queue drains FIFO exactly as a dedicated
-                        # worker would (bit-exact scores, ordered futures)
-                        self._busy.add(name)
-                        break
-                    timeout = (_MAX_WAIT_S if wait_ms is None
-                               else min(max(wait_ms / 1e3, 1e-4),
-                                        _MAX_WAIT_S))
-                    self._cv.wait(timeout)
+                claimed = self._claim()
+            if claimed is None:
+                return
+            name, cand = claimed
             eng = self._engines[name]
             served = False
             t0 = time.perf_counter()
@@ -232,20 +250,20 @@ class DeviceScheduler:
                 self._busy.discard(name)
                 if served:
                     self.n_dispatches += 1
-                    self._device_ms[name] += dt_ms
+                    self._dispatch_ms[name] += dt_ms
                     self._publish_shares(name, cand)
                 # a freed engine may already have the next due batch —
                 # and other threads may be sleeping on a stale deadline
                 self._cv.notify_all()
 
     def _publish_shares(self, served_name: str, cand: ReadyBatch) -> None:
-        """Mirror device-time accounting into engine stats (holds _cv;
+        """Mirror dispatch accounting into engine stats (holds _cv;
         engine stats locks nest strictly inside it)."""
-        total = sum(self._device_ms.values())
+        total = sum(self._dispatch_ms.values())
         for name, eng in self._engines.items():
             with eng.stats.lock:
-                eng.stats.device_time_share = (
-                    self._device_ms[name] / total if total else 0.0)
+                eng.stats.dispatch_wall_share = (
+                    self._dispatch_ms[name] / total if total else 0.0)
         eng = self._engines[served_name]
         overdue = max(0.0, -cand.slack_ms) if cand.partial else 0.0
         with eng.stats.lock:

@@ -14,6 +14,7 @@ worker imports this module.
 """
 
 import math
+import re
 
 import numpy as np
 import pytest
@@ -106,6 +107,23 @@ def test_gather_compiles_for_v5e(one_chip, tier, fmt, hot, d):
                 fn, one_chip, ids, ids, cache, ((CACHE_ROWS, 1), jnp.float32),
                 other, ((other_rows, 1), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+def test_tiered_gather_keeps_its_kernel_name(one_chip):
+    """The chip's trace names an op after its HLO instruction, and the
+    benchmark finds the gather by ``mtl_gather_tiered``: the Pallas call's
+    ``name`` keeps that name inside the op graph's named scopes."""
+    d = 32
+    ids = ((B * K,), jnp.int32)
+
+    def lookup(a, b, c, t):
+        with jax.named_scope("emb_lookup"):
+            return mtl_gather_two_level(a, b, c, t, dim=d, hot=1)
+    text = _compile_text(lookup, one_chip, ids, ids,
+                         _packed(CACHE_ROWS, d, "fp32"),
+                         _packed(ROWS, d, "fp32"))
+    assert re.search(r"%mtl_gather_tiered(\.\d+)? = \S+ custom-call\(",
+                     text)
 
 
 @pytest.mark.parametrize("d", [16, 32])

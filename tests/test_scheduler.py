@@ -93,7 +93,7 @@ def test_eight_models_two_threads_bit_exact():
     np.testing.assert_array_equal(got, want)           # bit-exact across modes
 
 
-def test_device_time_share_and_dispatch_counters():
+def test_dispatch_wall_share_and_dispatch_counters():
     rt = build_runtime(3, "shared", pool_size=2)
     rt.start()
     try:
@@ -102,11 +102,11 @@ def test_device_time_share_and_dispatch_counters():
         rt.stop()
     agg = rt.stats()
     assert agg.sched_dispatches >= 3                   # every model dispatched
-    assert abs(agg.device_time_share - 1.0) < 1e-9     # shares sum to 1
+    assert abs(agg.dispatch_wall_share - 1.0) < 1e-9   # shares sum to 1
     for name in rt.models:
         st = agg.per_model[name]
         assert st.sched_dispatches >= 1
-        assert 0.0 < st.device_time_share < 1.0
+        assert 0.0 < st.dispatch_wall_share < 1.0
         assert st.sched_preempted_slack_ms >= 0.0
     sched = rt.scheduler
     assert sched is not None and not sched.running     # stopped with the rt
