@@ -123,7 +123,7 @@ AGGREGATED_COUNTERS = (
     "sched_dispatches", "sched_preempted_slack_ms", "dispatch_wall_share",
     "queue_wait_ms_total", "batch_ms_total", "stack_ms_total",
     "observe_ms_total", "dispatch_ms_total", "device_wait_ms_total",
-    "readback_ms_total", "resolve_ms_total",
+    "readback_ms_total", "resolve_ms_total", "pool_wakes",
 )
 # emb_version and seconds_behind are aggregated by MAX, not sum — the
 # runtime handles them as customs (a sum of versions means nothing).
@@ -163,28 +163,42 @@ class RequestFuture:
     and across batches — because a single drain loop serves the queue
     FIFO. Done-callbacks run on the resolving thread (the worker, for an
     engine with ``start()`` called).
+
+    A future costs one plain lock and no ``threading.Event``: done-ness
+    is ``_callbacks`` swapped for None at resolution, and only a caller
+    that blocks in ``result`` on a pending future makes the ``Event`` it
+    waits on.
     """
 
     __slots__ = ("_event", "_lock", "_score", "_exc", "_callbacks",
                  "t_submit", "latency_ms")
 
     def __init__(self):
-        self._event = threading.Event()
-        self._lock = threading.Lock()   # guards _callbacks vs resolution
+        self._event: threading.Event | None = None   # made by a blocked result
+        self._lock = threading.Lock()   # guards _callbacks/_event vs resolution
         self._score: float | None = None
         self._exc: BaseException | None = None
-        self._callbacks: list[Callable[[RequestFuture], None]] = []
+        # pending callbacks; None once resolved
+        self._callbacks: list[Callable[[RequestFuture], None]] | None = []
         self.t_submit = time.perf_counter()
         self.latency_ms: float | None = None
 
     def done(self) -> bool:
-        return self._event.is_set()
+        return self._callbacks is None
 
     def result(self, timeout: float | None = None) -> float:
         """Block until resolved; returns the score (or re-raises the
         serving error that failed this request's batch)."""
-        if not self._event.wait(timeout):
-            raise TimeoutError(f"request not served within {timeout}s")
+        if self._callbacks is not None:
+            with self._lock:
+                pending = self._callbacks is not None
+                if pending and self._event is None:
+                    self._event = threading.Event()
+                event = self._event
+            # an Event made while pending is set by _finish, which swaps
+            # the callbacks under the same lock
+            if pending and not event.wait(timeout):
+                raise TimeoutError(f"request not served within {timeout}s")
         if self._exc is not None:
             raise self._exc
         return self._score
@@ -194,7 +208,7 @@ class RequestFuture:
         Callback exceptions are swallowed (stdlib-Future semantics): one
         bad callback must never block other requests from resolving."""
         with self._lock:
-            if not self._event.is_set():
+            if self._callbacks is not None:
                 self._callbacks.append(fn)
                 return
         self._run_callback(fn)
@@ -207,9 +221,11 @@ class RequestFuture:
 
     def _finish(self) -> None:
         with self._lock:
-            self._event.set()
-            cbs, self._callbacks = self._callbacks, []
-        for fn in cbs:
+            cbs, self._callbacks = self._callbacks, None
+            event = self._event
+        if event is not None:
+            event.set()
+        for fn in cbs or ():
             self._run_callback(fn)
 
     def _resolve(self, score: float, latency_ms: float) -> None:
@@ -300,6 +316,9 @@ class EngineStats:
     time), and ``dispatch_wall_share`` is this engine's fraction of the
     host wall time the pool spent in dispatches (shares over one
     scheduler's engines sum to 1; a host clock, not device time).
+    ``pool_wakes`` counts the submits that woke the pool: only a first
+    request into an empty queue or one that fills a bucket does, and
+    only while no pool thread has claimed the engine.
 
     The stage counters split each served batch's host time, one update
     per batch (``float`` ms totals of ``time.perf_counter``):
@@ -323,6 +342,7 @@ class EngineStats:
     sched_dispatches: int = 0
     sched_preempted_slack_ms: float = 0.0
     dispatch_wall_share: float = 0.0
+    pool_wakes: int = 0
     compute_ms_total: float = 0.0
     queue_wait_ms_total: float = 0.0
     batch_ms_total: float = 0.0
@@ -507,6 +527,7 @@ class InferenceEngine:
         self._worker: threading.Thread | None = None
         self._running = False
         self._scheduler = None        # set by DeviceScheduler.attach
+        self._claimed = False         # a pool thread holds this engine
         self._delta_source = None     # set by attach_delta_source
         # highest emb_version any compiled step has observed — the floor
         # the _runtime_env monotonicity hard-assert enforces
@@ -811,6 +832,7 @@ class InferenceEngine:
         queue is at ``max_queue_depth`` (backpressure)."""
         fut = RequestFuture()
         row = np.asarray(ids_row, dtype=np.int32)
+        sched = self._scheduler
         with self._cv:
             if (self.max_queue_depth is not None
                     and len(self._queue) >= self.max_queue_depth):
@@ -822,14 +844,28 @@ class InferenceEngine:
                     "is not keeping up — shed load or raise the bound"))
                 return fut
             self._queue.append((fut.t_submit, row, fut))
+            depth = len(self._queue)
+            # Wake the pool only on a readiness edge: a first request (a
+            # new hold deadline) or a full bucket (due now). Any other
+            # submit leaves this engine's pick and deadline as they were.
+            # An engine a pool thread has claimed needs no wake either:
+            # that thread re-polls every engine once its batch ends. The
+            # claim is read without the scheduler's lock but after the
+            # append, so a stale read loses no wake: a claim released
+            # before the read reads as released (we wake), and one
+            # released after it belongs to a thread whose next pick sees
+            # this request.
+            wake = (sched is not None and not self._claimed
+                    and (depth == 1 or depth in self.policy.buckets))
             with self.stats.lock:
-                self.stats.queue_depth = len(self._queue)
+                self.stats.queue_depth = depth
+                if wake:
+                    self.stats.pool_wakes += 1
             self._cv.notify()
         # outside _cv: the scheduler's pick loop holds its own lock while
         # polling next_ready (which takes _cv) — notifying it from inside
         # _cv would invert that order and deadlock
-        sched = self._scheduler
-        if sched is not None:
+        if wake:
             sched.notify()
         return fut
 

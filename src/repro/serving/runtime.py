@@ -120,6 +120,7 @@ class RuntimeStats:
     device_wait_ms_total: float
     readback_ms_total: float
     resolve_ms_total: float
+    pool_wakes: int
     per_model: dict[str, EngineStats]
 
 

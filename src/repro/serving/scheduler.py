@@ -64,9 +64,9 @@ from .engine import InferenceEngine, ReadyBatch
 
 __all__ = ["DeviceScheduler"]
 
-#: Cap on how long a pool thread sleeps waiting for a deadline: submits
-#: and busy-releases notify the pool anyway, this just bounds the damage
-#: if a notification is ever lost.
+#: Cap on how long a pool thread sleeps waiting for a deadline: readiness
+#: edges (a first request, a full bucket) and claim releases notify the
+#: pool anyway, this just bounds the damage if a notification is ever lost.
 _MAX_WAIT_S = 0.25
 
 
@@ -92,10 +92,10 @@ class DeviceScheduler:
             raise ValueError(f"pool_size must be >= 1, got {pool_size}")
         self.pool_size = pool_size
         self._engines: dict[str, InferenceEngine] = {}
-        # guards _engines/_busy/_dispatch_ms/n_dispatches and is the pool's
-        # wait target; never held across a dispatch (device compute)
+        # guards _engines/_dispatch_ms/n_dispatches and each engine's
+        # _claimed flag (written only under it), and is the pool's wait
+        # target; never held across a dispatch (device compute)
         self._cv = threading.Condition(threading.Lock())
-        self._busy: set[str] = set()
         self._dispatch_ms: dict[str, float] = {}
         self._workers: list[threading.Thread] = []
         self._running = False
@@ -105,7 +105,8 @@ class DeviceScheduler:
     def attach(self, name: str, engine: InferenceEngine) -> InferenceEngine:
         """Host ``engine`` under ``name``. Idempotent for the same
         (name, engine) pair; an attached engine's ``submit`` wakes the
-        pool instead of relying on a per-engine worker."""
+        pool on a readiness edge instead of relying on a per-engine
+        worker."""
         with self._cv:
             have = self._engines.get(name)
             if have is engine:
@@ -171,7 +172,8 @@ class DeviceScheduler:
         return bool(self._workers)
 
     def notify(self) -> None:
-        """Wake the pool (an attached engine got a submit)."""
+        """Wake the pool (an unclaimed attached engine's readiness
+        changed: a first request or a full bucket)."""
         with self._cv:
             self._cv.notify_all()
 
@@ -188,7 +190,7 @@ class DeviceScheduler:
         best_name, best = None, None
         wait_ms = None
         for name, eng in self._engines.items():
-            if name in self._busy:
+            if eng._claimed:
                 continue
             c = eng.next_ready(now)
             if c is None:
@@ -206,8 +208,7 @@ class DeviceScheduler:
         return ``(name, candidate)``; None once the pool stops. Caller
         holds ``_cv``. The first poll is a ``sched.pick`` span; if it
         finds nothing to claim, one ``sched.wait`` span covers the waits
-        and polls up to the claim — every submit wakes an idle pool
-        thread, so a span per wake-up would be a span per request."""
+        and polls up to the claim, however many wake-ups it takes."""
         if not self._running:
             return None
         with TraceAnnotation("sched.pick"):
@@ -224,7 +225,7 @@ class DeviceScheduler:
         # claim: one pool thread per engine at a time, so the queue
         # drains FIFO exactly as a dedicated worker would (bit-exact
         # scores, ordered futures)
-        self._busy.add(name)
+        self._engines[name]._claimed = True
         return name, cand
 
     def _pool_loop(self) -> None:
@@ -247,7 +248,7 @@ class DeviceScheduler:
                 eng._note_worker_error(exc)
             dt_ms = (time.perf_counter() - t0) * 1e3
             with self._cv:
-                self._busy.discard(name)
+                eng._claimed = False
                 if served:
                     self.n_dispatches += 1
                     self._dispatch_ms[name] += dt_ms

@@ -6,10 +6,13 @@ per-engine-worker mode, SLO-slack scheduling (a starved low-traffic
 model behind a high-traffic one still meets its ``TimeoutBatch``
 deadline), per-engine backpressure under the shared pool, the
 ``next_ready`` readiness view semantics, cross-intake-stream request
-coalescing, per-model device-time accounting, and the worker-error
-surfacing contract (``n_worker_errors`` + re-raise from ``stop()``).
+coalescing, per-model device-time accounting, the worker-error
+surfacing contract (``n_worker_errors`` + re-raise from ``stop()``), and
+the edge-triggered pool wake (a submit wakes the pool only on a first
+request or a full bucket of an unclaimed engine).
 """
 
+import collections
 import threading
 import time
 
@@ -23,6 +26,7 @@ from repro.models.ctr import CTR_MODELS
 from repro.serving import (BucketedBatch, DeviceScheduler, FixedBatch,
                            InferenceEngine, QueueFullError, ServingRuntime,
                            TimeoutBatch)
+from repro.serving import scheduler as scheduler_mod
 
 SCHEMA = CRITEO.scaled(2_000)
 SPEC_KW = dict(embed_dim=8, hidden=64, max_field=2_000)
@@ -341,3 +345,124 @@ def test_worker_error_surfaced_through_shared_pool_and_runtime_stop():
         rt.stop()                              # pool error resurfaces here
     assert rt.stats().n_worker_errors == 1
     rt.stop()                                  # idempotent once drained
+
+
+# --- edge-triggered pool wake -------------------------------------------------
+
+@pytest.fixture
+def no_backstop(monkeypatch):
+    """Stretch the pool's capped sleep to far past every test timeout, so
+    a request only a lost wake-up would strand fails its ``result``."""
+    monkeypatch.setattr(scheduler_mod, "_MAX_WAIT_S", 600.0)
+
+
+def _pooled(*policies, pool_size=2):
+    sched = DeviceScheduler(pool_size=pool_size)
+    engines = []
+    for i, policy in enumerate(policies):
+        model, params = make(seed=i)
+        eng = InferenceEngine(model, params, policy=policy)
+        eng.warmup()
+        engines.append(sched.attach(f"m{i}", eng))
+    return sched, engines
+
+
+def test_lone_request_served_after_the_hold(no_backstop):
+    sched, (eng,) = _pooled(TimeoutBatch(BucketedBatch((8, 16)),
+                                         max_wait_ms=5.0))
+    sched.start()
+    try:
+        time.sleep(0.05)                       # the pool is asleep
+        fut = eng.submit(rows_of(1)[0])
+        fut.result(timeout=30.0)
+    finally:
+        sched.stop()
+    assert fut.latency_ms >= 5.0               # held for the deadline
+    assert eng.stats.pool_wakes == 1           # the 0 -> 1 edge
+    assert eng.stats.batches_per_bucket == {8: 1}
+
+
+def test_burst_reaching_a_bucket_is_served_before_the_hold(no_backstop):
+    sched, (eng,) = _pooled(TimeoutBatch(BucketedBatch((8, 16)),
+                                         max_wait_ms=60_000.0))
+    sched.start()
+    try:
+        time.sleep(0.05)
+        futs = eng.submit_many(rows_of(8))
+        for f in futs:
+            f.result(timeout=30.0)             # not after the 60 s hold
+    finally:
+        sched.stop()
+    assert eng.stats.pool_wakes == 2           # first request, full bucket
+    assert eng.stats.batches_per_bucket == {8: 1}
+
+
+def test_idle_engine_served_while_another_is_claimed(no_backstop):
+    """Engine 0's batch holds its pool thread (a blocking done-callback);
+    a submit to engine 1 wakes the other thread, which serves it."""
+    sched, (busy, idle) = _pooled(
+        TimeoutBatch(FixedBatch(1), max_wait_ms=5.0),
+        TimeoutBatch(FixedBatch(8), max_wait_ms=5.0))
+    release = threading.Event()
+    held = busy.submit(rows_of(1)[0])
+    held.add_done_callback(lambda f: release.wait(60.0))
+    sched.start()                              # the callback runs in a claim
+    try:
+        deadline = time.perf_counter() + 30.0
+        while not (busy._claimed and held.done()):
+            assert time.perf_counter() < deadline
+            time.sleep(0.001)
+        fut = idle.submit(rows_of(1, seed=1)[0])
+        fut.result(timeout=30.0)
+        assert busy._claimed                   # still inside its batch
+    finally:
+        release.set()
+        sched.stop()
+    assert idle.stats.pool_wakes == 1 and idle.stats.n_requests == 1
+
+
+def test_closed_loop_wakes_rarely_and_scores_bit_exact(no_backstop):
+    """Pool 2, 2,048 outstanding, each resolved request replaced from
+    the submitting thread: nearly every submit finds the engine claimed
+    or mid-bucket, and every score equals the plan's own on its row."""
+    rt = ServingRuntime(pool_size=2)
+    model, params = make()
+    eng = rt.add_model("m", model, params,
+                       policy=TimeoutBatch(BucketedBatch((512,)),
+                                           max_wait_ms=5.0))
+    rt.warmup()
+    pool = np.stack(rows_of(1_024, seed=3))
+    outstanding, total = 2_048, 4 * 2_048
+    done_q = collections.deque()
+    futs = []
+
+    def send():
+        i = len(futs)
+        fut = rt.submit("m", pool[i % len(pool)])
+        fut.add_done_callback(lambda f: done_q.append(i))
+        futs.append(fut)
+
+    rt.start()
+    try:
+        for _ in range(outstanding):
+            send()
+        deadline = time.perf_counter() + 120.0
+        while len(futs) < total:
+            assert time.perf_counter() < deadline
+            if done_q:
+                done_q.popleft()
+                send()
+            else:
+                time.sleep(0.0001)
+        got = np.array([f.result(timeout=60.0) for f in futs],
+                       dtype=np.float32)
+    finally:
+        rt.stop()
+    st = rt.stats()
+    assert st.n_requests == total
+    assert st.pool_wakes <= 0.02 * st.n_requests, st.pool_wakes
+    plan = eng.plan_for(512)
+    want = np.concatenate([plan.predict(pool[i:i + 512])
+                           for i in range(0, len(pool), 512)])
+    idx = np.arange(total) % len(pool)
+    np.testing.assert_array_equal(got, want[idx])

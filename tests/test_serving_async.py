@@ -1,10 +1,11 @@
 """Async serving runtime tests (ISSUE-3 acceptance surface).
 
 Covers: futures-based intake (resolution values, submit order, latency
-stamps), the background worker draining a ``TimeoutBatch`` SLO without
-caller polling, refresh-without-recompile (plan-cache keys identical, zero
-new compiles, bit-exact vs ``DenseStore`` across ≥2 refreshes under zipf
-traffic), thread-safe stats with ``queue_depth``, the multi-model
+stamps, the future's own contract under racing threads), the background
+worker draining a ``TimeoutBatch`` SLO without caller polling,
+refresh-without-recompile (plan-cache keys identical, zero new compiles,
+bit-exact vs ``DenseStore`` across ≥2 refreshes under zipf traffic),
+thread-safe stats with ``queue_depth``, the multi-model
 ``ServingRuntime`` router, and the absence of the removed deprecated
 surfaces (``core.fused_embedding``, ``CTRServingEngine``).
 """
@@ -12,6 +13,7 @@ surfaces (``core.fused_embedding``, ``CTRServingEngine``).
 import importlib
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -103,6 +105,138 @@ def test_futures_resolve_in_submit_order_under_worker():
     assert within_batch_sorted, resolved
     np.testing.assert_allclose(got, direct_scores(model, params, rows),
                                rtol=1e-5, atol=1e-5)
+
+
+def _run_in_thread(fn):
+    """Start ``fn`` in a thread; returns the thread and a list that gets
+    ``("ok", value)`` or ``("raised", exc)``."""
+    out = []
+
+    def body():
+        try:
+            out.append(("ok", fn()))
+        except BaseException as exc:          # handed to the test
+            out.append(("raised", exc))
+    t = threading.Thread(target=body, daemon=True)
+    t.start()
+    return t, out
+
+
+def _callback_before_resolution():
+    fut, seen = RequestFuture(), []
+    fut.add_done_callback(lambda f: seen.append((f, threading.get_ident())))
+    assert seen == [] and not fut.done()
+    t, out = _run_in_thread(lambda: (fut._resolve(0.25, 1.5),
+                                     threading.get_ident())[1])
+    t.join(timeout=30.0)
+    assert not t.is_alive()
+    assert seen == [(fut, out[0][1])]                # on the resolving thread
+    assert fut.done() and fut.result() == 0.25 and fut.latency_ms == 1.5
+
+
+def _callback_after_resolution():
+    fut, seen = RequestFuture(), []
+    fut._resolve(0.5, 1.0)
+    fut.add_done_callback(seen.append)               # runs at once
+    assert seen == [fut]
+
+
+def _callback_that_raises():
+    fut, seen = RequestFuture(), []
+    fut.add_done_callback(lambda f: 1 / 0)
+    fut.add_done_callback(seen.append)
+    fut._resolve(0.75, 1.0)                          # swallowed, not raised
+    fut.add_done_callback(lambda f: 1 / 0)           # nor when done
+    assert seen == [fut] and fut.result() == 0.75
+
+
+def _timeout_zero_on_pending():
+    fut = RequestFuture()
+    with pytest.raises(TimeoutError, match=r"not served within 0s"):
+        fut.result(timeout=0)
+    assert not fut.done()
+    fut._resolve(0.5, 1.0)                          # still resolvable
+    assert fut.result(timeout=0) == 0.5
+
+
+def _blocked_result_wakes_on_resolve():
+    fut = RequestFuture()
+    t, out = _run_in_thread(lambda: fut.result(timeout=30.0))
+    time.sleep(0.02)                                 # let it block
+    fut._resolve(0.125, 1.0)
+    t.join(timeout=30.0)
+    assert not t.is_alive() and out == [("ok", 0.125)]
+
+
+def _blocked_result_wakes_on_fail():
+    fut, err = RequestFuture(), ValueError("batch failed")
+    t, out = _run_in_thread(lambda: fut.result(timeout=30.0))
+    time.sleep(0.02)
+    fut._fail(err)
+    t.join(timeout=30.0)
+    assert not t.is_alive() and out == [("raised", err)]
+
+
+def _fail_reraises():
+    fut, err = RequestFuture(), KeyError("boom")
+    fut._fail(err)
+    assert fut.done()
+    for timeout in (None, 0):
+        with pytest.raises(KeyError) as got:
+            fut.result(timeout=timeout)
+        assert got.value is err
+
+
+@pytest.mark.parametrize("case", [
+    _callback_before_resolution, _callback_after_resolution,
+    _callback_that_raises, _timeout_zero_on_pending,
+    _blocked_result_wakes_on_resolve, _blocked_result_wakes_on_fail,
+    _fail_reraises], ids=lambda f: f.__name__.strip("_"))
+def test_request_future_contract(case):
+    """``RequestFuture`` keeps one contract however it synchronises:
+    callbacks run once, on the resolving thread or at once if already
+    done, their exceptions swallowed; ``result`` returns, raises the
+    batch's error, or times out."""
+    case()
+
+
+def test_request_future_races_resolver_callbacks_and_waiters():
+    """2,000 futures resolved by one thread while two others race
+    ``add_done_callback`` and ``result(timeout=5)`` on each: every
+    callback runs exactly once and no waiter hangs."""
+    n = 2_000
+    futs = [RequestFuture() for _ in range(n)]
+    calls = [[] for _ in range(n)]
+    start = threading.Barrier(3)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+
+    def resolver():
+        start.wait(timeout=30.0)
+        for i, f in enumerate(futs):
+            time.sleep(0)            # let the racers reach a pending future
+            f._resolve(float(i), 0.0)
+
+    def racer(tag):
+        start.wait(timeout=30.0)
+        got = []
+        for i, f in enumerate(futs):
+            f.add_done_callback(lambda fut, i=i: calls[i].append(tag))
+            got.append(f.result(timeout=5.0))
+        return got
+
+    try:
+        threads = [_run_in_thread(resolver)] + [
+            _run_in_thread(lambda tag=tag: racer(tag)) for tag in "ab"]
+        for t, _ in threads:
+            t.join(timeout=60.0)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t, _ in threads)
+    want = [float(i) for i in range(n)]
+    assert [out for _, out in threads] == [
+        [("ok", None)], [("ok", want)], [("ok", want)]]
+    assert all(sorted(c) == ["a", "b"] for c in calls)
 
 
 # --- background worker --------------------------------------------------------
