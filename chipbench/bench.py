@@ -172,16 +172,17 @@ def _device_info(jax) -> dict:
             "count": len(devs), "memory_peak_bytes": peak}
 
 
-def window_traffic(streams: dict, mix: dict, sizes, seconds: float):
-    """``(rows, offsets, capacity)`` of one window of ``mix``: the request
-    rows (the schedule's, or the closed loop's pool), the due offsets of
-    an open loop (else None), and the size of the requests' book."""
+def window_traffic(streams: dict, mix: dict, schema: dict, seconds: float):
+    """``(rows, offsets, capacity)`` of one window of ``mix`` over a
+    configuration's request ``schema``: the request rows (the schedule's,
+    or the closed loop's pool), the due offsets of an open loop (else
+    None), and the size of the requests' book."""
     rng = streams["window"]
     if mix["loop"] == "open":
         offsets = loadgen.arrival_offsets(rng, mix["arrivals"], seconds)
-        rows = loadgen.request_rows(rng, offsets.size, sizes, mix["ids"])
+        rows = loadgen.request_rows(rng, offsets.size, schema, mix["ids"])
         return rows, offsets, offsets.size
-    rows = loadgen.request_rows(rng, mix["pool"], sizes, mix["ids"])
+    rows = loadgen.request_rows(rng, mix["pool"], schema, mix["ids"])
     return rows, None, int(mix["max_rate_per_s"] * seconds) \
         + mix["outstanding"]
 
@@ -217,7 +218,6 @@ class Session:
         self.mix = bench.mix(self.cell["traffic"])
         self.ref_model = bench.model(self.cfg["model"])
         self.streams = seed_streams(seed)
-        self.sizes = self.cfg["schema"]["field_sizes"]
         self.counter = CompileCounter()
         self.dep = None
         phases = {}
@@ -244,7 +244,7 @@ class Session:
             lap("plans")
             admit_rows = loadgen.request_rows(
                 self.streams["admit"], self.cfg["store"]["admit_requests"],
-                self.sizes, self.mix["ids"])
+                self.cfg["schema"], self.mix["ids"])
             self.dep.start()
             _serve_and_wait(self.dep, admit_rows)
             lap("admission_stream")
@@ -261,7 +261,8 @@ class Session:
             f"from the compile cache): {phases}")
 
     def traffic(self, mix: dict, seconds: float):
-        return window_traffic(self.streams, mix, self.sizes, seconds)
+        return window_traffic(self.streams, mix, self.cfg["schema"],
+                              seconds)
 
     def window(self, mix: dict, rows, offsets, capacity: int,
                seconds: float, traced: bool = False) -> Window:

@@ -37,7 +37,7 @@ def control_reading(bench, workload: str, seed: int,
     ref_model = bench.model(cfg["model"])
     streams = seed_streams(seed)
     rows, _, _ = window_traffic(streams, bench.mix(cell["traffic"]),
-                                cfg["schema"]["field_sizes"], seconds)
+                                cfg["schema"], seconds)
     w = jax.jit(lambda k: ref_model.init_weights(cfg, k))(streams["key"])
     checks = check.control_checks(cfg, ref_model, w, rows)
     return {"workload": workload, "seed": seed, "side": "control bfloat16",

@@ -54,12 +54,31 @@ def zipf_ids(rng: np.random.Generator, n: int, field_sizes,
     return np.clip(ids, 0, sizes.astype(np.int64) - 1).astype(np.int32)
 
 
-def request_rows(rng: np.random.Generator, n: int, field_sizes,
+def request_rows(rng: np.random.Generator, n: int, schema: dict,
                  ids: dict) -> np.ndarray:
-    """``n`` request rows drawn as the mix's ``ids`` section says."""
+    """``n`` int32 request rows of a configuration's ``schema``, laid out
+    as ``registry``'s docstring says: the numeric counts
+    (``schema.numeric``), zipf with their own exponent, then each field's
+    ``h_i`` ids (``schema.hotness``), each drawn on its own from the
+    field's zipf under the mix's ``ids``. A schema with neither key draws
+    exactly what one id per field always drew: the same generator calls
+    in the same order.
+    """
     if ids.get("dist") != "zipf":
         raise ValueError(f"unknown id distribution {ids.get('dist')!r}")
-    return zipf_ids(rng, n, field_sizes, ids["exponent"])
+    sizes = schema["field_sizes"]
+    hot = schema.get("hotness")
+    if hot is not None:
+        if len(hot) != len(sizes) or min(hot) < 1:
+            raise ValueError(f"hotness {hot} does not give each of the "
+                             f"{len(sizes)} fields one id or more")
+        sizes = np.repeat(np.asarray(sizes, np.int64), hot)
+    num = schema.get("numeric")
+    if num is None:
+        return zipf_ids(rng, n, sizes, ids["exponent"])
+    counts = zipf_ids(rng, n, num["caps"], num["exponent"])
+    return np.concatenate([counts, zipf_ids(rng, n, sizes, ids["exponent"])],
+                          axis=1)
 
 
 def arrival_offsets(rng: np.random.Generator, arrivals: dict,

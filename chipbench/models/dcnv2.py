@@ -22,12 +22,14 @@ def tables(cfg: dict) -> dict:
     return {"emb": cfg["embed_dim"]}
 
 
-def init_weights(cfg: dict, key) -> dict:
+def init_weights(cfg: dict, key, table=rm.table) -> dict:
+    """Weights from ``key``; ``table(key, cfg, dim)`` makes each embedding
+    table (``refmath.table``, or the same rows in a taller array)."""
     k = len(cfg["schema"]["field_sizes"])
     d_in = k * cfg["embed_dim"]
     keys = jax.random.split(key, 3 + cfg["cross_layers"])
     return {
-        "emb": rm.table(keys[0], cfg, cfg["embed_dim"]),
+        "emb": table(keys[0], cfg, cfg["embed_dim"]),
         "mlp": rm.mlp_init(keys[1], (d_in, *cfg["hidden"])),
         "head": rm.dense(keys[2], d_in + cfg["hidden"][-1], 1),
         "cross": [rm.dense(keys[3 + i], d_in, d_in)

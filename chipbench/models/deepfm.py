@@ -22,12 +22,14 @@ def tables(cfg: dict) -> dict:
     return {"emb": cfg["embed_dim"], "fm_w": 1}
 
 
-def init_weights(cfg: dict, key) -> dict:
+def init_weights(cfg: dict, key, table=rm.table) -> dict:
+    """Weights from ``key``; ``table(key, cfg, dim)`` makes each embedding
+    table (``refmath.table``, or the same rows in a taller array)."""
     k = len(cfg["schema"]["field_sizes"])
     keys = jax.random.split(key, 4)
     return {
-        "emb": rm.table(keys[0], cfg, cfg["embed_dim"]),
-        "fm_w": rm.table(keys[1], cfg, 1),
+        "emb": table(keys[0], cfg, cfg["embed_dim"]),
+        "fm_w": table(keys[1], cfg, 1),
         "fm_bias": jnp.zeros((1,), jnp.float32),
         "mlp": rm.mlp_init(keys[2], (k * cfg["embed_dim"], *cfg["hidden"])),
         "deep_head": rm.dense(keys[3], cfg["hidden"][-1], 1),

@@ -15,45 +15,56 @@ parameter tree. The reference never sees anything built here.
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
+
+from chipbench import refmath
+
+
+def _tuples(v):
+    return tuple(_tuples(x) for x in v) if isinstance(v, list) else v
 
 
 def model_spec(cfg: dict):
+    """The program's ``CTRModelSpec``: the six keys every configuration
+    states, and each entry of its optional ``arch`` as a keyword (lists
+    as tuples). A key the program does not know raises its ``TypeError``."""
     from repro.models.ctr import CTRModelSpec
+    arch = {k: _tuples(v) for k, v in cfg.get("arch", {}).items()}
     return CTRModelSpec(name=cfg["name"],
                         field_sizes=tuple(cfg["schema"]["field_sizes"]),
                         embed_dim=cfg["embed_dim"],
                         hidden=tuple(cfg["hidden"]),
                         cross_layers=cfg.get("cross_layers", 0),
-                        dtype=cfg["dtype"])
+                        dtype=cfg["dtype"], **arch)
 
 
-def program_params(cfg: dict, tables: dict, weights: dict) -> dict:
-    """The program's parameter tree from the harness's weights: every
-    embedding table (``tables``: weight key -> row width) zero-padded to
-    the program's table height and packed into its lane-dense layout;
-    dense leaves pass through. Traceable, so the harness makes the
-    program's parameters on the device in one jitted call."""
+def _table_rows(cfg: dict, dim: int) -> int:
+    """The program's height of a table of ``dim``-wide rows."""
     from repro.embedding import FusedEmbeddingSpec
+    return FusedEmbeddingSpec(field_sizes=tuple(cfg["schema"]["field_sizes"]),
+                              dim=dim, dtype=cfg["dtype"]).rows
+
+
+def program_params(cfg: dict, ref_model, key: jax.Array) -> dict:
+    """The program's parameter tree from the harness's weights: each
+    embedding table (``ref_model.tables``: weight key -> row width) made
+    at the program's table height (``refmath.table``'s ``rows``, the same
+    rows then zeros), so no padded second copy of it is ever held, and
+    packed into the program's lane-dense layout, a reshape of a table
+    whole lines high; dense leaves pass through. Traceable."""
     from repro.kernels.multi_table_lookup import pack_rows
-    sizes = tuple(cfg["schema"]["field_sizes"])
-    out = dict(weights)
-    for key, dim in tables.items():
-        spec = FusedEmbeddingSpec(field_sizes=sizes, dim=dim,
-                                  dtype=cfg["dtype"])
-        t = weights[key]
-        t = jnp.pad(t, ((0, spec.rows - t.shape[0]), (0, 0)))
-        out[key] = {"mega_table": pack_rows(t)}
+
+    def table(k, c, dim):
+        return refmath.table(k, c, dim, rows=_table_rows(c, dim))
+    out = ref_model.init_weights(cfg, key, table=table)
+    for name in ref_model.tables(cfg):
+        out[name] = {"mega_table": pack_rows(out[name])}
     return out
 
 
 def make_params(cfg: dict, ref_model, key: jax.Array) -> dict:
     """The program's parameters, made on the device from ``key`` in one
     jitted call."""
-    def init(k):
-        return program_params(cfg, ref_model.tables(cfg),
-                              ref_model.init_weights(cfg, k))
-    return jax.jit(init)(key)
+    return jax.jit(lambda k: program_params(cfg, ref_model, k))(key)
 
 
 def _store(cfg: dict):

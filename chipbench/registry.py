@@ -8,6 +8,34 @@
 
 A configuration, mix, model or metric is added by adding its file and an
 entry in ``BENCHMARK.json``; no file of the harness names any of them.
+
+Beside the keys every configuration has (``model``, ``schema.field_sizes``,
+``embed_dim``, ``hidden``, ``dtype``, ...), four optional ones let a
+configuration state more without a change to the harness:
+
+    arch                 keys of the program's model spec beyond those,
+                         passed through as keywords (lists as tuples);
+                         a key the program does not know fails set-up
+    schema.numeric       {"caps": [c_1, ..., c_m], "exponent": s}: m
+                         numeric features, integer counts in [0, c_j),
+                         zipf with exponent s (the model transforms them)
+    schema.hotness       [h_1, ..., h_k]: ids per field, each drawn on its
+                         own from the field's zipf (default one each)
+    init.block_rows      B: tables drawn B rows at a time, in place, so a
+                         table that fills most of a chip can be made there
+
+A request row is int32, ``m + Σh`` wide::
+
+    [count_1 .. count_m | field 1's h_1 ids | ... | field k's h_k ids]
+
+``schema.field_sizes`` stays the vocabulary of each field: ids index one
+table of ``Σ field_sizes`` rows, field by field. ``refmath.split_rows``
+and ``refmath.pooled_lookup`` read such rows in a model's reference.
+
+A model file gives ``tables(cfg)`` (weight key -> row width),
+``init_weights(cfg, key, table=refmath.table)`` (its tables made through
+``table``), ``logits(cfg, weights, rows)``, ``flops_per_request(cfg)``
+and ``gather_bytes(cfg, batch)``; it imports nothing of the program.
 """
 
 from __future__ import annotations

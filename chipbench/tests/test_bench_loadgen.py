@@ -22,14 +22,15 @@ def test_zipf_ids_in_range_and_skewed():
 
 
 def test_same_seed_same_rows():
-    a = loadgen.request_rows(np.random.default_rng(7), 100, [50, 60],
+    schema = {"field_sizes": [50, 60]}
+    a = loadgen.request_rows(np.random.default_rng(7), 100, schema,
                              {"dist": "zipf", "exponent": 1.1})
-    b = loadgen.request_rows(np.random.default_rng(7), 100, [50, 60],
+    b = loadgen.request_rows(np.random.default_rng(7), 100, schema,
                              {"dist": "zipf", "exponent": 1.1})
     assert np.array_equal(a, b)
     with pytest.raises(ValueError):
-        loadgen.request_rows(np.random.default_rng(7), 1, [5],
-                             {"dist": "lognormal"})
+        loadgen.request_rows(np.random.default_rng(7), 1,
+                             {"field_sizes": [5]}, {"dist": "lognormal"})
 
 
 def test_poisson_offsets_rate_and_phases():

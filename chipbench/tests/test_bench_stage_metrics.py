@@ -46,3 +46,13 @@ def test_stage_metric_reads_nothing_without_its_counters(name):
     idle = _snap(10, 1, queue_wait_ms_total=1.0, batch_ms_total=1.0,
                  device_wait_ms_total=1.0)
     assert metric.read(_ctx(idle, idle)) is None
+
+
+def test_emb_hit_share_reads_the_store_counters():
+    metric = registry.Benchmark().metric("emb_hit_share")
+    s0 = _snap(1_000, 10, emb_cache_hits=7_000, emb_cache_misses=3_000)
+    s1 = _snap(2_000, 12, emb_cache_hits=7_390, emb_cache_misses=3_610)
+    # 390 of the window's 1,000 looked-up rows came from the cache
+    assert metric.read(_ctx(s0, s1)) == pytest.approx(39.0)
+    assert metric.read(_ctx(_snap(10, 1), _snap(20, 2))) is None
+    assert metric.read(_ctx(s1, s1)) is None
