@@ -36,7 +36,9 @@ def run(quick: bool = False) -> dict:
     float_cols = [np.asarray(ids[:, i], dtype=np.float32)
                   for i in range(schema.k)]
     results = {}
-    for model_name in (["dcnv2"] if quick else list(CTR_MODELS)):
+    # the paper's four models (DLRM-DCNv2's rows hold numeric counts too)
+    for model_name in (["dcnv2"] if quick else
+                       ["dcn", "dcnv2", "widedeep", "deepfm"]):
         spec = ctr_spec(model_name, "criteo", 16, 256, max_field=MAX_FIELD)
         model = CTR_MODELS[model_name](spec)
         params = model.init(jax.random.PRNGKey(0))

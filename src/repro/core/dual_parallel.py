@@ -1,7 +1,9 @@
 """DualParallelExecutor — contribution C1, tying C2–C5 together.
 
 A CTR model exposes its forward pass as an ``OpGraph`` with four modules:
-``embedding`` → (``explicit`` ∥ ``implicit``) → ``head``. The executor turns
+``embedding`` → (``explicit`` ∥ ``implicit``) → ``head``; a graph may name
+another pair of parallel branches (``OpGraph.branches``: DLRM-DCNv2 runs
+its ``embedding`` lookup beside its ``bottom`` MLP). The executor turns
 that graph into a runnable step function at one of four optimization levels,
 mirroring the paper's Fig.-8 breakdown exactly:
 
@@ -94,8 +96,9 @@ class DualParallelExecutor:
         n_before = graph.n_kernels()
         if self.level in ("fused_all", "dual"):
             graph = fuse_non_gemm(graph)
-        explicit = graph.by_module("explicit")
-        implicit = graph.by_module("implicit")
+        # the graph's two parallel branches: explicit ∥ implicit unless the
+        # model names another pair ("explicit_first" then pins its first)
+        explicit, implicit = (graph.by_module(m) for m in graph.branches)
         if self.level == "dual":
             # "explicit_first"/"implicit_first" pin the head branch
             # deterministically (equal-length branches included); only

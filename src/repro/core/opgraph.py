@@ -89,8 +89,12 @@ def register_fused_kernel(hint: str, fn: Callable[..., Any]) -> None:
 class OpGraph:
     """A small SSA-form operator DAG (ops added in topological order)."""
 
-    def __init__(self, graph_inputs: Sequence[str]):
+    def __init__(self, graph_inputs: Sequence[str],
+                 branches: tuple[str, str] = ("explicit", "implicit")):
         self.graph_inputs = tuple(graph_inputs)
+        # the two independent modules the executor interleaves (Alg. 2):
+        # the paper's explicit ∥ implicit interaction branches by default
+        self.branches = tuple(branches)
         self.ops: list[Op | FusedOp] = []
         self._producers: dict[str, str] = {}   # value edge -> op name
         # free-form structural annotations the emitters stamp at build time
@@ -264,7 +268,7 @@ def fuse_non_gemm(graph: OpGraph, use_kernels: bool = True) -> OpGraph:
     except that contiguous sub-runs carrying a registered-kernel hint are
     emitted as their own group so the Pallas kernel can serve them.
     """
-    fused = OpGraph(graph.graph_inputs)
+    fused = OpGraph(graph.graph_inputs, graph.branches)
     fused.meta = dict(graph.meta)
     ops = graph.ops
     i = 0

@@ -112,7 +112,9 @@ class StageClock(threading.local):
 class InferencePlan:
     """One compiled, batch-shape-specific inference artifact.
 
-    ``step`` maps ``ids (batch_size, n_fields) int32 -> logits``; it is the
+    ``step`` maps ``rows (batch_size, row_width) int32 -> logits`` (a
+    request row is the model's ``spec.row_width`` int32s: its numeric
+    counts, then its id slots; ``k`` ids for a one-hot model); it is the
     AOT-compiled executable at level "dual" and the warmed eager chain at
     the other levels. Plans are immutable: recompile to change anything —
     with one deliberate exception: ``runtime_inputs`` names the embedding
@@ -127,7 +129,7 @@ class InferencePlan:
     graph: OpGraph
     order: tuple[str, ...]
     step: Callable[[jax.Array], jax.Array]
-    n_fields: int
+    row_width: int
     donate: bool
     compile_ms: float
     runtime_inputs: tuple[str, ...] = ()
@@ -203,7 +205,7 @@ class InferencePlan:
         return out
 
     def predict(self, ids) -> np.ndarray:
-        """Sigmoid scores for ``ids`` ((n_fields,) or (b, n_fields) with
+        """Sigmoid scores for ``ids`` ((row_width,) or (b, row_width) with
         b ≤ batch_size); pads up to the plan's batch shape and slices the
         padding back off."""
         return self.fetch(*self.launch(ids))
@@ -266,7 +268,7 @@ def compile_plan(model, params: Any, level: str = "dual",
     """Compile one (model, level, batch shape) into an InferencePlan.
 
     Args:
-        model: a ``CTRModel`` (anything with ``spec.k`` and
+        model: a ``CTRModel`` (anything with ``spec.row_width`` and
             ``build_graph(params, level)``).
         params: the model's parameter pytree.
         level: one of ``repro.core.LEVELS`` (the Fig.-8 ladder).
@@ -321,7 +323,7 @@ def compile_plan(model, params: Any, level: str = "dual",
     with TraceAnnotation("plan.compile"):
         graph, order = executor.prepare(params)
         step_env = executor.make_step(graph, order, donate=donate)
-        n_fields = model.spec.k
+        row_width = model.spec.row_width
 
         # runtime store tensors (refreshable tiers only): extra step inputs,
         # re-read from the provider each call instead of baked into the program
@@ -340,7 +342,7 @@ def compile_plan(model, params: Any, level: str = "dual",
         if mesh is not None:
             from repro.distributed.sharding import input_shardings
             in_shardings = input_shardings(
-                mesh, {"ids": jax.ShapeDtypeStruct((batch_size, n_fields),
+                mesh, {"ids": jax.ShapeDtypeStruct((batch_size, row_width),
                                                    jnp.int32)})
             rt_shardings = {k: v.sharding for k, v in runtime.items()}
 
@@ -364,7 +366,7 @@ def compile_plan(model, params: Any, level: str = "dual",
             # use — with the resolved input/runtime shardings baked into the
             # lowered avals so GSPMD partitions the program for the mesh
             spec = {"ids": jax.ShapeDtypeStruct(
-                (batch_size, n_fields), jnp.int32,
+                (batch_size, row_width), jnp.int32,
                 sharding=in_shardings.get("ids"))}
             rt_spec = {k: jax.ShapeDtypeStruct(v.shape, v.dtype,
                                                sharding=rt_shardings.get(k))
@@ -384,7 +386,7 @@ def compile_plan(model, params: Any, level: str = "dual",
             def step(ids: jax.Array) -> jax.Array:
                 return step_env(bind_inputs(ids), bind_runtime())
             jax.block_until_ready(
-                step(jnp.zeros((batch_size, n_fields), dtype=jnp.int32)))
+                step(jnp.zeros((batch_size, row_width), dtype=jnp.int32)))
     compile_ms = (time.perf_counter() - t0) * 1e3
 
     key = plan_key_for(model, level, batch_size, branch_order,
@@ -394,7 +396,7 @@ def compile_plan(model, params: Any, level: str = "dual",
     stats.embedding_store = _store_describe(model)
     stats.compute_dtype = compute_dtype
     return InferencePlan(key=key, stats=stats, graph=graph,
-                         order=tuple(order), step=step, n_fields=n_fields,
+                         order=tuple(order), step=step, row_width=row_width,
                          donate=donate, compile_ms=compile_ms,
                          runtime_inputs=tuple(sorted(runtime)),
                          mesh=mesh, input_shardings=in_shardings,

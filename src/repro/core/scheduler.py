@@ -122,12 +122,15 @@ def depth_first_schedule(explicit: Sequence[Op | FusedOp],
 
 def full_order(graph: OpGraph, schedule: Schedule) -> list[str]:
     """Embed the two-branch queue into the whole-graph execution order:
-    embedding ops first (both branches consume the embedded features), then
-    the interleaved queue, then head ops."""
-    pre = [op.name for op in graph.ops if op.module == "embedding"]
-    post = [op.name for op in graph.ops
-            if op.module not in ("embedding", "explicit", "implicit")]
-    order = pre + schedule.queue + post
+    the ops emitted before either branch first (the embedding, which both
+    interaction branches consume), then the interleaved queue, then every
+    other op in graph order (the head)."""
+    queued = set(schedule.queue)
+    rest = [op.name for op in graph.ops if op.name not in queued]
+    first = next((i for i, op in enumerate(graph.ops)
+                  if op.name in queued), len(graph.ops))
+    pre = [op.name for op in graph.ops[:first]]
+    order = pre + schedule.queue + rest[len(pre):]
     if not graph.is_valid_order(order):
         raise ValueError(
             f"{schedule.policy} queue is not a valid topological order — "

@@ -22,7 +22,12 @@ class FusedEmbeddingSpec:
     Attributes:
         field_sizes: number of features n_i per field (len = k).
         dim:         shared embedding dimension d.
-        multi_hot:   max ids per field (1 = one-hot fields).
+        multi_hot:   max ids per field of the masked ``(b, k, h)`` lookup
+                     (1 = one-hot fields).
+        hotness:     fixed ids per field of a request row, ``(h_1, ..., h_k)``
+                     (default: one each). A row then holds ``Σh`` id
+                     slots, field ``i``'s ``h_i`` side by side, and a
+                     lookup sums each field's slots (:attr:`slot_offsets`).
         dtype:       parameter dtype.
         pad_rows_to: pad the mega-table height to a multiple (sharding);
                      the height is also rounded up to whole packed lines
@@ -40,11 +45,16 @@ class FusedEmbeddingSpec:
     dtype: str = "float32"
     pad_rows_to: int = 1
     row_dtype: str | None = None
+    hotness: tuple[int, ...] | None = None
 
     def __post_init__(self):
         if self.row_dtype not in (None, "int8"):
             raise ValueError(f"row_dtype must be None or 'int8', "
                              f"got {self.row_dtype!r}")
+        if self.hotness is not None and (
+                len(self.hotness) != self.k or min(self.hotness) < 1):
+            raise ValueError(f"hotness {self.hotness} does not give each of "
+                             f"the {self.k} fields one id or more")
 
     @property
     def k(self) -> int:
@@ -74,6 +84,19 @@ class FusedEmbeddingSpec:
     def offsets(self) -> np.ndarray:
         return np.concatenate(
             [[0], np.cumsum(self.field_sizes)[:-1]]).astype(np.int32)
+
+    @property
+    def slots(self) -> int:
+        """Id slots of a request row: ``Σ hotness``, or ``k``."""
+        return self.k if self.hotness is None else int(sum(self.hotness))
+
+    @property
+    def slot_offsets(self) -> np.ndarray:
+        """First table row of each id slot's field: :attr:`offsets`, each
+        repeated ``h_i`` times."""
+        if self.hotness is None:
+            return self.offsets
+        return np.repeat(self.offsets, self.hotness)
 
     @property
     def zero_row(self) -> int:

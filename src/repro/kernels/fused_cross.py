@@ -22,14 +22,38 @@ def _cross_v2_kernel(x0_ref, xw_ref, x_ref, out_ref):
     out_ref[...] = x0_ref[...] * xw_ref[...] + x_ref[...]
 
 
+#: bytes of one operand's block; four operands, double-buffered, stay
+#: well inside the 16 MiB of scoped VMEM a v5e kernel may use
+BLOCK_BYTES = 2 << 20
+LANES = 128
+
+
+def _width_block(rows: int, dim: int, itemsize: int) -> int:
+    """Columns of one block: the whole width while a ``rows``-high block
+    fits :data:`BLOCK_BYTES`, else the widest multiple of 128 lanes that
+    fits and divides ``dim`` (any fitting multiple of 128 when ``dim`` is
+    not one: the last block is then ragged)."""
+    if rows * dim * itemsize <= BLOCK_BYTES:
+        return dim
+    fit = max(1, BLOCK_BYTES // (rows * itemsize * LANES))
+    if dim % LANES:
+        return fit * LANES
+    tiles = dim // LANES
+    return max(m for m in range(1, min(fit, tiles) + 1)
+               if tiles % m == 0) * LANES
+
+
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
 def fused_cross_v2(x0: jax.Array, xw_plus: jax.Array, x: jax.Array, *,
                    block_b: int = 256, interpret: bool = False) -> jax.Array:
-    """DCNv2 cross tail: ``x0 * xw_plus + x`` in one VMEM pass."""
+    """DCNv2 cross tail: ``x0 * xw_plus + x`` in one VMEM pass, over a grid
+    of row blocks and, for widths whose row block would not fit
+    :data:`BLOCK_BYTES`, column blocks."""
     b, dim = x0.shape
     bm = min(block_b, b)
-    grid = (pl.cdiv(b, bm),)
-    spec = pl.BlockSpec((bm, dim), lambda i: (i, 0))
+    bd = _width_block(bm, dim, x0.dtype.itemsize)
+    grid = (pl.cdiv(b, bm), pl.cdiv(dim, bd))
+    spec = pl.BlockSpec((bm, bd), lambda i, j: (i, j))
     return pl.pallas_call(
         _cross_v2_kernel,
         grid=grid,

@@ -32,7 +32,8 @@ from repro.kernels import ref as kref
 from repro.quant import quantize_channels
 
 __all__ = ["CTRModelSpec", "CTRModel", "init_dense", "mlp_init",
-           "emit_embedding_ops", "emit_mlp_ops", "bce_loss"]
+           "emit_embedding_ops", "emit_mlp_ops", "emit_cross_v2_ops",
+           "bce_loss"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,6 +45,18 @@ class CTRModelSpec:
     hidden: tuple[int, ...] = (256, 256, 256)  # paper: 256/512/1024 ×3
     cross_layers: int = 3                    # paper: 3 (DCN/DCNv2)
     dtype: str = "float32"
+    #: numeric features leading each request row (integer counts)
+    n_numeric: int = 0
+    #: ids per field of a request row (None: one each)
+    hotness: tuple[int, ...] | None = None
+    #: widths of the MLP over the numeric features (DLRM's bottom MLP)
+    bottom: tuple[int, ...] = ()
+    #: rank of each low-rank cross layer's projection (DLRM-DCNv2)
+    cross_rank: int = 0
+    #: precision of every dense product (``jnp.matmul``'s ``precision``):
+    #: None is XLA's default, one bfloat16 pass on a TPU; "highest"
+    #: computes float32 products in float32
+    matmul_precision: str | None = None
 
     @property
     def k(self) -> int:
@@ -53,9 +66,17 @@ class CTRModelSpec:
     def input_dim(self) -> int:
         return self.k * self.embed_dim
 
+    @property
+    def row_width(self) -> int:
+        """int32s of one request row: the numeric counts, then every
+        field's ids (``[count_1 .. count_m | field 1's h_1 ids | ...]``);
+        ``k`` for one id a field and no numeric feature."""
+        return self.n_numeric + self.embedding_spec().slots
+
     def embedding_spec(self) -> FusedEmbeddingSpec:
         return FusedEmbeddingSpec(field_sizes=self.field_sizes,
-                                  dim=self.embed_dim, dtype=self.dtype)
+                                  dim=self.embed_dim, dtype=self.dtype,
+                                  hotness=self.hotness)
 
     def wide_spec(self) -> FusedEmbeddingSpec:
         """d=1 tables for linear terms (Wide&Deep / FM first order)."""
@@ -85,12 +106,14 @@ def mlp_init(key, dims: tuple[int, ...], dtype) -> list[dict]:
 
 def emit_embedding_ops(g: OpGraph, emb: FusedEmbeddingCollection,
                        params: dict, level: str, *, out: str = "x_embed",
-                       prefix: str = "emb") -> None:
+                       prefix: str = "emb", n_numeric: int = 0) -> None:
     """Embedding module ops over the store subtree at ``params[prefix]``.
 
     ``naive`` = k serial gathers + concat off the store's dense view (the
     baseline the paper measures against); otherwise ONE fused lookup
     through whatever tiers the store keeps (mega-table or cache+backing).
+    The id slots are the request rows' columns after the first
+    ``n_numeric``; a field of several slots is summed over them.
 
     Refreshable stores declare ``runtime_keys``: those leaves become extra
     *graph inputs* (edge names from :func:`repro.embedding.runtime_edge`)
@@ -100,13 +123,23 @@ def emit_embedding_ops(g: OpGraph, emb: FusedEmbeddingCollection,
     matching env for the eager/training path).
     """
     store_params = params[prefix]
+
+    def slots(ids):
+        return ids[:, n_numeric:] if n_numeric else ids
+
     if level == "naive":
         k = emb.spec.k
         offs = emb.spec.offsets
+        hot = emb.spec.hotness or (1,) * k
+        first = np.concatenate([[0], np.cumsum(hot)[:-1]])
         table = emb.dense_view(store_params)
         for i in range(k):
-            def one_field(ids, _i=i, _o=int(offs[i])):
-                return jnp.take(table, ids[:, _i] + _o, axis=0)
+            def one_field(ids, _s=int(first[i]) + n_numeric, _h=hot[i],
+                          _o=int(offs[i])):
+                if _h == 1:
+                    return jnp.take(table, ids[:, _s] + _o, axis=0)
+                return jnp.sum(jnp.take(table, ids[:, _s:_s + _h] + _o,
+                                        axis=0), axis=1)
             g.add(Op(f"{prefix}_lookup_{i}", one_field, ("ids",),
                      f"{prefix}_f{i}", module="embedding"))
         g.add(Op(f"{prefix}_concat",
@@ -122,20 +155,22 @@ def emit_embedding_ops(g: OpGraph, emb: FusedEmbeddingCollection,
             g.add_input(e)
 
         def fused_runtime(ids, *leaves):
-            return emb.apply({**static, **dict(zip(rt, leaves))}, ids)
+            return emb.apply({**static, **dict(zip(rt, leaves))},
+                             slots(ids))
 
         g.add(Op(f"{prefix}_fused", fused_runtime, ("ids",) + edges,
                  out, module="embedding"))
     else:
         g.add(Op(f"{prefix}_fused",
-                 lambda ids: emb.apply(store_params, ids),
+                 lambda ids: emb.apply(store_params, slots(ids)),
                  ("ids",), out, module="embedding"))
 
 
 def emit_mlp_ops(g: OpGraph, layers: list[dict], src: str, module: str,
                  prefix: str = "mlp", final_act: bool = False,
-                 compute_dtype: str = "fp32") -> str:
-    """Per-layer GEMM (flagged) + ReLU (non-GEMM, fusable).
+                 compute_dtype: str = "fp32", precision=None) -> str:
+    """Per-layer GEMM (flagged, at ``precision``) + ReLU (non-GEMM,
+    fusable).
 
     ``compute_dtype="int8"`` swaps each fp32 GEMM + ReLU pair for ONE
     fused quantized op (``kops.dense_matmul_q8``): the weight matrix is
@@ -174,7 +209,8 @@ def emit_mlp_ops(g: OpGraph, layers: list[dict], src: str, module: str,
                 + 4 * fan_in * fan_out - q8_bytes
             continue
         g.add(Op(f"{prefix}_gemm{li}",
-                 lambda h, _w=w, _b=b: h @ _w + _b,
+                 lambda h, _w=w, _b=b: jnp.matmul(h, _w, precision=precision)
+                 + _b,
                  (cur,), f"{prefix}_h{li}", is_gemm=True, module=module))
         cur = f"{prefix}_h{li}"
         if act:
@@ -183,6 +219,41 @@ def emit_mlp_ops(g: OpGraph, layers: list[dict], src: str, module: str,
                      (cur,), f"{prefix}_a{li}", module=module,
                      fused_hint="relu"))
             cur = f"{prefix}_a{li}"
+    return cur
+
+
+def emit_cross_v2_ops(g: OpGraph, layers: list[dict], x0: str,
+                      precision=None) -> str:
+    """DCN-V2 cross layers over edge ``x0`` (module ``explicit``), eq. (2):
+    ``x_{l+1} = x0 ⊙ (x_l W_l + b_l) + x_l``, or with a layer's rank-r
+    projection ``v``, ``x0 ⊙ ((x_l V_l) W_l + b_l) + x_l``. Each product is
+    a flagged GEMM at ``precision`` (bias inside the last one); the
+    elementwise tail carries the ``cross_v2_tail`` hint, so one Pallas
+    kernel serves every layer. Returns the last edge, ``explicit_out``."""
+    cur = x0
+    for li, layer in enumerate(layers):
+        src = cur
+        if "v" in layer:
+            g.add(Op(f"cross_v{li}",
+                     lambda x, _v=layer["v"]: jnp.matmul(
+                         x, _v, precision=precision),
+                     (cur,), f"xv{li}", is_gemm=True, module="explicit"))
+            src = f"xv{li}"
+        g.add(Op(f"cross_gemm{li}",
+                 lambda x, _w=layer["w"], _b=layer["b"]: jnp.matmul(
+                     x, _w, precision=precision) + _b,
+                 (src,), f"xw{li}", is_gemm=True, module="explicit"))
+        out_edge = ("explicit_out" if li == len(layers) - 1
+                    else f"x_cross{li}")
+        g.add(Op(f"cross_mul{li}",
+                 lambda x0_, xw: x0_ * xw,
+                 (x0, f"xw{li}"), f"cm{li}",
+                 module="explicit", fused_hint="cross_v2_tail"))
+        g.add(Op(f"cross_res{li}",
+                 lambda m, x: m + x,
+                 (f"cm{li}", cur), out_edge,
+                 module="explicit", fused_hint="cross_v2_tail"))
+        cur = out_edge
     return cur
 
 
@@ -248,8 +319,14 @@ class CTRModel:
     #: param-tree key of the main (tierable) embedding subtree — the one
     #: ``store=``/``use_store``/``refresh_cache`` operate on
     main_embedding_key = "emb"
+    #: True for models that read request rows wider than one id a field
+    #: (numeric features, several ids a field)
+    wide_rows = False
 
     def __init__(self, spec: CTRModelSpec, store=None):
+        if spec.row_width != spec.k and not self.wide_rows:
+            raise ValueError(f"{type(self).__name__} takes one id a field "
+                             "and no numeric feature")
         self.spec = spec
         self.embedding = FusedEmbeddingCollection(spec.embedding_spec(),
                                                   store=store)

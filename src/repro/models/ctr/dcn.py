@@ -48,11 +48,13 @@ class DCN(CTRModel):
         emit_embedding_ops(g, self.embedding, params, level)
 
         # explicit: cross network v1
+        p = self.spec.matmul_precision
         cur = "x_embed"
         n_layers = len(params["cross"])
         for li, layer in enumerate(params["cross"]):
             w, b = layer["w"], layer["b"]
-            g.add(Op(f"cross_gemm{li}", lambda x, _w=w: x @ _w,
+            g.add(Op(f"cross_gemm{li}",
+                     lambda x, _w=w: jnp.matmul(x, _w, precision=p),
                      (cur,), f"xlw{li}", is_gemm=True, module="explicit"))
             hint = f"dcn_v1_tail_{id(self)}_{li}"
             register_fused_kernel(hint, _make_v1_kernel(b, first=(li == 0)))
@@ -71,14 +73,15 @@ class DCN(CTRModel):
         # implicit: deep MLP
         deep_out = emit_mlp_ops(g, params["mlp"], "x_embed", "implicit",
                                 prefix="deep", final_act=True,
-                                compute_dtype=compute_dtype)
+                                compute_dtype=compute_dtype, precision=p)
 
         # head
         hw, hb = params["head"]["w"], params["head"]["b"]
         g.add(Op("head_concat",
                  lambda a, b_: jnp.concatenate([a, b_], axis=1),
                  ("explicit_out", deep_out), "stacked", module="head"))
-        g.add(Op("head_gemm", lambda h: h @ hw + hb, ("stacked",),
+        g.add(Op("head_gemm",
+                 lambda h: jnp.matmul(h, hw, precision=p) + hb, ("stacked",),
                  "logit", is_gemm=True, module="head"))
         return g
 

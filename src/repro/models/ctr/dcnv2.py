@@ -13,8 +13,8 @@ import jax.numpy as jnp
 
 from repro.core import Op, OpGraph
 
-from .common import (CTRModel, emit_embedding_ops, emit_mlp_ops, init_dense,
-                     mlp_init)
+from .common import (CTRModel, emit_cross_v2_ops, emit_embedding_ops,
+                     emit_mlp_ops, init_dense, mlp_init)
 
 
 class DCNv2(CTRModel):
@@ -38,35 +38,20 @@ class DCNv2(CTRModel):
         emit_embedding_ops(g, self.embedding, params, level)
 
         # explicit: cross network v2
-        cur = "x_embed"
-        n_layers = len(params["cross"])
-        for li, layer in enumerate(params["cross"]):
-            w, b = layer["w"], layer["b"]
-            g.add(Op(f"cross_gemm{li}",
-                     lambda x, _w=w, _b=b: x @ _w + _b,
-                     (cur,), f"xw{li}", is_gemm=True, module="explicit"))
-            out_edge = ("explicit_out" if li == n_layers - 1
-                        else f"x_cross{li}")
-            g.add(Op(f"cross_mul{li}",
-                     lambda x0, xw: x0 * xw,
-                     ("x_embed", f"xw{li}"), f"cm{li}",
-                     module="explicit", fused_hint="cross_v2_tail"))
-            g.add(Op(f"cross_res{li}",
-                     lambda m, x: m + x,
-                     (f"cm{li}", cur), out_edge,
-                     module="explicit", fused_hint="cross_v2_tail"))
-            cur = out_edge
+        p = self.spec.matmul_precision
+        emit_cross_v2_ops(g, params["cross"], "x_embed", precision=p)
 
         # implicit: deep MLP
         deep_out = emit_mlp_ops(g, params["mlp"], "x_embed", "implicit",
                                 prefix="deep", final_act=True,
-                                compute_dtype=compute_dtype)
+                                compute_dtype=compute_dtype, precision=p)
 
         # head
         hw, hb = params["head"]["w"], params["head"]["b"]
         g.add(Op("head_concat",
                  lambda a, b_: jnp.concatenate([a, b_], axis=1),
                  ("explicit_out", deep_out), "stacked", module="head"))
-        g.add(Op("head_gemm", lambda h: h @ hw + hb, ("stacked",),
+        g.add(Op("head_gemm",
+                 lambda h: jnp.matmul(h, hw, precision=p) + hb, ("stacked",),
                  "logit", is_gemm=True, module="head"))
         return g

@@ -69,11 +69,13 @@ class WideDeep(CTRModel):
                  ("wide_terms",), "explicit_out", module="explicit"))
 
         # implicit: deep MLP + its own head GEMM to a logit
+        p = self.spec.matmul_precision
         deep_out = emit_mlp_ops(g, params["mlp"], "x_embed", "implicit",
                                 prefix="deep", final_act=True,
-                                compute_dtype=compute_dtype)
+                                compute_dtype=compute_dtype, precision=p)
         hw, hb = params["deep_head"]["w"], params["deep_head"]["b"]
-        g.add(Op("deep_head", lambda h: h @ hw + hb, (deep_out,),
+        g.add(Op("deep_head",
+                 lambda h: jnp.matmul(h, hw, precision=p) + hb, (deep_out,),
                  "implicit_out", is_gemm=True, module="implicit"))
 
         # head: sum of branch logits

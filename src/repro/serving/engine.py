@@ -534,7 +534,14 @@ class InferenceEngine:
         self._version_floor = 0
         self.worker_error: BaseException | None = None
         self.stats = EngineStats(latency_window=latency_window)
+        # request rows lead with the model's numeric features; the store
+        # sees the id slots after them
+        self._n_numeric = model.spec.n_numeric
         staging = self._staging_store
+        if staging is not None and model.spec.row_width != model.spec.k:
+            raise NotImplementedError(
+                "a staging store serves one id a field and no numeric "
+                "feature")
         if staging is not None and mesh is not None:
             # stage-time publishes must land mesh-placed like everything
             # else in self.params (refresh already goes through place())
@@ -581,7 +588,7 @@ class InferenceEngine:
         coll = getattr(self.model, "embedding", None)
         if coll is None or not coll.store.refreshable:
             return
-        coll.observe(rows)
+        coll.observe(rows[:, self._n_numeric:] if self._n_numeric else rows)
         self._mirror_store_stats()
 
     def _mirror_store_stats(self) -> None:
@@ -826,8 +833,10 @@ class InferenceEngine:
 
     # -- request queue -------------------------------------------------------
     def submit(self, ids_row: np.ndarray) -> RequestFuture:
-        """Queue one request (a per-field id vector of shape (k,));
-        returns a future resolving to its score when its batch serves —
+        """Queue one request (a row of the model's ``spec.row_width``
+        int32s: its numeric counts, then its id slots; ``(k,)`` ids for a
+        one-hot model); returns a future resolving to its score when its
+        batch serves —
         or an already-failed future (:class:`QueueFullError`) when the
         queue is at ``max_queue_depth`` (backpressure)."""
         fut = RequestFuture()
@@ -1123,7 +1132,8 @@ class InferenceEngine:
 
     # -- one-shot --------------------------------------------------------------
     def predict(self, ids) -> np.ndarray:
-        """One-shot scores for ``ids`` ((k,) or (b, k)), bypassing the
+        """One-shot scores for request rows ``ids`` ((row_width,) or
+        (b, row_width)), bypassing the
         queue. Reuses the plan cache: the smallest covering bucket, with
         batches beyond the largest bucket chunked through it — so the
         cache stays bounded by the policy's bucket set no matter what

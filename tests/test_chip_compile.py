@@ -148,3 +148,25 @@ def test_dense_kernel_compiles_for_v5e(one_chip, kernel, d):
         text = _compile_text(fused_fm_second_order, one_chip,
                              ((B, K, d), f32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("width", [3456, 4992])
+def test_cross_tail_compiles_past_one_block(one_chip, width):
+    """DLRM-DCNv2's 3,456-wide cross (and 39 fields at d=128): a 512-row
+    block of the whole width would pass the scoped VMEM limit, so the
+    kernel's grid splits the width too."""
+    f32 = jnp.float32
+    text = _compile_text(fused_cross_v2, one_chip, ((512, width), f32),
+                         ((512, width), f32), ((512, width), f32))
+    assert re.search(r"%fused_cross_v2(\.\d+)? = \S+ custom-call\(", text)
+
+
+def test_slot_gather_compiles_at_dlrm_width(one_chip):
+    """A 512-request DLRM-DCNv2 batch: 214 id slots a request, one d=128
+    row each, through the cached store's two tiers of a 25.5M-row table."""
+    r, d, rows = 512 * 214, 128, 25_523_084
+    fn = lambda a, b, c, t: mtl_gather_two_level(a, b, c, t, dim=d, hot=1)
+    text = _compile_text(fn, one_chip, ((r,), jnp.int32), ((r,), jnp.int32),
+                         _packed(CACHE_ROWS, d, "fp32"),
+                         _packed(rows, d, "fp32"))
+    assert "tpu_custom_call" in text

@@ -6,9 +6,12 @@ dequant+bias+ReLU — scored against the fp32 plan and exercised through
 the engine with the full int8 stack (rows + matmuls) under refresh.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 import jax
+import jax.numpy as jnp
 
 from repro.configs import ctr_spec
 from repro.core import COMPUTE_DTYPES, compile_plan, plan_key_for
@@ -21,9 +24,15 @@ BATCH = 16
 
 def _setup(model_name, hidden=64):
     spec = ctr_spec(model_name, "criteo", 8, hidden, max_field=VOCAB)
+    if model_name == "dlrm_dcnv2":
+        # DLRM-DCNv2 reads numeric counts before the ids (13, as Criteo)
+        spec = dataclasses.replace(spec, n_numeric=13, bottom=(hidden, 8),
+                                   cross_rank=16)
     model = CTR_MODELS[model_name](spec)
     params = model.init(jax.random.PRNGKey(0))
     ids = synthetic_batch(CRITEO.scaled(VOCAB), 5, BATCH)["ids"]
+    counts = jnp.arange(BATCH * spec.n_numeric, dtype=jnp.int32) % 997
+    ids = jnp.concatenate([counts.reshape(BATCH, -1), ids], axis=1)
     return model, params, ids
 
 

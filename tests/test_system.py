@@ -5,6 +5,8 @@ C5 fusion bookkeeping, training convergence + checkpoint/restart, the
 serving engine, and pipeline determinism (fault-tolerance substrate).
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 import jax
@@ -24,20 +26,31 @@ SPEC_KW = dict(embed_dim=8, hidden=64, max_field=2_000)
 
 def make(model_name):
     spec = ctr_spec(model_name, "criteo", **SPEC_KW)
+    if model_name == "dlrm_dcnv2":
+        # DLRM-DCNv2 reads numeric counts before the ids (13, as Criteo)
+        spec = dataclasses.replace(spec, n_numeric=13, bottom=(64, 8),
+                                   cross_rank=16)
     model = CTR_MODELS[model_name](spec)
     params = model.init(jax.random.PRNGKey(0))
     return model, params
+
+
+def request_rows(model, ids):
+    """``ids`` behind the model's numeric counts, if it reads any."""
+    m = model.spec.n_numeric
+    counts = jnp.arange(ids.shape[0] * m, dtype=jnp.int32) % 997
+    return jnp.concatenate([counts.reshape(ids.shape[0], m), ids], axis=1)
 
 
 @pytest.mark.parametrize("model_name", list(CTR_MODELS))
 def test_level_ladder_is_numerically_invariant(model_name):
     """Paper Table I: DPIFrame is a pure re-scheduling layer."""
     model, params = make(model_name)
-    batch = synthetic_batch(SCHEMA, 0, 64)
+    ids = request_rows(model, synthetic_batch(SCHEMA, 0, 64)["ids"])
     outs = {}
     for level in ("naive", "fused_emb", "fused_all", "dual"):
         ex = DualParallelExecutor(model.build_graph, level=level)
-        outs[level] = np.asarray(ex.build(params)({"ids": batch["ids"]}))
+        outs[level] = np.asarray(ex.build(params)({"ids": ids}))
     for level, out in outs.items():
         np.testing.assert_allclose(out, outs["naive"], rtol=1e-5, atol=1e-6,
                                    err_msg=level)
