@@ -38,7 +38,6 @@ user code should not need to touch it directly anymore.
 from __future__ import annotations
 
 import dataclasses
-import threading
 import time
 from typing import Any, Callable
 
@@ -51,8 +50,8 @@ from .dual_parallel import (BRANCH_ORDERS, LEVELS, DualParallelExecutor,
                             ExecutorStats)
 from .opgraph import OpGraph
 
-__all__ = ["PlanKey", "InferencePlan", "compile_plan", "plan_key_for",
-           "place_params", "COMPUTE_DTYPES"]
+__all__ = ["PlanKey", "InferencePlan", "Launched", "compile_plan",
+           "plan_key_for", "place_params", "COMPUTE_DTYPES"]
 
 #: dense-branch compute dtypes a plan can be compiled at: fp32 GEMMs, or
 #: int8 matmuls with fused in-kernel dequant (kernels.dense_matmul_q8)
@@ -91,21 +90,6 @@ def plan_key_for(model, level: str, batch_size: int,
                    branch_order=branch_order, sharded=sharded,
                    store=_store_describe(model),
                    compute_dtype=compute_dtype)
-
-
-class StageClock(threading.local):
-    """Running totals, in ms, of one thread's plan calls by stage:
-    ``dispatch`` (pad, host-to-device copy, step and sigmoid dispatched),
-    ``wait`` (until the scores are ready on the device) and ``readback``
-    (device-to-host copy and slice). Kept per thread, as several threads
-    may call one plan at once; a caller takes the difference of two
-    :meth:`totals` around its own calls."""
-    dispatch_ms = 0.0
-    wait_ms = 0.0
-    readback_ms = 0.0
-
-    def totals(self) -> tuple[float, float, float]:
-        return self.dispatch_ms, self.wait_ms, self.readback_ms
 
 
 @dataclasses.dataclass(frozen=True)
@@ -149,9 +133,6 @@ class InferencePlan:
     #: eager levels): ``as_text()`` / ``memory_analysis()`` of the program
     #: every call runs
     executable: Any = None
-    #: running stage times of this plan's calls, per calling thread
-    clock: StageClock = dataclasses.field(default_factory=StageClock,
-                                          compare=False, repr=False)
 
     @property
     def level(self) -> str:
@@ -164,12 +145,11 @@ class InferencePlan:
     def __call__(self, ids: jax.Array) -> jax.Array:
         return self.step(ids)
 
-    def launch(self, ids) -> tuple[jax.Array, int]:
+    def launch(self, ids) -> Launched:
         """First half of :meth:`predict`: pad ``ids`` to the plan's batch
         shape, copy them to the device and dispatch the step and the
-        sigmoid, without waiting for either. Returns the scores still on
-        the device (padding rows included) and the count of real rows."""
-        t0 = time.perf_counter()
+        sigmoid, without waiting for either; the :class:`Launched` batch
+        comes back."""
         with TraceAnnotation("plan.dispatch"):
             ids = np.asarray(ids, dtype=np.int32)
             if ids.ndim == 1:
@@ -179,36 +159,49 @@ class InferencePlan:
                 raise ValueError(
                     f"{b} rows > plan batch_size {self.batch_size}; use an "
                     "InferenceEngine (it batches) or compile a bigger plan")
+            padded = ids
             if b < self.batch_size:
                 pad = np.zeros((self.batch_size - b, ids.shape[1]),
                                dtype=ids.dtype)
-                ids = np.concatenate([ids, pad])
-            logits = self.step(jnp.asarray(ids))
+                padded = np.concatenate([ids, pad])
+            logits = self.step(jnp.asarray(padded))
             scores = jax.nn.sigmoid(jnp.reshape(jnp.asarray(logits), (-1,)))
-        self.clock.dispatch_ms += (time.perf_counter() - t0) * 1e3
-        return scores, b
+        return Launched(scores, ids)
 
-    def fetch(self, scores: jax.Array, b: int) -> np.ndarray:
-        """Second half of :meth:`predict`: wait until ``scores`` are ready
-        on the device, then copy them to the host and keep the first
-        ``b``."""
-        t0 = time.perf_counter()
+    @staticmethod
+    def wait(launched: Launched) -> None:
+        """Block until a launched batch's scores are ready on the
+        device."""
         with TraceAnnotation("plan.wait"):
-            scores.block_until_ready()
-        t1 = time.perf_counter()
-        with TraceAnnotation("plan.readback"):
-            out = np.asarray(scores)[:b]
-        t2 = time.perf_counter()
-        clock = self.clock
-        clock.wait_ms += (t1 - t0) * 1e3
-        clock.readback_ms += (t2 - t1) * 1e3
-        return out
+            launched.scores.block_until_ready()
 
     def predict(self, ids) -> np.ndarray:
         """Sigmoid scores for ``ids`` ((row_width,) or (b, row_width) with
-        b ≤ batch_size); pads up to the plan's batch shape and slices the
-        padding back off."""
-        return self.fetch(*self.launch(ids))
+        b ≤ batch_size): :meth:`launch`, :meth:`wait`, then the scores
+        copied to the host with the padding sliced off. ``ids`` may also
+        be a batch this plan launched: its scores are then read back, once
+        ready, and not computed again."""
+        launched = ids
+        if not isinstance(ids, Launched):
+            launched = self.launch(ids)
+            self.wait(launched)
+        with TraceAnnotation("plan.readback"):
+            return np.asarray(launched.scores)[:len(launched.rows)]
+
+
+@dataclasses.dataclass(frozen=True)
+class Launched:
+    """A batch :meth:`InferencePlan.launch` dispatched and nobody has read
+    back yet: its ``scores`` on the device, padding rows included, and
+    the request ``rows`` they are for. It stands for those rows as an
+    array (``np.asarray(launched)``), so it can take their place in
+    :meth:`InferencePlan.predict`, and code that wraps ``predict`` and
+    reads its argument as rows sees the rows."""
+    scores: jax.Array
+    rows: np.ndarray
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.rows, dtype=dtype)
 
 
 def _shard_params(params: Any, mesh: jax.sharding.Mesh, model_axis: str,

@@ -20,7 +20,10 @@ The engine owns
   or by the **background worker thread** (``start()``/``stop()``), which
   drains the queue through the policy on its own so latency-SLO policies
   like ``TimeoutBatch`` fire without any caller polling (PCDF's
-  full-link-asynchronous serving loop);
+  full-link-asynchronous serving loop). Every drain keeps one batch in
+  flight: it launches the next due batch on the device before it reads
+  back the one it holds, so the device step runs while the host forms
+  the next batch (``_serve_step``);
 * **latency accounting** separating queueing from compute (bounded rolling
   p50/p99 window — see ``EngineStats``; all counters behind one lock so
   the worker and callers never race), plus per-bucket compile counts and
@@ -68,7 +71,7 @@ import numpy as np
 import jax
 from jax.profiler import TraceAnnotation
 
-from repro.core.plan import (InferencePlan, PlanKey, compile_plan,
+from repro.core.plan import (InferencePlan, Launched, PlanKey, compile_plan,
                              place_params, plan_key_for)
 from repro.embedding import StagingOverflowError
 from .batching import BatchPolicy, BucketedBatch
@@ -112,7 +115,7 @@ _PLAN_MIRROR = {
 #: matching RuntimeStats field, which the dataclass asserts at import).
 AGGREGATED_COUNTERS = (
     "n_requests", "n_batches", "n_rejected", "queue_depth",
-    "n_worker_errors",
+    "n_worker_errors", "n_overlapped_batches",
     "cache_hits", "cache_misses",
     "emb_cache_refreshes", "emb_staged_rows", "emb_prefetched_rows",
     "emb_h2d_bytes", "emb_staging_overflows", "emb_gather_bytes",
@@ -140,7 +143,9 @@ class ReadyBatch:
     ``partial`` tells the dispatcher whether serving it needs
     ``allow_partial`` — at dispatch time the engine re-decides against
     the *current* queue, so requests that arrived meanwhile coalesce into
-    (possibly a larger bucket of) the same dispatch.
+    (possibly a larger bucket of) the same dispatch. ``take == 0`` (due
+    now, not partial) is an engine that holds a launched batch while no
+    new one is due: the pick only reads the held batch back.
     """
     take: int
     bucket: int
@@ -323,22 +328,28 @@ class EngineStats:
     The stage counters split each served batch's host time, one update
     per batch (``float`` ms totals of ``time.perf_counter``):
     ``queue_wait_ms_total`` sums, over the batch's requests, the time
-    from submit to the pop that took them; ``batch_ms_total`` runs from
-    that pop to the last future resolved, and within it
-    ``stack_ms_total`` (rows stacked), ``observe_ms_total`` (the store's
-    admission counters fed), ``dispatch_ms_total`` /
+    from submit to the pop that took them. ``batch_ms_total`` is the
+    drain's own time in the batch's stages: from the pop through its
+    launch, then from the start of its readback to its last future
+    resolved (one stretch for a staging store's serial batch). It is not
+    the pop-to-resolve wall time, which overlaps the next batch's launch.
+    Within it: ``stack_ms_total`` (rows stacked), ``observe_ms_total``
+    (the store's admission counters fed), ``dispatch_ms_total`` /
     ``device_wait_ms_total`` / ``readback_ms_total`` (the plan call's
-    stages, ``InferencePlan.clock``) and ``resolve_ms_total`` (counters
+    stages, timed around the engine's own ``launch`` / ``wait`` /
+    ``predict`` calls on the plan) and ``resolve_ms_total`` (counters
     updated and every future resolved, callbacks included). Each has the
     profiler span of its stage (``engine.*``, ``plan.*``; see
-    ``docs/operations.md``). ``compute_ms_total`` is the host clock
-    around the whole plan call, staging included.
+    ``docs/operations.md``). ``compute_ms_total`` is the sum of the plan
+    call's three stages. ``n_overlapped_batches`` counts the batches
+    launched while the engine held an earlier one not yet read back.
     """
     n_requests: int = 0
     n_batches: int = 0
     n_rejected: int = 0
     queue_depth: int = 0
     n_worker_errors: int = 0
+    n_overlapped_batches: int = 0
     sched_dispatches: int = 0
     sched_preempted_slack_ms: float = 0.0
     dispatch_wall_share: float = 0.0
@@ -436,6 +447,31 @@ class EngineStats:
             return self.emb_prefetched_rows / n if n else 0.0
 
 
+@dataclasses.dataclass(eq=False, slots=True)
+class _Batch:
+    """One device batch in the drain: its requests, popped FIFO at
+    ``t_pop``, and its stage times in ms, each taken around the engine's
+    own call, since its launch and its readback may run on different
+    threads. From launch to readback ``out`` holds its scores on the
+    device; ``drain_ms`` sums the drain's time in its stages so far."""
+    items: list
+    bucket: int
+    t_pop: float
+    overlapped: bool = False          # launched while another was held
+    plan: InferencePlan | None = None
+    out: Launched | None = None
+    stack_ms: float = 0.0
+    observe_ms: float = 0.0
+    dispatch_ms: float = 0.0
+    wait_ms: float = 0.0
+    readback_ms: float = 0.0
+    drain_ms: float = 0.0
+
+    def fail(self, exc: BaseException) -> None:
+        for _, _, fut in self.items:
+            fut._fail(exc)
+
+
 class InferenceEngine:
     """Batched CTR inference over a cache of compiled ``InferencePlan``s.
 
@@ -528,6 +564,10 @@ class InferenceEngine:
         self._running = False
         self._scheduler = None        # set by DeviceScheduler.attach
         self._claimed = False         # a pool thread holds this engine
+        # the batch launched and not yet read back (written under
+        # _drain_lock); at most one, so the device runs it while the
+        # drain forms the next
+        self._held: _Batch | None = None
         self._delta_source = None     # set by attach_delta_source
         # highest emb_version any compiled step has observed — the floor
         # the _runtime_env monotonicity hard-assert enforces
@@ -607,14 +647,14 @@ class InferenceEngine:
             return store
         return None
 
-    def _predict_staged(self, plan: InferencePlan, rows: np.ndarray
+    def _predict_staged(self, batch: _Batch, rows: np.ndarray
                         ) -> np.ndarray:
-        """Run ``plan.predict`` with every embedding miss of ``rows``
-        resolved first. Caller holds ``_drain_lock`` (staging republishes
-        ``self.params`` and must not race a refresh).
+        """Scores of ``rows`` through ``batch.plan``, with every embedding
+        miss resolved first. Caller holds ``_drain_lock`` (staging
+        republishes ``self.params`` and must not race a refresh).
 
-        Fast path: one ``store.stage`` (mostly prefetch hits) + one
-        predict. A :class:`StagingOverflowError` — the batch's distinct
+        Fast path: one ``store.stage`` (mostly prefetch hits) + one plan
+        call. A :class:`StagingOverflowError` — the batch's distinct
         miss set exceeds the staging buffer — falls back to the
         synchronous chunked host gather: ``split_for_staging`` cuts the
         batch so every chunk's misses fit, and each chunk is staged and
@@ -622,9 +662,6 @@ class InferenceEngine:
         the bucket shape). Slower, never wrong.
         """
         store = self._staging_store
-        if store is None:
-            self._bump_mlp_quant(plan)
-            return plan.predict(rows)
         key = getattr(self.model, "main_embedding_key", "emb")
         try:
             with TraceAnnotation("engine.stage"):
@@ -636,14 +673,12 @@ class InferenceEngine:
                 with TraceAnnotation("engine.stage"):
                     staged = store.stage(self.params[key], chunk)
                 self.params = {**self.params, key: staged}
-                self._bump_mlp_quant(plan)
-                outs.append(plan.predict(chunk))
+                outs.append(self._fetch(batch, self._dispatch(batch, chunk)))
             self._mirror_store_stats()
             return np.concatenate(outs)
         self.params = {**self.params, key: staged}
         self._mirror_store_stats()
-        self._bump_mlp_quant(plan)
-        return plan.predict(rows)
+        return self._fetch(batch, self._dispatch(batch, rows))
 
     def _bump_mlp_quant(self, plan: InferencePlan) -> None:
         """Count one execution of a quantized-compute plan: every int8
@@ -858,13 +893,16 @@ class InferenceEngine:
             # new hold deadline) or a full bucket (due now). Any other
             # submit leaves this engine's pick and deadline as they were.
             # An engine a pool thread has claimed needs no wake either:
-            # that thread re-polls every engine once its batch ends. The
-            # claim is read without the scheduler's lock but after the
-            # append, so a stale read loses no wake: a claim released
-            # before the read reads as released (we wake), and one
-            # released after it belongs to a thread whose next pick sees
-            # this request.
+            # that thread re-polls every engine once its batch ends; nor
+            # does one that holds a launched batch, which is due now, so a
+            # pick that reads it back and re-polls is still to come. Both
+            # are read without the scheduler's lock but after the append,
+            # so a stale read loses no wake: a claim released (or a batch
+            # read back) before the read reads as such (we wake), and one
+            # after it belongs to a pick whose next poll sees this
+            # request.
             wake = (sched is not None and not self._claimed
+                    and self._held is None
                     and (depth == 1 or depth in self.policy.buckets))
             with self.stats.lock:
                 self.stats.queue_depth = depth
@@ -897,10 +935,20 @@ class InferenceEngine:
         (``TimeoutBatch.max_wait_ms``) or, for policies without their own
         deadline (``FixedBatch``/``BucketedBatch``), the same few-tick
         grace the per-engine worker loop applies (``8·worker_tick_ms``).
-        Returns None when the queue is empty or the policy would decline
-        even a forced partial.
+        An engine that holds a launched batch is due now whatever its
+        queue holds (``take == 0`` when no new batch is due), so the pool
+        comes straight back to read it back. Returns None when nothing is
+        held and the queue is empty or the policy would decline even a
+        forced partial.
         """
-        now = time.perf_counter() if now is None else now
+        cand = self._queued_candidate(
+            time.perf_counter() if now is None else now)
+        if self._held is not None and (cand is None or cand.slack_ms > 0.0):
+            return ReadyBatch(0, 0, 0.0, False)
+        return cand
+
+    def _queued_candidate(self, now: float) -> ReadyBatch | None:
+        """``next_ready`` of the queue alone."""
         with self._cv:
             pending = len(self._queue)
             if not pending:
@@ -943,12 +991,12 @@ class InferenceEngine:
 
     def stop(self, flush: bool = True) -> None:
         """Stop the worker (joins the thread, and a staging store's
-        prefetch worker). With ``flush`` (default),
-        force-drain whatever is still queued so no future is left
-        unresolved. Re-raises the last error a background drain swallowed
-        (the failing batch's futures were already failed at the time;
-        ``stats.n_worker_errors`` counts every one) — cleared on raise,
-        so the call stays idempotent."""
+        prefetch worker). With ``flush`` (default), force-drain whatever
+        is still queued so no future is left unresolved; without it, only
+        a held batch is read back. Re-raises the last error a background
+        drain swallowed (the failing batch's futures were already failed
+        at the time; ``stats.n_worker_errors`` counts every one) —
+        cleared on raise, so the call stays idempotent."""
         with self._cv:
             self._running = False
             self._cv.notify_all()
@@ -957,6 +1005,8 @@ class InferenceEngine:
             worker.join()
         if flush:
             self.flush()
+        else:
+            self._read_back_held()
         staging = self._staging_store
         if staging is not None:
             # join the prefetch worker too: no thread may outlive the
@@ -1027,97 +1077,192 @@ class InferenceEngine:
     def _serve(self, *, allow_partial: bool, force: bool) -> np.ndarray:
         out: list[np.ndarray] = []
         with self._drain_lock:
-            while True:
-                scores = self._serve_step(allow_partial=allow_partial,
-                                          force=force)
-                if scores is None:
-                    break
-                out.append(scores)
+            try:
+                while True:
+                    launched, scores = self._serve_step(
+                        allow_partial=allow_partial, force=force)
+                    if scores is not None:
+                        out.append(scores)
+                    elif not launched:
+                        break               # nothing due and nothing held
+            finally:
+                self._read_back_held()      # a failed batch strands none
         return np.concatenate(out) if out else np.empty((0,))
 
     def _serve_step(self, *, allow_partial: bool, force: bool
-                    ) -> np.ndarray | None:
-        """Serve at most *one* policy decision (one device batch); None
-        when the policy declines. The unit a shared-pool scheduler
-        dispatches — one batch per pick, so other engines' due batches
-        interleave between ours — and the loop body of ``_serve``. The
-        decision runs against the queue as it is *now*, so requests that
-        arrived since a scheduler's readiness poll coalesce in."""
-        with self._drain_lock:
-            with self._cv:
-                if not self._queue:
-                    return None
-                oldest_wait_ms = (
-                    math.inf if force else
-                    (time.perf_counter() - self._queue[0][0]) * 1e3)
-                decision = self.policy.decide(
-                    len(self._queue), oldest_wait_ms,
-                    allow_partial=allow_partial)
-                if decision is None:
-                    return None
-                items = [self._queue.popleft()
-                         for _ in range(decision.take)]
-                with self.stats.lock:
-                    self.stats.queue_depth = len(self._queue)
-            t_pop = time.perf_counter()
-            with TraceAnnotation("engine.batch"):
-                scores = self._run_batch(items, decision.bucket, t_pop)
-            self._maybe_auto_refresh()
-            return scores
+                    ) -> tuple[bool, np.ndarray | None]:
+        """One pick's work: the unit a shared-pool scheduler dispatches,
+        so other engines' due batches interleave between ours, and the
+        loop body of ``_serve``. Returns whether it launched a batch and
+        the scores of the batch it read back (None if it read none back).
 
-    def _run_batch(self, items: list, bucket: int, t_pop: float
-                   ) -> np.ndarray:
-        """Serve the requests ``items`` popped at ``t_pop`` as one batch
-        of ``bucket`` rows: stack, observe, plan call, counters, resolve.
-        Each stage is a profiler span of its own, timed into the stage
-        counters of ``EngineStats``; nothing here is timed per request."""
-        t_submit = [it[0] for it in items]
-        try:
-            # inside the try: a malformed row (ragged shape) must
-            # fail its batch's futures, not strand them unresolved
-            with TraceAnnotation("engine.stack"):
-                rows = np.stack([it[1] for it in items])
-            t_stacked = time.perf_counter()
-            with TraceAnnotation("engine.observe"):
-                self._observe_traffic(rows)
-            t_observed = time.perf_counter()
-            plan = self.plan_for(bucket)
-            # batch t+1's ids go to the async prefetch worker now,
-            # so its host-side miss gather overlaps batch t's
-            # stage+compute below (no-op for non-staging stores)
-            self._hint_upcoming()
-            stages0 = plan.clock.totals()
-            t0 = time.perf_counter()
-            # plan.predict pads to the bucket shape and slices the
-            # padding back off — one output transform shared with
-            # the one-shot path; _predict_staged resolves staging
-            # stores' misses first (pass-through otherwise)
-            scores = self._predict_staged(plan, rows)
-            t1 = time.perf_counter()
-        except Exception as exc:
-            for _, _, fut in items:
-                fut._fail(exc)
-            raise
-        dispatch, wait, readback = (
-            b - a for a, b in zip(stages0, plan.clock.totals()))
+        A one-deep pipeline: the engine may hold one batch launched on the
+        device and not yet read back. One step (1) pops, stacks, observes
+        and launches a batch if one is due, by the policy against the
+        queue as it is *now* (requests that arrived since a scheduler's
+        readiness poll coalesce in), while the held batch still runs;
+        (2) reads back the held batch and resolves its futures while the
+        new one runs; (3) holds the new one. A batch never waits for a
+        later one to form: the next step reads it back whether or not
+        anything new is due. The first launched is the first read back,
+        so futures resolve in submit order, and a failure fails only its
+        own batch's futures. A staging store's batch is served whole in
+        its step instead: staging republishes ``self.params`` per batch.
+        """
+        with self._drain_lock:
+            batch = self._pop(allow_partial=allow_partial, force=force)
+            if self._staging_store is not None:
+                if batch is None:
+                    return False, None
+                return True, self._serve_staged(batch)
+            held, self._held = self._held, None
+            launch_exc = None
+            if batch is not None:
+                batch.overlapped = held is not None
+                try:
+                    self._launch(batch)
+                except Exception as exc:
+                    launch_exc = exc
+            try:
+                scores = None if held is None else self._read_back(held)
+            finally:
+                if launch_exc is None:
+                    self._held = batch
+                else:                       # after the held batch resolved
+                    batch.fail(launch_exc)
+            if launch_exc is not None:
+                raise launch_exc
+            return batch is not None, scores
+
+    def _pop(self, *, allow_partial: bool, force: bool) -> _Batch | None:
+        """Take one policy decision's requests off the queue, FIFO; None
+        when the policy declines."""
+        with self._cv:
+            if not self._queue:
+                return None
+            oldest_wait_ms = (
+                math.inf if force else
+                (time.perf_counter() - self._queue[0][0]) * 1e3)
+            decision = self.policy.decide(
+                len(self._queue), oldest_wait_ms,
+                allow_partial=allow_partial)
+            if decision is None:
+                return None
+            items = [self._queue.popleft() for _ in range(decision.take)]
+            with self.stats.lock:
+                self.stats.queue_depth = len(self._queue)
+        return _Batch(items, decision.bucket, time.perf_counter())
+
+    def _read_back_held(self) -> None:
+        """Read back and resolve the held batch, if there is one."""
+        with self._drain_lock:
+            held, self._held = self._held, None
+            if held is not None:
+                self._read_back(held)
+
+    def _prepare(self, batch: _Batch) -> np.ndarray:
+        """Stack the batch's rows and feed them to the store's admission
+        counters; the plan the batch runs goes to ``batch.plan``."""
+        with TraceAnnotation("engine.stack"):
+            rows = np.stack([it[1] for it in batch.items])
+        t1 = time.perf_counter()
+        with TraceAnnotation("engine.observe"):
+            self._observe_traffic(rows)
+        batch.stack_ms = (t1 - batch.t_pop) * 1e3
+        batch.observe_ms = (time.perf_counter() - t1) * 1e3
+        batch.plan = self.plan_for(batch.bucket)
+        return rows
+
+    def _dispatch(self, batch: _Batch, rows: np.ndarray) -> Launched:
+        """``batch.plan.launch(rows)``, timed into the batch."""
+        self._bump_mlp_quant(batch.plan)
+        t0 = time.perf_counter()
+        out = batch.plan.launch(rows)
+        batch.dispatch_ms += (time.perf_counter() - t0) * 1e3
+        return out
+
+    def _fetch(self, batch: _Batch, out: Launched) -> np.ndarray:
+        """Wait for the launched ``out`` and read its scores back, each
+        timed into the batch. The readback is ``plan.predict``, the plan
+        call every served batch's scores come from."""
+        t0 = time.perf_counter()
+        batch.plan.wait(out)
+        t1 = time.perf_counter()
+        scores = batch.plan.predict(out)
+        batch.wait_ms += (t1 - t0) * 1e3
+        batch.readback_ms += (time.perf_counter() - t1) * 1e3
+        return scores
+
+    def _launch(self, batch: _Batch) -> None:
+        """Stack, observe and dispatch ``batch``, without waiting for
+        the device."""
+        with TraceAnnotation("engine.batch"):
+            batch.out = self._dispatch(batch, self._prepare(batch))
+        batch.drain_ms = (time.perf_counter() - batch.t_pop) * 1e3
+
+    def _read_back(self, batch: _Batch) -> np.ndarray:
+        """Wait for a launched batch, read its scores back and resolve
+        its futures; a failure fails them and is raised."""
+        t0 = time.perf_counter()
+        with TraceAnnotation("engine.batch"):
+            try:
+                scores = self._fetch(batch, batch.out)
+            except Exception as exc:
+                batch.fail(exc)
+                raise
+            self._resolve(batch, scores, t0)
+        self._maybe_auto_refresh()
+        return scores
+
+    def _serve_staged(self, batch: _Batch) -> np.ndarray:
+        """Serve one batch through a staging store, all in one go: stack,
+        observe, stage, plan call, resolve."""
+        with TraceAnnotation("engine.batch"):
+            try:
+                # inside the try: a malformed row (ragged shape) must
+                # fail its batch's futures, not strand them unresolved
+                rows = self._prepare(batch)
+                # batch t+1's ids go to the async prefetch worker now,
+                # so its host-side miss gather overlaps batch t's
+                # stage+compute below
+                self._hint_upcoming()
+                scores = self._predict_staged(batch, rows)
+            except Exception as exc:
+                batch.fail(exc)
+                raise
+            self._resolve(batch, scores, batch.t_pop)
+        self._maybe_auto_refresh()
+        return scores
+
+    def _resolve(self, batch: _Batch, scores: np.ndarray, t_start: float
+                 ) -> None:
+        """Count the batch into ``stats`` and resolve its futures in
+        submit order; ``t_start`` is where the drain's last stretch on it
+        began. Nothing here is timed per request."""
+        t_ready = time.perf_counter()
+        items, bucket = batch.items, batch.bucket
         take = len(items)
         st = self.stats
         with TraceAnnotation("engine.resolve"):
-            lat = [(t1 - ts) * 1e3 for ts in t_submit]
+            t_submit = [it[0] for it in items]
+            lat = [(t_ready - ts) * 1e3 for ts in t_submit]
             with st.lock:
                 st.n_requests += take
                 st.n_batches += 1
+                st.n_overlapped_batches += batch.overlapped
                 st.batches_per_bucket[bucket] = (
                     st.batches_per_bucket.get(bucket, 0) + 1)
                 st.padded_rows_total += bucket - take
-                st.compute_ms_total += (t1 - t0) * 1e3
+                st.compute_ms_total += (batch.dispatch_ms + batch.wait_ms
+                                        + batch.readback_ms)
                 st.latency_ms.extend(lat)
-                st.queue_wait_ms_total += (take * t_pop - sum(t_submit)) * 1e3
-                st.stack_ms_total += (t_stacked - t_pop) * 1e3
-                st.observe_ms_total += (t_observed - t_stacked) * 1e3
-                st.dispatch_ms_total += dispatch
-                st.device_wait_ms_total += wait
-                st.readback_ms_total += readback
+                st.queue_wait_ms_total += (
+                    take * batch.t_pop - sum(t_submit)) * 1e3
+                st.stack_ms_total += batch.stack_ms
+                st.observe_ms_total += batch.observe_ms
+                st.dispatch_ms_total += batch.dispatch_ms
+                st.device_wait_ms_total += batch.wait_ms
+                st.readback_ms_total += batch.readback_ms
             # futures resolve in submit order (items popped FIFO)
             for (_, _, fut), score, l in zip(items, scores, lat):
                 fut._resolve(float(score), l)
@@ -1126,9 +1271,8 @@ class InferenceEngine:
         # its futures resolved, and its other counters must be visible
         # before they resolve (callers read stats after ``result()``)
         with st.lock:
-            st.resolve_ms_total += (t_end - t1) * 1e3
-            st.batch_ms_total += (t_end - t_pop) * 1e3
-        return scores
+            st.resolve_ms_total += (t_end - t_ready) * 1e3
+            st.batch_ms_total += batch.drain_ms + (t_end - t_start) * 1e3
 
     # -- one-shot --------------------------------------------------------------
     def predict(self, ids) -> np.ndarray:
@@ -1152,7 +1296,11 @@ class InferenceEngine:
             # observe+stage+predict so a concurrent refresh can't interleave
             with self._drain_lock:
                 self._observe_traffic(ids)
-                return self._predict_staged(self.plan_for(bucket), ids)
+                batch = _Batch([], bucket, time.perf_counter())
+                batch.plan = self.plan_for(bucket)
+                return self._predict_staged(batch, ids)
         with self._drain_lock:    # observe never races a refresh/drain
             self._observe_traffic(ids)
-        return self._predict_staged(self.plan_for(bucket), ids)
+        plan = self.plan_for(bucket)
+        self._bump_mlp_quant(plan)
+        return plan.predict(ids)

@@ -70,7 +70,8 @@ class RuntimeStats:
     ``dispatch_wall_share`` sums the per-engine shares, so it reads ~1.0
     when a shared scheduler has dispatched anything and 0.0 in
     per-engine-worker mode. The stage totals (``queue_wait_ms_total``
-    to ``resolve_ms_total``, see ``EngineStats``) sum over engines.
+    to ``resolve_ms_total``, see ``EngineStats``) and
+    ``n_overlapped_batches`` sum over engines.
     Every counter named in ``engine.AGGREGATED_COUNTERS`` is a field
     here — :meth:`stats` sums them generically, and the import-time
     check below keeps the two definitions from drifting.
@@ -89,6 +90,7 @@ class RuntimeStats:
     n_rejected: int
     queue_depth: int
     n_worker_errors: int
+    n_overlapped_batches: int
     p50_ms: float
     p99_ms: float
     cache_hits: int

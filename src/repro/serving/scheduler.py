@@ -36,7 +36,10 @@ Scores are **bit-exact with per-engine-worker mode**: each engine is
 claimed by at most one pool thread at a time, so its queue still drains
 FIFO through the same ``_serve_step`` path, and each request's score
 depends only on its own row (padding rows are zeros), never on which
-batch composition served it.
+batch composition served it. An engine overlaps its own host work with
+its device step: a pick launches the engine's next due batch before it
+reads back the one the engine holds, and an engine that holds a batch is
+due now, so the pool comes straight back to it.
 
 Standalone::
 
@@ -75,10 +78,13 @@ class DeviceScheduler:
 
     Args:
         pool_size: worker threads sharing the device across every
-            attached engine. 2 is usually right on one device: one
-            thread blocks in device compute while the other forms and
-            stages the next batch. Thread count is ``pool_size``
-            regardless of how many engines attach.
+            attached engine. One thread at a time serves an engine, and
+            the engine itself hides its device step behind its host
+            work (it launches batch N+1 before it reads back batch N),
+            so a second thread helps across engines only: it serves
+            another engine's due batch while the first blocks in a
+            readback. 2 is usually right on one device. Thread count is
+            ``pool_size`` regardless of how many engines attach.
 
     Attributes:
         n_dispatches: total batches dispatched across all engines.
@@ -157,15 +163,22 @@ class DeviceScheduler:
         return self
 
     def stop(self) -> None:
-        """Stop and join the pool. In-flight dispatches finish; queued
-        requests stay queued (drain them via the engines' ``flush``/
-        ``stop`` — ``ServingRuntime.stop`` does). Idempotent."""
+        """Stop and join the pool. In-flight dispatches finish, and each
+        engine's held batch is read back; queued requests stay queued
+        (drain them via the engines' ``flush``/``stop`` —
+        ``ServingRuntime.stop`` does). Idempotent."""
         with self._cv:
             self._running = False
             self._cv.notify_all()
+            engines = list(self._engines.values())
         workers, self._workers = self._workers, []
         for t in workers:
             t.join()
+        for eng in engines:
+            try:
+                eng._read_back_held()
+            except Exception as exc:        # its futures already failed
+                eng._note_worker_error(exc)
 
     @property
     def running(self) -> bool:
@@ -236,12 +249,12 @@ class DeviceScheduler:
                 return
             name, cand = claimed
             eng = self._engines[name]
-            served = False
+            launched = served = False
             t0 = time.perf_counter()
             try:
-                scores = eng._serve_step(allow_partial=cand.partial,
-                                         force=False)
-                served = scores is not None
+                launched, scores = eng._serve_step(
+                    allow_partial=cand.partial, force=False)
+                served = launched or scores is not None
             except Exception as exc:
                 # same contract as the per-engine worker loop: the batch's
                 # futures already failed; count it, keep the pool alive
@@ -250,21 +263,26 @@ class DeviceScheduler:
             with self._cv:
                 eng._claimed = False
                 if served:
-                    self.n_dispatches += 1
                     self._dispatch_ms[name] += dt_ms
-                    self._publish_shares(name, cand)
+                    self._publish_shares(name, cand if launched else None)
                 # a freed engine may already have the next due batch —
                 # and other threads may be sleeping on a stale deadline
                 self._cv.notify_all()
 
-    def _publish_shares(self, served_name: str, cand: ReadyBatch) -> None:
-        """Mirror dispatch accounting into engine stats (holds _cv;
-        engine stats locks nest strictly inside it)."""
+    def _publish_shares(self, served_name: str,
+                        cand: ReadyBatch | None) -> None:
+        """Mirror dispatch accounting into engine stats, counting a
+        dispatch of ``cand`` unless it is None (the pick only read a
+        batch back). Holds _cv; engine stats locks nest strictly inside
+        it."""
         total = sum(self._dispatch_ms.values())
         for name, eng in self._engines.items():
             with eng.stats.lock:
                 eng.stats.dispatch_wall_share = (
                     self._dispatch_ms[name] / total if total else 0.0)
+        if cand is None:
+            return
+        self.n_dispatches += 1
         eng = self._engines[served_name]
         overdue = max(0.0, -cand.slack_ms) if cand.partial else 0.0
         with eng.stats.lock:

@@ -13,6 +13,7 @@ request or a full bucket of an unclaimed engine).
 """
 
 import collections
+import sys
 import threading
 import time
 
@@ -382,6 +383,96 @@ def test_lone_request_served_after_the_hold(no_backstop):
     assert eng.stats.batches_per_bucket == {8: 1}
 
 
+def test_lone_partial_read_back_by_a_pool_of_one(no_backstop):
+    """One pool thread launches a lone partial and holds it; nothing else
+    is ever due, and the same thread comes back to read it back."""
+    sched, (eng,) = _pooled(TimeoutBatch(BucketedBatch((8, 16)),
+                                         max_wait_ms=5.0), pool_size=1)
+    sched.start()
+    try:
+        fut = eng.submit(rows_of(1)[0])
+        fut.result(timeout=30.0)
+    finally:
+        sched.stop()
+    assert fut.latency_ms >= 5.0
+    assert eng._held is None
+    assert (eng.stats.n_batches, eng.stats.n_overlapped_batches) == (1, 0)
+    assert eng.stats.sched_dispatches == 1     # the readback is no dispatch
+
+
+def test_held_engine_is_due_now_behind_overdue_partials():
+    """An engine holding a launched batch is due now (slack 0, nothing to
+    take), so an overdue partial elsewhere still goes first; once that is
+    served, the held engine is picked and read back."""
+    sched = DeviceScheduler(pool_size=1)
+    model_a, params_a = make(seed=0)
+    a = InferenceEngine(model_a, params_a, policy=FixedBatch(8))
+    model_b, params_b = make(seed=1)
+    b = InferenceEngine(model_b, params_b,
+                        policy=TimeoutBatch(FixedBatch(8), max_wait_ms=5.0))
+    sched.attach("a", a)
+    sched.attach("b", b)
+    futs = a.submit_many(rows_of(8))
+    assert a._serve_step(allow_partial=False, force=False) == (True, None)
+    c = a.next_ready()
+    assert (c.take, c.slack_ms, c.partial) == (0, 0.0, False)
+    b.submit(rows_of(1, seed=1)[0])
+    name, cand, _ = sched._pick(time.perf_counter() + 0.05)
+    assert name == "b" and cand.partial and cand.slack_ms < 0.0
+    b.flush()
+    name, cand, _ = sched._pick(time.perf_counter())
+    assert name == "a" and cand.take == 0
+    launched, scores = a._serve_step(allow_partial=cand.partial, force=False)
+    assert not launched and len(scores) == 8
+    assert all(f.done() for f in futs) and a.next_ready() is None
+
+
+def test_pipelined_drain_under_thread_churn(no_backstop):
+    """Two engines, a pool of three and a 10 us switch interval, one
+    submitter thread each: every future resolves once, in its engine's
+    submit order, with the plan's own score, and nothing stays held."""
+    sched, engines = _pooled(
+        *[TimeoutBatch(FixedBatch(8), max_wait_ms=1.0) for _ in range(2)],
+        pool_size=3)
+    n = 200
+    rows = [np.stack(rows_of(n, seed=s)) for s in range(2)]
+    order = [[], []]
+    futs = [[], []]
+
+    def submitter(k):
+        for i in range(n):
+            f = engines[k].submit(rows[k][i])
+            f.add_done_callback(lambda _, i=i: order[k].append(i))
+            futs[k].append(f)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    sched.start()
+    try:
+        threads = [threading.Thread(target=submitter, args=(k,))
+                   for k in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60.0)
+        assert not any(t.is_alive() for t in threads)
+        got = [np.array([f.result(timeout=60.0) for f in fs])
+               for fs in futs]
+    finally:
+        sched.stop()
+        sys.setswitchinterval(old)
+    for k, eng in enumerate(engines):
+        assert order[k] == list(range(n))
+        assert eng._held is None and eng.stats.n_requests == n
+        assert eng.stats.n_overlapped_batches < eng.stats.n_batches
+        # a score depends on its own row only, not on the batch's other
+        # rows or its place there (padding rows are zeros)
+        plan = eng.plan_for(8)
+        want = np.concatenate([plan.predict(rows[k][i:i + 8])
+                               for i in range(0, n, 8)])
+        np.testing.assert_array_equal(got[k], want)
+
+
 def test_burst_reaching_a_bucket_is_served_before_the_hold(no_backstop):
     sched, (eng,) = _pooled(TimeoutBatch(BucketedBatch((8, 16)),
                                          max_wait_ms=60_000.0))
@@ -461,6 +552,9 @@ def test_closed_loop_wakes_rarely_and_scores_bit_exact(no_backstop):
     st = rt.stats()
     assert st.n_requests == total
     assert st.pool_wakes <= 0.02 * st.n_requests, st.pool_wakes
+    # 2,048 queued at the start: the second bucket launched while the
+    # first was held, at least
+    assert 0 < st.n_overlapped_batches < st.n_batches
     plan = eng.plan_for(512)
     want = np.concatenate([plan.predict(pool[i:i + 512])
                            for i in range(0, len(pool), 512)])

@@ -21,10 +21,12 @@ import jax
 
 from repro.configs import ctr_spec
 from repro.data.synthetic import CRITEO, zipf_ids
-from repro.embedding import CachedStore
+from repro.core.plan import InferencePlan
+from repro.embedding import CachedStore, HostBackedStore
 from repro.models.ctr import CTR_MODELS
-from repro.serving import (BucketedBatch, FixedBatch, InferenceEngine,
-                           RequestFuture, ServingRuntime, TimeoutBatch)
+from repro.serving import (BucketedBatch, DeviceScheduler, FixedBatch,
+                           InferenceEngine, RequestFuture, ServingRuntime,
+                           TimeoutBatch)
 
 SCHEMA = CRITEO.scaled(2_000)
 SPEC_KW = dict(embed_dim=8, hidden=64, max_field=2_000)
@@ -385,6 +387,142 @@ def test_raising_done_callback_does_not_strand_other_futures():
     eng.serve_pending()
     assert all(f.done() for f in futs)             # nobody left hanging
     assert seen == [futs[1].result()]
+
+
+# --- the drain's one-deep pipeline -------------------------------------------
+
+def _store(kind, model):
+    if kind == "cached":
+        return CachedStore(model.spec.embedding_spec(), capacity=128)
+    if kind == "host":
+        return HostBackedStore(model.spec.embedding_spec(), capacity=64,
+                               staging_capacity=1024)
+    return None
+
+
+@pytest.mark.parametrize("drain,store", [
+    ("serve_pending", None), ("flush", None), ("worker", None),
+    ("serve_pending", "cached"), ("serve_pending", "host")])
+def test_queued_buckets_overlap_in_submit_order_bit_exact(drain, store):
+    """Three full buckets queued: the drain launches each one while it
+    holds the one before (two overlapped), futures resolve in submit
+    order, scores equal the plan's own. A staging store keeps the serial
+    path and never overlaps."""
+    model, params = make()
+    eng = InferenceEngine(model, params, store=_store(store, model),
+                          policy=TimeoutBatch(FixedBatch(8),
+                                              max_wait_ms=60_000.0))
+    eng.warmup()
+    rows = rows_of(24)
+    order = []
+    futs = eng.submit_many(rows)
+    for i, f in enumerate(futs):
+        f.add_done_callback(lambda _, i=i: order.append(i))
+    if drain == "worker":
+        eng.start()
+        try:
+            got = np.array([f.result(timeout=60.0) for f in futs])
+        finally:
+            eng.stop()
+    else:
+        got = (eng.serve_pending(allow_partial=False)
+               if drain == "serve_pending" else eng.flush())
+    assert order == list(range(24))
+    assert eng._held is None
+    st = eng.stats
+    assert st.n_batches == 3
+    assert st.n_overlapped_batches == (0 if store == "host" else 2)
+    np.testing.assert_array_equal([f.result() for f in futs], got)
+    if store == "host":                        # staged misses: the engine's
+        want = eng.predict(np.stack(rows))     # own one-shot path
+    else:
+        plan = eng.plan_for(8)
+        want = np.concatenate([plan.predict(np.stack(rows[i:i + 8]))
+                               for i in range(0, 24, 8)])
+    np.testing.assert_array_equal(got, want)
+
+
+def _held_engine():
+    """An engine holding one launched full bucket, 3 more requests
+    queued behind it (a partial under a long hold)."""
+    model, params = make()
+    eng = InferenceEngine(model, params,
+                          policy=TimeoutBatch(FixedBatch(8),
+                                              max_wait_ms=60_000.0))
+    futs = eng.submit_many(rows_of(11))
+    assert eng._serve_step(allow_partial=False, force=False) == (True, None)
+    assert eng._held is not None and not futs[0].done()
+    return eng, futs
+
+
+@pytest.mark.parametrize("drain", ["serve_pending", "flush", "stop",
+                                   "stop_no_flush", "scheduler_stop"])
+def test_drains_leave_nothing_held(drain):
+    eng, futs = _held_engine()
+    if drain == "serve_pending":
+        eng.serve_pending(allow_partial=False)
+    elif drain == "flush":
+        eng.flush()
+    elif drain == "stop":
+        eng.stop()
+    elif drain == "stop_no_flush":
+        eng.stop(flush=False)
+    else:
+        DeviceScheduler(pool_size=1).attach("m", eng)
+        eng._scheduler.stop()
+    assert eng._held is None
+    assert all(f.done() for f in futs[:8])    # the held batch read back
+    flushed = drain in ("flush", "stop")
+    assert all(f.done() == flushed for f in futs[8:])
+    assert eng.pending() == (0 if flushed else 3)
+    assert eng.stats.n_batches == (2 if flushed else 1)
+
+
+@pytest.mark.parametrize("failing", ["launch", "readback"])
+def test_a_failing_batch_fails_only_its_own_futures(failing, monkeypatch):
+    """Batch N is held when batch N+1 launches. A launch that fails
+    fails N+1's futures alone, after N resolved; a readback that fails
+    fails N's alone, and N+1 is still held and resolves."""
+    model, params = make()
+    eng = InferenceEngine(model, params, policy=FixedBatch(4))
+    plan = eng.plan_for(4)
+    good = rows_of(8, seed=1)
+    n = eng.submit_many(good[:4])
+    eng._serve_step(allow_partial=False, force=False)      # N held
+    if failing == "launch":
+        n1 = eng.submit_many(good[4:7]) + [eng.submit(
+            np.zeros(len(SCHEMA.field_sizes) + 1, dtype=np.int32))]
+        err = ValueError
+    else:
+        n1 = eng.submit_many(good[4:])
+        err = RuntimeError
+        real = InferencePlan.wait
+        calls = []
+
+        def flaky(scores):
+            calls.append(scores)
+            if len(calls) == 1:
+                raise RuntimeError("readback lost")
+            real(scores)
+        monkeypatch.setattr(InferencePlan, "wait", staticmethod(flaky))
+    order = []
+    for tag, futs in (("n", n), ("n1", n1)):
+        for f in futs:
+            f.add_done_callback(lambda _, tag=tag: order.append(tag))
+    with pytest.raises(err):
+        eng._serve_step(allow_partial=False, force=False)
+    failed, served = (n1, n) if failing == "launch" else (n, n1)
+    if failing == "readback":
+        assert eng._held is not None          # N+1 launched and still held
+        eng.flush()
+    assert eng._held is None
+    assert order == ["n"] * 4 + ["n1"] * 4    # N first, either way
+    for f in failed:
+        with pytest.raises(err):
+            f.result(timeout=0)
+    rows = good[:4] if failing == "launch" else good[4:]
+    np.testing.assert_array_equal([f.result(timeout=0) for f in served],
+                                  plan.predict(np.stack(rows)))
 
 
 # --- refresh-without-recompile (ISSUE-3 satellite + acceptance) ---------------
