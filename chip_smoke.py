@@ -108,82 +108,51 @@ def reference_scores(params: dict, ids, offsets, dim: int):
 
 def phase_kernels(seed: int) -> None:
     """Every main-path Pallas kernel once at small shapes, on the chip,
-    against its jnp twin: the gathers bitwise (one-hot) or to 1e-6
-    (pooled: the kernel and the twin sum slots in different orders), the
-    dense kernels to their test tolerances."""
+    against its jnp twin: each store's lookup (dense, cached and host,
+    fp32 and int8; pooled fields through a ``hotness`` spec) bitwise
+    (one-hot) or to 1e-6 (pooled), the dense kernels to their test
+    tolerances."""
     import numpy as np
     import jax
     import jax.numpy as jnp
     from repro import quant
+    from repro.embedding import (CachedStore, DenseStore,
+                                 FusedEmbeddingCollection,
+                                 FusedEmbeddingSpec, HostBackedStore)
     from repro.kernels import ops, ref
-    from repro.kernels.multi_table_lookup import pack_rows
 
     rng = np.random.default_rng(seed)
     worst = 0.0
     for d in (16, 32):
-        sizes = [3000, 700, 41, 1200]
-        n = sum(sizes) + 1
-        offsets = jnp.asarray(np.cumsum([0] + sizes[:-1]), jnp.int32)
-        table = rng.normal(size=(n, d)).astype(np.float32) * 0.05
-        table[-1] = 0.0
-        q, qs = quant.quantize_rows(table)
-        q, qs = np.asarray(q), np.asarray(qs)
-        hot_rows = rng.choice(n, size=512, replace=False)
-        cmap = np.full(n, -1, np.int32)
-        cmap[hot_rows] = np.arange(512, dtype=np.int32)
-        warm = np.setdiff1d(np.arange(n), hot_rows)[:2000]
-        smap = np.full(n, -1, np.int32)
-        smap[warm] = np.arange(warm.size, dtype=np.int32)
-        fp = dict(lines=pack_rows(jnp.asarray(table)),
-                  cache=pack_rows(jnp.asarray(table[hot_rows])),
-                  staging=pack_rows(jnp.asarray(table[warm])))
-        i8 = dict(lines=pack_rows(jnp.asarray(q)),
-                  cache=pack_rows(jnp.asarray(q[hot_rows])),
-                  staging=pack_rows(jnp.asarray(q[warm])))
-        cs, bs, ss = (jnp.asarray(qs[hot_rows]), jnp.asarray(qs),
-                      jnp.asarray(qs[warm]))
-        cm, sm = jnp.asarray(cmap), jnp.asarray(smap)
+        sizes = (3000, 700, 41, 1200)
         for hot in (1, 3):
+            spec = FusedEmbeddingSpec(
+                field_sizes=sizes, dim=d,
+                hotness=None if hot == 1 else (hot,) * len(sizes))
+            dense = DenseStore(spec)
+            table = dense.init(jax.random.PRNGKey(seed))
             b = 1024 // hot
             ids = jnp.asarray(np.stack(
-                [rng.integers(0, s, size=(b, hot)) for s in sizes], axis=1),
-                jnp.int32)
-            mask = jnp.asarray(rng.integers(0, 2, size=ids.shape),
-                               jnp.float32)
-            one = ids[:, :, 0]
-            cases = {
-                "dense": lambda s: ops.multi_table_lookup(
-                    one, fp["lines"], offsets, strategy=s, dim=d)
-                if hot == 1 else ops.multi_table_lookup_multihot(
-                    ids, mask, fp["lines"], offsets, strategy=s, dim=d),
-                "two_level": lambda s: ops.multi_table_lookup_cached(
-                    one, fp["cache"], fp["lines"], cm, offsets,
-                    strategy=s, dim=d)
-                if hot == 1 else ops.multi_table_lookup_cached_multihot(
-                    ids, mask, fp["cache"], fp["lines"], cm, offsets,
-                    strategy=s, dim=d),
-                "two_level_q8": lambda s: ops.multi_table_lookup_cached_q8(
-                    one, i8["cache"], cs, i8["lines"], bs, cm, offsets,
-                    strategy=s, dim=d)
-                if hot == 1 else ops.multi_table_lookup_cached_q8_multihot(
-                    ids, mask, i8["cache"], cs, i8["lines"], bs, cm,
-                    offsets, strategy=s, dim=d),
-                "three_level": lambda s: ops.multi_table_lookup_host(
-                    one, fp["cache"], fp["staging"], cm, sm, offsets,
-                    strategy=s, dim=d)
-                if hot == 1 else ops.multi_table_lookup_host_multihot(
-                    ids, mask, fp["cache"], fp["staging"], cm, sm, offsets,
-                    strategy=s, dim=d),
-                "three_level_q8": lambda s: ops.multi_table_lookup_host_q8(
-                    one, i8["cache"], cs, i8["staging"], ss, cm, sm,
-                    offsets, strategy=s, dim=d)
-                if hot == 1 else ops.multi_table_lookup_host_q8_multihot(
-                    ids, mask, i8["cache"], cs, i8["staging"], ss, cm, sm,
-                    offsets, strategy=s, dim=d),
+                [rng.integers(0, s, size=(b, hot)) for s in sizes],
+                axis=1).reshape(b, -1), jnp.int32)
+            stores = {
+                "dense": dense,
+                "cached": CachedStore(spec, capacity=512),
+                "cached_q8": CachedStore(spec, capacity=512,
+                                         row_dtype="int8"),
+                "host": HostBackedStore(spec, capacity=512,
+                                        staging_capacity=8192),
+                "host_q8": HostBackedStore(spec, capacity=512,
+                                           staging_capacity=8192,
+                                           row_dtype="int8"),
             }
-            for name, run in cases.items():
-                got = np.asarray(run("pallas"))
-                want = np.asarray(run("jnp"))
+            for name, store in stores.items():
+                params = store.adopt(table)
+                if store.needs_staging:
+                    params = store.stage(params, np.asarray(ids))
+                coll = FusedEmbeddingCollection(spec, store=store)
+                got = np.asarray(coll.apply(params, ids, strategy="pallas"))
+                want = np.asarray(coll.apply(params, ids, strategy="jnp"))
                 diff = float(np.abs(got - want).max())
                 ok = (np.array_equal(got, want) if hot == 1
                       else diff <= 1e-6)

@@ -9,11 +9,11 @@ Two tiers, one index map:
   ``slot_of_row`` (rows,)    int32 index map: cache slot of each global
                              row, -1 when the row is not cached.
 
-A lookup is one *two-level gather*: cached rows are gathered from the
-cache, misses fall through to the backing store — on TPU via the
-scalar-prefetch Pallas kernel ``mtl_gather_two_level`` (the miss
-fall-through happens in the BlockSpec index map, so hits never touch
-backing rows beyond row 0), on CPU via the identical-math jnp path.
+A lookup is one *two-level gather*: :meth:`CachedStore._route` sends
+cached rows to the cache tier and misses to the backing tier, and one
+``gather_tiers`` call reads each slot from its tier only — on TPU the
+Pallas gather ``mtl_gather_tiered`` (one DMA per slot, from the tier the
+slot map chose), on CPU its identical-math jnp twin.
 
 Bit-exactness by construction: cache rows are verbatim copies of backing
 rows, so ``CachedStore`` and ``DenseStore`` built from the same key are
@@ -35,8 +35,8 @@ still never changes values *within the int8 representation*; what relaxes
 is fp32 bit-exactness (round-trip error ≤ scale/2 per element, gated
 model-level by ``benchmarks/accuracy_parity.py --quant``). The gather
 moves ``d + 4`` bytes per row instead of ``4·d`` and dequantizes in-kernel
-(``mtl_gather_two_level_q8``). Scales are runtime inputs like everything
-else, so refresh stays recompile-free.
+(``_route`` hands it each slot's scale with its row). Scales are runtime
+inputs like everything else, so refresh stays recompile-free.
 """
 
 from __future__ import annotations
@@ -49,7 +49,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro import quant
-from repro.kernels import ops as kops
 from repro.kernels.multi_table_lookup import pack_rows, set_rows
 
 from .spec import FusedEmbeddingSpec
@@ -182,34 +181,6 @@ class CachedStore(EmbeddingStore):
         return self.unpack(params["backing"])
 
     # -- lookup ------------------------------------------------------------
-    def lookup(self, params: dict, ids: jax.Array, offsets: jax.Array, *,
-               strategy: str = "auto",
-               interpret: bool | None = None) -> jax.Array:
-        if self.quantized:
-            return kops.multi_table_lookup_cached_q8(
-                ids, params["cache"], params["cache_scale"],
-                params["backing"], params["backing_scale"],
-                params["slot_of_row"], offsets,
-                strategy=strategy, interpret=interpret, dim=self.spec.dim)
-        return kops.multi_table_lookup_cached(
-            ids, params["cache"], params["backing"], params["slot_of_row"],
-            offsets, strategy=strategy, interpret=interpret,
-            dim=self.spec.dim)
-
-    def lookup_multihot(self, params: dict, ids: jax.Array, mask: jax.Array,
-                        offsets: jax.Array, *, strategy: str = "auto",
-                        interpret: bool | None = None) -> jax.Array:
-        if self.quantized:
-            return kops.multi_table_lookup_cached_q8_multihot(
-                ids, mask, params["cache"], params["cache_scale"],
-                params["backing"], params["backing_scale"],
-                params["slot_of_row"], offsets,
-                strategy=strategy, interpret=interpret, dim=self.spec.dim)
-        return kops.multi_table_lookup_cached_multihot(
-            ids, mask, params["cache"], params["backing"],
-            params["slot_of_row"], offsets,
-            strategy=strategy, interpret=interpret, dim=self.spec.dim)
-
     mesh_leaves = ("cache", "slot_of_row", "backing")
 
     def _route(self, env, rows, local, own, first):
@@ -222,10 +193,12 @@ class CachedStore(EmbeddingStore):
         row = jnp.where(hit, slots, local)
         scale = None
         if self.quantized:
+            # the backing's scales are replicated: indexed by global row
             scale = jnp.where(
                 hit,
-                jnp.take(env["cache_scale"][:, 0], jnp.maximum(slots, 0)),
-                jnp.take(env["backing_scale"][:, 0], rows))
+                jnp.take(env["cache_scale"][:, 0], jnp.maximum(slots, 0),
+                         mode="clip"),
+                jnp.take(env["backing_scale"][:, 0], rows, mode="clip"))
         return tier, row, (env["cache"], env["backing"]), scale
 
     # -- traffic / cache management ---------------------------------------

@@ -2,19 +2,24 @@
 
 All k per-field embedding tables are concatenated row-wise into ONE
 mega-table; per-field ids become global rows via static offsets. One gather
-(Pallas on TPU / single XLA gather on CPU) replaces k serial lookups —
-contribution C2, with C3's output-first allocation inside the kernel.
+replaces k serial lookups — contribution C2, with C3's output-first
+allocation inside the kernel. A multi-hot field is ``h`` id slots at its
+field's offset in that same gather, summed afterwards (:func:`pool_slots`).
 
 Where the mega-table *lives* is the store's business
 (:mod:`repro.embedding.store`): ``DenseStore`` holds it as one fast-memory
 leaf, ``CachedStore`` splits it into a device-resident hot-row cache plus a
-backing table. The collection delegates parameter init/placement and every
-lookup to its store, so models, plans, and engines never see the tiers.
+backing table, ``HostBackedStore`` keeps the backing in host memory. The
+collection delegates parameter init/placement and every lookup to its
+store, so models, plans, and engines never see the tiers. Every lookup
+takes one path: ``store._route`` picks each slot's tier, row and int8
+scale, and ``kops.gather_tiers`` gathers them (the Pallas kernel
+``mtl_gather_tiered`` on TPU, its jnp twin elsewhere).
 
 Distribution: the dense table (or the cached store's backing tier) is
 *row-sharded* over the ``model`` mesh axis (vocab-parallel). Inside a mesh
 plan, :meth:`FusedEmbeddingCollection.apply` runs the store's
-``lookup_sharded``: the masked-local-gather + psum pattern under
+``lookup_sharded``: the same route per shard, then a psum, under
 ``shard_map`` — the multi-chip generalization of Alg. 1.
 :func:`sharded_vocab_lookup` is the same pattern for LM vocab embeddings
 (a 1-table degenerate case).
@@ -87,14 +92,6 @@ class FusedEmbeddingCollection:
         if self.spec.hotness is None:
             return rows
         return pool_slots(rows, self.spec.hotness, self.spec.dim)
-
-    def apply_multihot(self, params: dict, ids: jax.Array, mask: jax.Array,
-                       *, strategy: str = "auto",
-                       interpret: bool | None = None) -> jax.Array:
-        """ids/mask (b, k, h) -> (b, k*d) sum-pooled."""
-        return self.store.lookup_multihot(params, ids, mask, self._offsets,
-                                          strategy=strategy,
-                                          interpret=interpret)
 
     def apply_serial(self, params: dict, ids: jax.Array) -> jax.Array:
         """Baseline: k separate gathers + concat (PyTorch-A analogue)."""

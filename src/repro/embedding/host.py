@@ -15,12 +15,13 @@ server (arXiv:2210.08804 / 2210.08803) brought to the DPIFrame stack:
            from a file (``np.memmap``), so the table need not fit host RAM
            either.
 
-A lookup is one **three-way select** inside the scalar-prefetch gather
-(``kernels.mtl_gather_three_level`` on TPU, jnp twin on CPU): cache hit →
-cache row, staged miss → staging row, neither → zero-guard. Correctness
-therefore rests on the serve path resolving every miss *before* the
-lookup: ``stage(params, ids)`` gathers the batch's uncached rows from the
-host backing into the staging buffer (most already there thanks to the
+A lookup is one **three-way select** (:meth:`HostBackedStore._route`) in
+front of the one gather (``gather_tiers``: the Pallas kernel on TPU, its
+jnp twin on CPU): cache hit → cache row, staged miss → staging row,
+neither → zero-guard. Correctness therefore rests on the serve path
+resolving every miss *before* the lookup: ``stage(params, ids)`` gathers
+the batch's uncached rows from the host backing into the staging buffer
+(most already there thanks to the
 :class:`~repro.embedding.prefetch.PrefetchPipeline`'s async hints) and
 publishes fresh ``staging``/``staging_slot_of_row`` tensors through the
 same double-buffered swap a refresh uses — all four device tensors are
@@ -45,7 +46,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro import quant
-from repro.kernels import ops as kops
 from repro.kernels.multi_table_lookup import pack_rows, set_rows, unpack_rows
 
 from .prefetch import PrefetchPipeline, StagingOverflowError
@@ -62,9 +62,9 @@ class HostBackedStore(EmbeddingStore):
         spec: the fused embedding schema.
         capacity: device cache rows ``C`` (clamped to ``spec.rows``).
         staging_capacity: staging slots ``S``; must cover one sample's
-            worst-case miss set (``k * multi_hot``) so chunked serving can
-            always make progress. Default ``max(4 * k * multi_hot, 256)``
-            (clamped to ``spec.rows``).
+            worst-case miss set (its ``spec.slots`` ids) so chunked
+            serving can always make progress. Default
+            ``max(4 * spec.slots, 256)`` (clamped to ``spec.rows``).
         backing_path: optional file for the third tier — the backing table
             is a ``np.memmap`` of this file instead of a RAM array. Create
             via :meth:`init`/:meth:`adopt` (writes the table), reopen an
@@ -75,8 +75,8 @@ class HostBackedStore(EmbeddingStore):
             writes the scales to a ``backing_path + ".scale"`` sidecar),
             the staging pipeline moves ``d + 4`` bytes per resolved row
             instead of ``4·d``, and the gather dequantizes in-kernel
-            (``mtl_gather_three_level_q8``). Default ``None`` keeps the
-            bit-exact full-precision tiers.
+            (``_route`` hands it each slot's scale with its row). Default
+            ``None`` keeps the bit-exact full-precision tiers.
 
     The param subtree holds **only the four device tensors**; the backing
     lives on the store object itself (``host_view()``), which is exactly
@@ -100,13 +100,13 @@ class HostBackedStore(EmbeddingStore):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = int(min(capacity, spec.rows))
-        per_sample = spec.k * spec.multi_hot
+        per_sample = spec.slots
         if staging_capacity is None:
             staging_capacity = max(4 * per_sample, 256)
         if staging_capacity < per_sample:
             raise ValueError(
                 f"staging_capacity {staging_capacity} < one sample's "
-                f"worst-case miss set k*multi_hot = {per_sample}; chunked "
+                f"worst-case miss set of {per_sample} ids; chunked "
                 "serving could never make progress")
         self.staging_capacity = int(min(staging_capacity, spec.rows))
         self.backing_path = os.fspath(backing_path) if backing_path else None
@@ -305,25 +305,22 @@ class HostBackedStore(EmbeddingStore):
             "(serial baselines, the 'naive' level).")
 
     # -- staging (the per-batch miss pipeline) -------------------------------
-    def _global_rows(self, ids, mask=None) -> np.ndarray:
-        """Local (…, k[, h]) ids -> clipped global rows, masked slots
-        dropped (their lookup is zero-guarded, nothing to stage)."""
+    def _global_rows(self, ids) -> np.ndarray:
+        """Local (…, slots) ids -> clipped global rows, each slot at its
+        field's offset."""
         ids = np.asarray(ids, dtype=np.int64)
         if ids.ndim == 1:
             ids = ids[None, :]
-        offs = self.spec.offsets
-        rows = ids + (offs[None, :] if ids.ndim == 2 else offs[None, :, None])
-        if mask is not None:
-            rows = rows[np.asarray(mask).astype(bool)]
+        rows = ids + self.spec.slot_offsets[None, :]
         return np.clip(rows.reshape(-1), 0, self.spec.rows - 1)
 
-    def miss_rows(self, ids, mask=None) -> np.ndarray:
+    def miss_rows(self, ids) -> np.ndarray:
         """Distinct global rows of this batch absent from the device cache
         (the set the staging buffer must resolve)."""
-        rows = np.unique(self._global_rows(ids, mask))
+        rows = np.unique(self._global_rows(ids))
         return rows[self._slot_of_row[rows] < 0]
 
-    def stage(self, params: dict, ids, mask=None) -> dict:
+    def stage(self, params: dict, ids) -> dict:
         """Resolve this batch's cache misses into the staging buffer and
         return the param subtree with fresh staging tensors.
 
@@ -334,7 +331,7 @@ class HostBackedStore(EmbeddingStore):
         miss set exceeds the buffer — callers serve in
         :meth:`split_for_staging` chunks instead.
         """
-        miss = self.miss_rows(ids, mask)
+        miss = self.miss_rows(ids)
         try:
             staged, already = self.pipeline.ensure(miss)
         except StagingOverflowError:
@@ -347,17 +344,17 @@ class HostBackedStore(EmbeddingStore):
         self.stats.h2d_bytes += staged * self.wire_row_bytes
         return {**params, **self._staging_leaves()}
 
-    def prefetch_hint(self, ids, mask=None) -> None:
+    def prefetch_hint(self, ids) -> None:
         """Queue an upcoming batch's rows for speculative off-thread
         staging (the engine calls this with batch t+1's rows while batch
         t's dense compute runs)."""
-        self.pipeline.hint(self._global_rows(ids, mask))
+        self.pipeline.hint(self._global_rows(ids))
 
     def split_for_staging(self, ids) -> list:
         """Split a (b, k) batch into row-contiguous chunks whose distinct
         miss sets each fit the staging buffer — the synchronous fallback
         for miss storms. Greedy; singleton chunks always fit because
-        ``staging_capacity >= k * multi_hot``."""
+        ``staging_capacity >= spec.slots``."""
         ids = np.asarray(ids)
         if ids.ndim == 1:
             ids = ids[None, :]
@@ -373,35 +370,22 @@ class HostBackedStore(EmbeddingStore):
         return chunks
 
     # -- lookup --------------------------------------------------------------
-    def lookup(self, params: dict, ids: jax.Array, offsets: jax.Array, *,
-               strategy: str = "auto",
-               interpret: bool | None = None) -> jax.Array:
+    def _route(self, env, rows, local, own, first):
+        # cache hit -> tier 0, staged miss -> tier 1, neither -> the zero
+        # guard. One device only: with no mesh_leaves, lookup_sharded never
+        # runs it (a sharded host tier needs sharded row maps first)
+        cslots = jnp.take(env["slot_of_row"], rows, axis=0)
+        sslots = jnp.take(env["staging_slot_of_row"], rows, axis=0)
+        hit = cslots >= 0
+        tier = jnp.where(hit, 0, jnp.where(sslots >= 0, 1, -1))
+        row = jnp.where(hit, cslots, jnp.maximum(sslots, 0))
+        scale = None
         if self.quantized:
-            return kops.multi_table_lookup_host_q8(
-                ids, params["cache"], params["cache_scale"],
-                params["staging"], params["staging_scale"],
-                params["slot_of_row"], params["staging_slot_of_row"],
-                offsets, strategy=strategy, interpret=interpret,
-                dim=self.spec.dim)
-        return kops.multi_table_lookup_host(
-            ids, params["cache"], params["staging"], params["slot_of_row"],
-            params["staging_slot_of_row"], offsets,
-            strategy=strategy, interpret=interpret, dim=self.spec.dim)
-
-    def lookup_multihot(self, params: dict, ids: jax.Array, mask: jax.Array,
-                        offsets: jax.Array, *, strategy: str = "auto",
-                        interpret: bool | None = None) -> jax.Array:
-        if self.quantized:
-            return kops.multi_table_lookup_host_q8_multihot(
-                ids, mask, params["cache"], params["cache_scale"],
-                params["staging"], params["staging_scale"],
-                params["slot_of_row"], params["staging_slot_of_row"],
-                offsets, strategy=strategy, interpret=interpret,
-                dim=self.spec.dim)
-        return kops.multi_table_lookup_host_multihot(
-            ids, mask, params["cache"], params["staging"],
-            params["slot_of_row"], params["staging_slot_of_row"], offsets,
-            strategy=strategy, interpret=interpret, dim=self.spec.dim)
+            scale = jnp.where(
+                hit,
+                jnp.take(env["cache_scale"][:, 0], row, mode="clip"),
+                jnp.take(env["staging_scale"][:, 0], row, mode="clip"))
+        return tier, row, (env["cache"], env["staging"]), scale
 
     # -- traffic / cache management ------------------------------------------
     def observe(self, global_rows: np.ndarray) -> None:

@@ -22,8 +22,6 @@ class FusedEmbeddingSpec:
     Attributes:
         field_sizes: number of features n_i per field (len = k).
         dim:         shared embedding dimension d.
-        multi_hot:   max ids per field of the masked ``(b, k, h)`` lookup
-                     (1 = one-hot fields).
         hotness:     fixed ids per field of a request row, ``(h_1, ..., h_k)``
                      (default: one each). A row then holds ``Σh`` id
                      slots, field ``i``'s ``h_i`` side by side, and a
@@ -41,7 +39,6 @@ class FusedEmbeddingSpec:
     """
     field_sizes: tuple[int, ...]
     dim: int
-    multi_hot: int = 1
     dtype: str = "float32"
     pad_rows_to: int = 1
     row_dtype: str | None = None

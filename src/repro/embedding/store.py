@@ -7,8 +7,10 @@ device-resident cache of hot rows over a larger backing store, exploiting
 the zipf skew of real CTR traffic. This module is that tier for the repro:
 
   ``EmbeddingStore``  the abstraction every embedding consumer talks to —
-                      parameter init/placement, one-hot and multi-hot
-                      lookup, traffic observation, cache bookkeeping.
+                      parameter init/placement, the lookup (one tier
+                      routing, :meth:`EmbeddingStore._route`, on one chip
+                      and on a mesh), traffic observation, cache
+                      bookkeeping.
   ``DenseStore``      today's monolithic mega-table (the default): one
                       ``mega_table`` leaf, every lookup one fused gather.
   ``CachedStore``     (``repro.embedding.cached``) hot-row cache of
@@ -21,7 +23,8 @@ the zipf skew of real CTR traffic. This module is that tier for the repro:
 ``FusedEmbeddingCollection`` delegates all lookups and parameter handling
 to its store, so the whole stack — ``kernels/ops.py`` →
 ``embedding/collection.py`` → ``core/plan.py`` → ``serving/engine.py`` —
-is store-agnostic.
+is store-agnostic. A store's only lookup-specific code is its ``_route``;
+the gather itself is ``kops.gather_tiers`` for every store.
 """
 
 from __future__ import annotations
@@ -42,6 +45,13 @@ from .spec import FusedEmbeddingSpec
 
 __all__ = ["StoreStats", "EmbeddingStore", "DenseStore", "runtime_edge",
            "validate_deltas"]
+
+
+def _flat_rows(ids: jax.Array, offsets: jax.Array) -> jax.Array:
+    """Alg. 1 lines 6–7 vectorized: (b, slots) local ids -> (b·slots,)
+    global mega-table rows."""
+    return (ids.astype(jnp.int32)
+            + offsets[None, :].astype(jnp.int32)).reshape(-1)
 
 
 def runtime_edge(prefix: str, leaf: str) -> str:
@@ -199,14 +209,11 @@ class EmbeddingStore:
 
     def gather_rows(self, lines, rows) -> jax.Array:
         """Logical rows ``rows`` of a packed table (refresh-time cache
-        fills) — the Pallas gather on a TPU, so the table is never
-        relayouted; a plain take on its row view elsewhere."""
+        fills): a one-tier ``gather_tiers``, so on a TPU the table is
+        never relayouted."""
         rows = jnp.asarray(rows, dtype=jnp.int32)
-        if kops.on_tpu():
-            from repro.kernels.multi_table_lookup import mtl_gather
-            return mtl_gather(rows, lines, dim=self.spec.dim,
-                              dtype=self.wire_dtype)
-        return jnp.take(self.unpack(lines), rows, axis=0)
+        return kops.gather_tiers(jnp.zeros_like(rows), rows, (lines,),
+                                 dim=self.spec.dim, dtype=self.wire_dtype)
 
     def _observe_traffic(self, rows: np.ndarray) -> None:
         """Wire-byte accounting shared by every tiered store's ``observe``:
@@ -278,29 +285,36 @@ class EmbeddingStore:
         raise NotImplementedError
 
     # -- lookup ------------------------------------------------------------
-    def lookup(self, params: dict, ids: jax.Array, offsets: jax.Array, *,
-               strategy: str = "auto",
-               interpret: bool | None = None) -> jax.Array:
-        """ids (b, k) -> (b, k*d)."""
-        raise NotImplementedError
-
-    def lookup_multihot(self, params: dict, ids: jax.Array, mask: jax.Array,
-                        offsets: jax.Array, *, strategy: str = "auto",
-                        interpret: bool | None = None) -> jax.Array:
-        """ids/mask (b, k, h) -> (b, k*d) sum-pooled."""
-        raise NotImplementedError
-
     #: param leaves :meth:`lookup_sharded` reads, the row-sharded table
     #: last; empty for stores without a vocab-parallel lookup
     mesh_leaves: tuple = ()
 
     def _route(self, env: dict, rows: jax.Array, local: jax.Array,
-               own: jax.Array, first: jax.Array):
-        """Per-shard tier routing for :meth:`lookup_sharded`: given global
-        ``rows``, their ``local`` row in this shard's slice of the table
-        and whether this shard ``own``s them (``first`` = model shard 0),
-        return ``(tier, row, tables, scale)`` for ``kops.gather_tiers``."""
+               own: jax.Array, first):
+        """The store's tier routing, the one place it decides where a row
+        is read. Given global ``rows`` (R,), their ``local`` row in this
+        shard's slice of the row-sharded table, whether this shard
+        ``own``s each of them (R,) and whether it is model shard 0
+        (``first``, which serves the replicated tiers), return
+        ``(tier, row, tables, scale)`` for ``kops.gather_tiers``: per slot
+        the table (-1 = zero), the row in it and, for int8 tables, the
+        fp32 scale of that row. On one device every row is owned, local
+        rows are global and ``first`` is true."""
         raise NotImplementedError
+
+    def lookup(self, params: dict, ids: jax.Array, offsets: jax.Array, *,
+               strategy: str = "auto",
+               interpret: bool | None = None) -> jax.Array:
+        """ids (b, slots) -> (b, slots*d): slot ``j`` reads global row
+        ``ids[:, j] + offsets[j]`` from the tier :meth:`_route` picks, all
+        slots in one gather."""
+        rows = _flat_rows(ids, offsets)
+        tier, row, tables, scale = self._route(
+            params, rows, rows, jnp.ones(rows.shape, bool), True)
+        out = kops.gather_tiers(tier, row, tables, scale,
+                                dim=self.spec.dim, dtype=self.wire_dtype,
+                                strategy=strategy, interpret=interpret)
+        return out.reshape(ids.shape[0], -1)
 
     def lookup_sharded(self, params: dict, ids: jax.Array,
                        offsets: jax.Array, mesh, model_axis: str = "model",
@@ -337,8 +351,7 @@ class EmbeddingStore:
             n_local = table.shape[0] * rows_per_line(dim, self.wire_dtype)
             axis = jax.lax.axis_index(model_axis)
             first = axis == 0
-            rows = (ids.astype(jnp.int32)
-                    + offsets[None, :].astype(jnp.int32)).reshape(-1)
+            rows = _flat_rows(ids, offsets)
             lo = axis * n_local if sharded else 0
             local = rows - lo
             own = (local >= 0) & (local < n_local)
@@ -357,13 +370,13 @@ class EmbeddingStore:
                              out_specs=ids_spec, check_vma=False)(ids, *leaves)
 
     # -- staging (only meaningful when ``needs_staging``) -------------------
-    def stage(self, params: dict, ids, mask=None) -> dict:
+    def stage(self, params: dict, ids) -> dict:
         """Resolve this batch's misses into device-reachable tensors and
         return the param subtree to serve it with. No-op pass-through for
         stores whose device tensors already cover every row."""
         return params
 
-    def prefetch_hint(self, ids, mask=None) -> None:
+    def prefetch_hint(self, ids) -> None:
         """Hint that ``ids`` will be served soon — staging stores resolve
         their misses off-thread while earlier batches compute. No-op."""
 
@@ -445,20 +458,6 @@ class DenseStore(EmbeddingStore):
 
     def dense_view(self, params: dict) -> jax.Array:
         return self.unpack(params["mega_table"])
-
-    def lookup(self, params: dict, ids: jax.Array, offsets: jax.Array, *,
-               strategy: str = "auto",
-               interpret: bool | None = None) -> jax.Array:
-        return kops.multi_table_lookup(
-            ids, params["mega_table"], offsets,
-            strategy=strategy, interpret=interpret, dim=self.spec.dim)
-
-    def lookup_multihot(self, params: dict, ids: jax.Array, mask: jax.Array,
-                        offsets: jax.Array, *, strategy: str = "auto",
-                        interpret: bool | None = None) -> jax.Array:
-        return kops.multi_table_lookup_multihot(
-            ids, mask, params["mega_table"], offsets,
-            strategy=strategy, interpret=interpret, dim=self.spec.dim)
 
     mesh_leaves = ("mega_table",)
 

@@ -6,6 +6,11 @@
   dense_matmul.py        int8 MLP matmul with fused dequant epilogue
   ops.py                 public wrappers + strategy dispatch
   ref.py                 reference oracles (incl. literal Alg. 1)
+
+Embedding lookups have one entry point, :func:`gather_tiers`: a store
+routes each slot to a tier, a row and an int8 scale
+(``EmbeddingStore._route``), and ``gather_tiers`` runs the one Pallas
+gather ``mtl_gather_tiered`` or its jnp twin ``ref.ref_tiered_gather``.
 """
 
 from .ops import (
@@ -13,10 +18,7 @@ from .ops import (
     fused_cross_v1,
     fused_cross_v2,
     fused_fm_second_order,
-    multi_table_lookup,
-    multi_table_lookup_cached,
-    multi_table_lookup_cached_multihot,
-    multi_table_lookup_multihot,
+    gather_tiers,
     multi_table_lookup_onehot,
     on_tpu,
 )
@@ -26,10 +28,7 @@ __all__ = [
     "fused_cross_v1",
     "fused_cross_v2",
     "fused_fm_second_order",
-    "multi_table_lookup",
-    "multi_table_lookup_cached",
-    "multi_table_lookup_cached_multihot",
-    "multi_table_lookup_multihot",
+    "gather_tiers",
     "multi_table_lookup_onehot",
     "on_tpu",
 ]

@@ -11,16 +11,14 @@ TPU adaptation of the paper's GPU design (DESIGN.md §2):
   into VMEM and writes one dense output block — output blocks map 1:1 to
   grid steps, so writes are perfectly sequential (output-first, C3).
 
-Production variants (all one kernel, :func:`_gather_kernel`, differing in
-how many tiers it reads and whether it dequantizes):
-
-  ``mtl_gather``              output-first row gather (the paper's algorithm).
-  ``mtl_gather_multihot``     same, sum-pooling ``hot`` ids per output row.
-  ``mtl_gather_two_level``    cache + backing tiers (CachedStore).
-  ``mtl_gather_three_level``  cache + staging tiers + zero-guard
-                              (HostBackedStore).
-  ``*_q8``                    int8 rows + per-row fp32 scale, dequantized
-                              in-kernel.
+One production kernel, :func:`mtl_gather_tiered` (body
+:func:`_gather_kernel`): each slot reads one row from whichever of 1–2
+packed table operands its tier names (dense; cache + backing; cache +
+staging), or zero for tier -1; int8 rows dequantize in-kernel by a
+per-slot fp32 scale; ``hot > 1`` sum-pools that many slots per output
+row. The stores decide each slot's tier, row and scale
+(``EmbeddingStore._route``) and reach the kernel through
+``ops.gather_tiers``.
 
 Strawmen kept for the benchmarks only:
 
@@ -286,190 +284,6 @@ def mtl_gather_tiered(tier: jax.Array, row: jax.Array, tables: tuple,
     if q8 or np.dtype(dtype).itemsize == 4:
         return out
     return jax.lax.bitcast_convert_type(out, dtype).reshape(r, dim)
-
-
-def _pick_scale(scales: tuple, tier: jax.Array, row: jax.Array
-                ) -> jax.Array:
-    """Per-slot fp32 scale of the tier each slot reads (0 for zero slots,
-    whose payload the kernel drops anyway)."""
-    out = jnp.zeros(tier.shape, jnp.float32)
-    for t, sc in enumerate(scales):
-        out = jnp.where(tier == t,
-                        jnp.take(sc.reshape(-1), row, axis=0, mode="clip"),
-                        out)
-    return out
-
-
-# ---------------------------------------------------------------------------
-# Public gathers: one tier (dense), two (cache + backing), three (cache +
-# staging + zero-guard); fp32 rows or int8 rows with in-kernel dequant
-# ---------------------------------------------------------------------------
-
-def mtl_gather(flat_rows: jax.Array, lines: jax.Array, *, dim: int,
-               dtype=None, interpret: bool = False) -> jax.Array:
-    """Output-first fused multi-table gather.
-
-    Args:
-        flat_rows: (R,) int32 *global* row ids into the mega-table
-                   (= per-field id + table offset, precomputed).
-        lines:     the mega-table packed by :func:`pack_rows`.
-        dim:       row width d.
-        dtype:     row dtype (default: ``lines.dtype``, i.e. fp32 rows).
-
-    Returns:
-        (R, d) gathered rows; caller reshapes (b*k, d) -> (b, k*d).
-    """
-    dtype = np.dtype(dtype or lines.dtype)
-    tier = jnp.zeros(flat_rows.shape, jnp.int32)
-    return mtl_gather_tiered(tier, flat_rows, (lines,), hot=1, dim=dim,
-                             dtype=dtype, interpret=interpret)
-
-
-def mtl_gather_multihot(flat_rows: jax.Array, lines: jax.Array, *, hot: int,
-                        dim: int, interpret: bool = False) -> jax.Array:
-    """Pooled (sum) gather of ``hot`` ids per output row.
-
-    Invalid slots must be pre-redirected to the all-zero row of the
-    mega-table (ops.py does), which realizes the 0/1 validity mask without
-    any in-kernel branching — masking by address, the TPU-friendly form.
-
-    Args:
-        flat_rows: (R*hot,) int32 global rows, row-major per output row.
-        lines:     packed fp32 mega-table whose zero row is all-zero.
-
-    Returns:
-        (R, d) pooled rows.
-    """
-    tier = jnp.zeros(flat_rows.shape, jnp.int32)
-    return mtl_gather_tiered(tier, flat_rows, (lines,), hot=hot, dim=dim,
-                             dtype=np.dtype(lines.dtype), interpret=interpret)
-
-
-def _two_level(flat_rows, slots):
-    hit = slots >= 0
-    return jnp.where(hit, 0, 1), jnp.where(hit, slots, flat_rows)
-
-
-def mtl_gather_two_level(flat_rows: jax.Array, slots: jax.Array,
-                         cache: jax.Array, backing: jax.Array, *, dim: int,
-                         hot: int = 1, interpret: bool = False) -> jax.Array:
-    """Two-level gather: cache hits from the hot-row cache, misses from the
-    backing table, pooled over ``hot`` ids per output row (hot=1 = plain
-    gather, the one-hot path).
-
-    The slot map decides per row which tier the kernel DMAs from — the TPU
-    analogue of HugeCTR's address indirection through the inference
-    parameter server's hashmap; the other tier is never read.
-
-    Args:
-        flat_rows: (R*hot,) int32 global rows into ``backing``.
-        slots:     (R*hot,) int32 cache slot per row, -1 = not cached
-                   (= ``slot_of_row[flat_rows]``, pre-gathered outside).
-        cache:     packed (C, d) hot-row copies.
-        backing:   packed (N, d) full mega-table.
-
-    Returns:
-        (R, d) gathered (hot=1) or sum-pooled (hot>1) rows.
-    """
-    tier, row = _two_level(flat_rows, slots)
-    return mtl_gather_tiered(tier, row, (cache, backing), hot=hot, dim=dim,
-                             dtype=np.dtype(backing.dtype),
-                             interpret=interpret)
-
-
-def mtl_gather_two_level_q8(flat_rows: jax.Array, slots: jax.Array,
-                            cache: jax.Array, cache_scale: jax.Array,
-                            backing: jax.Array, backing_scale: jax.Array, *,
-                            dim: int, hot: int = 1, interpret: bool = False
-                            ) -> jax.Array:
-    """Quantized two-level gather with in-kernel dequantization.
-
-    The int8 variant of :func:`mtl_gather_two_level`: both tiers hold int8
-    rows (packed as int32 words) plus an ``(N, 1)`` fp32 scale column;
-    each slot's scale is picked from the same tier as its row, and the
-    body dequantizes (``q.astype(f32) * scale``) before the pooled
-    accumulate, so multi-hot pooling happens in fp32.
-
-    Args:
-        flat_rows:     (R*hot,) int32 global rows into ``backing``.
-        slots:         (R*hot,) int32 cache slot per row, -1 = not cached.
-        cache:         packed (C, d) int8 hot-row copies.
-        cache_scale:   (C, 1) fp32 per-row scales of the cache tier.
-        backing:       packed (N, d) int8 full mega-table.
-        backing_scale: (N, 1) fp32 per-row scales of the backing tier.
-
-    Returns:
-        (R, d) float32 dequantized (hot=1) or sum-pooled (hot>1) rows.
-    """
-    tier, row = _two_level(flat_rows, slots)
-    scale = _pick_scale((cache_scale, backing_scale), tier, row)
-    return mtl_gather_tiered(tier, row, (cache, backing), scale, hot=hot,
-                             dim=dim, dtype=np.dtype(np.int8),
-                             interpret=interpret)
-
-
-def _three_level(cslots, sslots):
-    cache_hit = cslots >= 0
-    tier = jnp.where(cache_hit, 0, jnp.where(sslots >= 0, 1, -1))
-    return tier, jnp.where(cache_hit, cslots, jnp.maximum(sslots, 0))
-
-
-def mtl_gather_three_level(cslots: jax.Array, sslots: jax.Array,
-                           cache: jax.Array, staging: jax.Array, *, dim: int,
-                           hot: int = 1, interpret: bool = False
-                           ) -> jax.Array:
-    """Three-level gather: cache hits from the hot-row cache, staged misses
-    from the per-batch staging buffer, anything else zero (the guard),
-    pooled over ``hot`` ids per output row.
-
-    The out-of-HBM variant of :func:`mtl_gather_two_level`: the backing
-    table lives in *host* memory and never appears as an operand — the
-    host-side prefetch pipeline copies each batch's miss rows into
-    ``staging`` before the call. Zero-guarded slots issue no DMA at all.
-
-    Args:
-        cslots:  (R*hot,) int32 cache slot per row, -1 = not cached.
-        sslots:  (R*hot,) int32 staging slot per row, -1 = not staged.
-        cache:   packed (C, d) hot-row copies.
-        staging: packed (S, d) this batch's staged miss rows.
-
-    Returns:
-        (R, d) gathered (hot=1) or sum-pooled (hot>1) rows.
-    """
-    tier, row = _three_level(cslots, sslots)
-    return mtl_gather_tiered(tier, row, (cache, staging), hot=hot, dim=dim,
-                             dtype=np.dtype(cache.dtype),
-                             interpret=interpret)
-
-
-def mtl_gather_three_level_q8(cslots: jax.Array, sslots: jax.Array,
-                              cache: jax.Array, cache_scale: jax.Array,
-                              staging: jax.Array, staging_scale: jax.Array,
-                              *, dim: int, hot: int = 1,
-                              interpret: bool = False) -> jax.Array:
-    """Quantized three-level gather with in-kernel dequantization.
-
-    The int8 variant of :func:`mtl_gather_three_level`: cache and staging
-    hold packed int8 rows with ``(·, 1)`` fp32 scale columns, so the
-    host→device staging path and the device gather both move ``d + 4``
-    bytes per row. Rows in neither tier keep the zero-guard.
-
-    Args:
-        cslots:        (R*hot,) int32 cache slot per row, -1 = not cached.
-        sslots:        (R*hot,) int32 staging slot per row, -1 = not staged.
-        cache:         packed (C, d) int8 hot-row copies.
-        cache_scale:   (C, 1) fp32 per-row scales of the cache tier.
-        staging:       packed (S, d) int8 staged miss rows.
-        staging_scale: (S, 1) fp32 per-row scales of the staging tier.
-
-    Returns:
-        (R, d) float32 dequantized (hot=1) or sum-pooled (hot>1) rows.
-    """
-    tier, row = _three_level(cslots, sslots)
-    scale = _pick_scale((cache_scale, staging_scale), tier, row)
-    return mtl_gather_tiered(tier, row, (cache, staging), scale, hot=hot,
-                             dim=dim, dtype=np.dtype(np.int8),
-                             interpret=interpret)
 
 
 # ---------------------------------------------------------------------------

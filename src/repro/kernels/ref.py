@@ -6,6 +6,8 @@ Each ``ref_*`` function is the ground truth the kernels are tested against
 ``multi_table_lookup_alg1`` is a *literal* transcription of the paper's
 Algorithm 1 (flat element-wise traversal of the output matrix) — O(b·k·d)
 scalar Python, used only at tiny sizes to anchor the vectorized oracles.
+``ref_tiered_gather`` is the one gather twin: ``ops.gather_tiers`` runs it
+in place of the Pallas gather wherever its strategy resolves to jnp.
 """
 
 from __future__ import annotations
@@ -46,25 +48,6 @@ def multi_table_lookup_alg1(ids: np.ndarray, tables: list[np.ndarray]) -> np.nda
     return out.reshape(b, row_width)
 
 
-def ref_multi_table_lookup(ids, mega_table, offsets, k: int):
-    """Vectorized oracle over the concatenated mega-table.
-
-    Args:
-        ids:        (b, k) per-field IDs (local to each table).
-        mega_table: (sum_i n_i, d) all k tables concatenated along rows.
-        offsets:    (k,) row offset of each table inside ``mega_table``.
-        k:          number of feature fields.
-
-    Returns:
-        (b, k*d) embedding output.
-    """
-    b = ids.shape[0]
-    d = mega_table.shape[1]
-    flat_rows = (ids + offsets[None, :]).reshape(-1)          # (b*k,) global rows
-    gathered = jnp.take(mega_table, flat_rows, axis=0)        # (b*k, d)
-    return gathered.reshape(b, k * d)
-
-
 def ref_serial_lookup(ids, tables):
     """The *baseline* the paper accelerates: k independent lookups + concat.
 
@@ -73,149 +56,6 @@ def ref_serial_lookup(ids, tables):
     """
     cols = [jnp.take(tables[i], ids[:, i], axis=0) for i in range(len(tables))]
     return jnp.concatenate(cols, axis=1)
-
-
-def ref_multi_hot_lookup(ids, weights, mega_table, offsets):
-    """Multi-hot (sequence-feature) oracle: weighted sum over the hot axis.
-
-    Args:
-        ids:        (b, k, h) per-field IDs, h = max hot count.
-        weights:    (b, k, h) 0/1 validity mask (or arbitrary pooling weights).
-        mega_table: (N, d).
-        offsets:    (k,).
-
-    Returns:
-        (b, k*d) pooled embedding output.
-    """
-    b, k, h = ids.shape
-    d = mega_table.shape[1]
-    rows = (ids + offsets[None, :, None]).reshape(-1)
-    gathered = jnp.take(mega_table, rows, axis=0).reshape(b, k, h, d)
-    pooled = jnp.sum(gathered * weights[..., None].astype(mega_table.dtype), axis=2)
-    return pooled.reshape(b, k * d)
-
-
-def ref_two_level_gather(flat_rows, slot_of_row, cache, backing):
-    """Two-level (cache + backing) gather oracle — the CachedStore lookup.
-
-    Hits read their row from ``cache``, misses fall through to ``backing``;
-    the not-taken tier is pinned to its row 0 (same address indirection the
-    Pallas kernel performs in its index maps). Because cache rows are
-    verbatim copies of backing rows, the result is *bitwise* equal to
-    ``jnp.take(backing, flat_rows)``.
-
-    Args:
-        flat_rows:   (R,) int32 global rows.
-        slot_of_row: (N,) int32 cache slot per global row, -1 = uncached.
-        cache:       (C, d) hot-row copies.
-        backing:     (N, d) full mega-table.
-
-    Returns:
-        (R, d) gathered rows.
-    """
-    slots = jnp.take(slot_of_row, flat_rows, axis=0)
-    hit = slots >= 0
-    from_cache = jnp.take(cache, jnp.maximum(slots, 0), axis=0)
-    from_backing = jnp.take(backing, jnp.where(hit, 0, flat_rows), axis=0)
-    return jnp.where(hit[:, None], from_cache, from_backing)
-
-
-def ref_three_level_gather(flat_rows, slot_of_row, staging_slot_of_row,
-                           cache, staging):
-    """Three-level (cache / staging / zero-guard) gather oracle — the
-    HostBackedStore lookup.
-
-    Unlike the two-level gather there is *no device-resident backing* to
-    fall through to: rows absent from both the cache and the per-batch
-    staging buffer gather **zero** (the guard). Correctness is the serve
-    path's contract — it stages every miss before the lookup — so on a
-    correctly staged batch the result is bitwise equal to gathering from
-    the host backing (cache and staging rows are verbatim copies).
-
-    Args:
-        flat_rows:           (R,) int32 global rows.
-        slot_of_row:         (N,) int32 cache slot per row, -1 = uncached.
-        staging_slot_of_row: (N,) int32 staging slot per row, -1 = unstaged.
-        cache:               (C, d) hot-row copies.
-        staging:             (S, d) this batch's staged miss rows.
-
-    Returns:
-        (R, d) gathered rows (zero where neither tier resolves).
-    """
-    cslots = jnp.take(slot_of_row, flat_rows, axis=0)
-    sslots = jnp.take(staging_slot_of_row, flat_rows, axis=0)
-    cache_hit = cslots >= 0
-    stage_hit = jnp.logical_and(~cache_hit, sslots >= 0)
-    from_cache = jnp.take(cache, jnp.maximum(cslots, 0), axis=0)
-    from_staging = jnp.take(staging, jnp.maximum(sslots, 0), axis=0)
-    out = jnp.where(cache_hit[:, None], from_cache,
-                    jnp.where(stage_hit[:, None], from_staging, 0))
-    return out.astype(cache.dtype)
-
-
-def ref_two_level_gather_q8(flat_rows, slot_of_row, cache, cache_scale,
-                            backing, backing_scale):
-    """Quantized two-level gather oracle — the int8 CachedStore lookup.
-
-    Mirrors ``mtl_gather_two_level_q8``'s arithmetic *exactly* (select the
-    int8 payload, select the fp32 scale via the same hit predicate, one
-    dequant multiply), so the kernel-vs-ref comparison is bitwise.
-
-    Args:
-        flat_rows:     (R,) int32 global rows.
-        slot_of_row:   (N,) int32 cache slot per global row, -1 = uncached.
-        cache:         (C, d) int8 hot-row copies.
-        cache_scale:   (C, 1) fp32 per-row scales.
-        backing:       (N, d) int8 full mega-table.
-        backing_scale: (N, 1) fp32 per-row scales.
-
-    Returns:
-        (R, d) float32 dequantized rows.
-    """
-    slots = jnp.take(slot_of_row, flat_rows, axis=0)
-    hit = slots >= 0
-    safe_slots = jnp.maximum(slots, 0)
-    miss_rows = jnp.where(hit, 0, flat_rows)
-    q = jnp.where(hit[:, None],
-                  jnp.take(cache, safe_slots, axis=0),
-                  jnp.take(backing, miss_rows, axis=0)).astype(jnp.float32)
-    s = jnp.where(hit[:, None],
-                  jnp.take(cache_scale, safe_slots, axis=0),
-                  jnp.take(backing_scale, miss_rows, axis=0))
-    return q * s
-
-
-def ref_three_level_gather_q8(flat_rows, slot_of_row, staging_slot_of_row,
-                              cache, cache_scale, staging, staging_scale):
-    """Quantized three-level gather oracle — the int8 HostBackedStore
-    lookup (zero-guard included: rows in neither tier select an int8
-    payload of 0, which dequantizes to exactly 0.0 under any scale).
-
-    Args:
-        flat_rows:           (R,) int32 global rows.
-        slot_of_row:         (N,) int32 cache slot per row, -1 = uncached.
-        staging_slot_of_row: (N,) int32 staging slot per row, -1 = unstaged.
-        cache:               (C, d) int8 hot-row copies.
-        cache_scale:         (C, 1) fp32 per-row scales.
-        staging:             (S, d) int8 staged miss rows.
-        staging_scale:       (S, 1) fp32 per-row scales.
-
-    Returns:
-        (R, d) float32 dequantized rows (zero where neither tier resolves).
-    """
-    cslots = jnp.take(slot_of_row, flat_rows, axis=0)
-    sslots = jnp.take(staging_slot_of_row, flat_rows, axis=0)
-    cache_hit = cslots >= 0
-    stage_hit = sslots >= 0
-    from_cache = jnp.take(cache, jnp.maximum(cslots, 0), axis=0)
-    from_staging = jnp.take(staging, jnp.maximum(sslots, 0), axis=0)
-    q = jnp.where(cache_hit[:, None], from_cache,
-                  jnp.where(stage_hit[:, None], from_staging, 0)
-                  ).astype(jnp.float32)
-    s = jnp.where(cache_hit[:, None],
-                  jnp.take(cache_scale, jnp.maximum(cslots, 0), axis=0),
-                  jnp.take(staging_scale, jnp.maximum(sslots, 0), axis=0))
-    return q * s
 
 
 def ref_tiered_gather(tier, row, tables, scale=None):

@@ -22,10 +22,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.data.synthetic import CRITEO
-from repro.kernels.multi_table_lookup import (
-    LANES, mtl_gather, mtl_gather_multihot, mtl_gather_three_level,
-    mtl_gather_three_level_q8, mtl_gather_two_level,
-    mtl_gather_two_level_q8, rows_per_line)
+from repro.embedding import (CachedStore, DenseStore, FusedEmbeddingSpec,
+                             HostBackedStore)
+from repro.kernels.multi_table_lookup import (LANES, mtl_gather_tiered,
+                                              rows_per_line)
+from repro.kernels import ops as kops
 from repro.kernels.dense_matmul import dmm_q8
 from repro.kernels.fused_cross import fused_cross_v1, fused_cross_v2
 from repro.kernels.fused_fm import fused_fm_second_order
@@ -53,9 +54,13 @@ def one_chip():
 
 
 def _compile_text(fn, one_chip, *shapes) -> str:
-    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
-            for s, dt in shapes]
-    return jax.jit(fn).lower(*args).compile().as_text()
+    """Compile ``fn`` for the described chip; each of ``shapes`` is a
+    ``(shape, dtype)`` pair or a dict of them (a store's params)."""
+    def arg(s):
+        if isinstance(s, dict):
+            return {k: arg(v) for k, v in s.items()}
+        return jax.ShapeDtypeStruct(s[0], s[1], sharding=one_chip)
+    return jax.jit(fn).lower(*map(arg, shapes)).compile().as_text()
 
 
 def _packed(rows: int, d: int, fmt: str):
@@ -64,6 +69,50 @@ def _packed(rows: int, d: int, fmt: str):
     rpl = rows_per_line(d, dtype)
     word = jnp.int32 if fmt == "int8" else jnp.float32
     return (math.ceil(rows / rpl), LANES), word
+
+
+def _store(kind: str, fmt: str, d: int, field_sizes=CRITEO.field_sizes):
+    """A store of ``kind`` and its param shapes at the serving sizes: the
+    full table, a 65,536-row cache and an 8,192-row staging buffer."""
+    spec = FusedEmbeddingSpec(field_sizes=tuple(field_sizes), dim=d,
+                              row_dtype="int8" if fmt == "int8" else None)
+    rows = sum(field_sizes) + 1
+    row_map = ((rows,), jnp.int32)
+    if kind == "dense":
+        return DenseStore(spec), {"mega_table": _packed(rows, d, fmt)}
+    if kind == "two_level":
+        store = CachedStore(spec, capacity=CACHE_ROWS)
+        params = {"cache": _packed(CACHE_ROWS, d, fmt),
+                  "backing": _packed(rows, d, fmt), "slot_of_row": row_map}
+        tiers = {"cache": CACHE_ROWS, "backing": rows}
+    else:
+        store = HostBackedStore(spec, capacity=CACHE_ROWS,
+                                staging_capacity=STAGING_ROWS)
+        params = {"cache": _packed(CACHE_ROWS, d, fmt),
+                  "staging": _packed(STAGING_ROWS, d, fmt),
+                  "slot_of_row": row_map, "staging_slot_of_row": row_map}
+        tiers = {"cache": CACHE_ROWS, "staging": STAGING_ROWS}
+    if fmt == "int8":
+        params.update({f"{t}_scale": ((n, 1), jnp.float32)
+                       for t, n in tiers.items()})
+    return store, params
+
+
+def _lookup(store, offsets):
+    """The store's one-hot lookup, Pallas compiled for the chip."""
+    return lambda params, ids: store.lookup(
+        params, ids, offsets, strategy="pallas", interpret=False)
+
+
+def _pooled(store, hot):
+    """The store's route into the pooled gather, ``hot`` slots a row."""
+    def fn(params, rows):
+        tier, row, tables, scale = store._route(
+            params, rows, rows, jnp.ones(rows.shape, bool), True)
+        return mtl_gather_tiered(tier, row, tables, scale, hot=hot,
+                                 dim=store.spec.dim, dtype=store.wire_dtype,
+                                 interpret=False)
+    return fn
 
 
 GATHERS = [(tier, fmt, hot)
@@ -76,36 +125,22 @@ GATHERS = [(tier, fmt, hot)
 @pytest.mark.parametrize("d", [16, 32])
 @pytest.mark.parametrize("tier,fmt,hot", GATHERS)
 def test_gather_compiles_for_v5e(one_chip, tier, fmt, hot, d):
-    """dense = DenseStore (int8: the raw-row gather refreshes use);
-    two_level = CachedStore; three_level = HostBackedStore."""
-    r = B * K * hot
-    ids = ((r,), jnp.int32)
-    if tier == "dense":
-        dtype = np.int8 if fmt == "int8" else np.float32
-        table = _packed(ROWS, d, fmt)
-        if hot == 1:
-            fn = lambda rows, t: mtl_gather(rows, t, dim=d, dtype=dtype)
-        else:
-            fn = lambda rows, t: mtl_gather_multihot(rows, t, hot=hot,
-                                                         dim=d)
-        text = _compile_text(fn, one_chip, ids, table)
+    """dense = DenseStore (int8: the raw-row gather refreshes use,
+    ``gather_tiers`` over one tier); two_level = CachedStore;
+    three_level = HostBackedStore. One-hot: the store's lookup; pooled:
+    its route into ``mtl_gather_tiered(hot=3)``."""
+    store, params = _store(tier, fmt, d)
+    if tier == "dense" and fmt == "int8":
+        fn = lambda p, rows: kops.gather_tiers(
+            jnp.zeros_like(rows), rows, (p["mega_table"],), dim=d,
+            dtype=np.int8, strategy="pallas", interpret=False)
+        text = _compile_text(fn, one_chip, params, ((B * K,), jnp.int32))
+    elif hot == 1:
+        text = _compile_text(_lookup(store, jnp.asarray(store.spec.offsets)),
+                             one_chip, params, ((B, K), jnp.int32))
     else:
-        cache = _packed(CACHE_ROWS, d, fmt)
-        other_rows = ROWS if tier == "two_level" else STAGING_ROWS
-        other = _packed(other_rows, d, fmt)
-        if fmt == "fp32":
-            kern = (mtl_gather_two_level if tier == "two_level"
-                    else mtl_gather_three_level)
-            fn = lambda a, b, c, t: kern(a, b, c, t, dim=d, hot=hot)
-            text = _compile_text(fn, one_chip, ids, ids, cache, other)
-        else:
-            kern = (mtl_gather_two_level_q8 if tier == "two_level"
-                    else mtl_gather_three_level_q8)
-            fn = lambda a, b, c, cs, t, ts: kern(a, b, c, cs, t, ts, dim=d,
-                                                 hot=hot)
-            text = _compile_text(
-                fn, one_chip, ids, ids, cache, ((CACHE_ROWS, 1), jnp.float32),
-                other, ((other_rows, 1), jnp.float32))
+        text = _compile_text(_pooled(store, hot), one_chip, params,
+                             ((B * K * hot,), jnp.int32))
     assert "tpu_custom_call" in text
 
 
@@ -113,15 +148,13 @@ def test_tiered_gather_keeps_its_kernel_name(one_chip):
     """The chip's trace names an op after its HLO instruction, and the
     benchmark finds the gather by ``mtl_gather_tiered``: the Pallas call's
     ``name`` keeps that name inside the op graph's named scopes."""
-    d = 32
-    ids = ((B * K,), jnp.int32)
+    store, params = _store("two_level", "fp32", 32)
+    lookup = _lookup(store, jnp.asarray(store.spec.offsets))
 
-    def lookup(a, b, c, t):
+    def scoped(p, ids):
         with jax.named_scope("emb_lookup"):
-            return mtl_gather_two_level(a, b, c, t, dim=d, hot=1)
-    text = _compile_text(lookup, one_chip, ids, ids,
-                         _packed(CACHE_ROWS, d, "fp32"),
-                         _packed(ROWS, d, "fp32"))
+            return lookup(p, ids)
+    text = _compile_text(scoped, one_chip, params, ((B, K), jnp.int32))
     assert re.search(r"%mtl_gather_tiered(\.\d+)? = \S+ custom-call\(",
                      text)
 
@@ -164,9 +197,8 @@ def test_cross_tail_compiles_past_one_block(one_chip, width):
 def test_slot_gather_compiles_at_dlrm_width(one_chip):
     """A 512-request DLRM-DCNv2 batch: 214 id slots a request, one d=128
     row each, through the cached store's two tiers of a 25.5M-row table."""
-    r, d, rows = 512 * 214, 128, 25_523_084
-    fn = lambda a, b, c, t: mtl_gather_two_level(a, b, c, t, dim=d, hot=1)
-    text = _compile_text(fn, one_chip, ((r,), jnp.int32), ((r,), jnp.int32),
-                         _packed(CACHE_ROWS, d, "fp32"),
-                         _packed(rows, d, "fp32"))
+    slots, rows = 214, 25_523_084
+    store, params = _store("two_level", "fp32", 128, field_sizes=(rows - 1,))
+    fn = _lookup(store, jnp.zeros((slots,), jnp.int32))
+    text = _compile_text(fn, one_chip, params, ((512, slots), jnp.int32))
     assert "tpu_custom_call" in text

@@ -11,6 +11,8 @@ serves end-to-end through ``InferenceEngine.submit`` with the backing
 never uploaded wholesale.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 import jax
@@ -38,14 +40,15 @@ def needs(n):
                "--xla_force_host_platform_device_count=8)")
 
 
-def make_pair(capacity=48, staging_capacity=256, backing_path=None):
+def make_pair(capacity=48, staging_capacity=256, backing_path=None,
+              spec=SPEC):
     """Dense and host-backed collections over the *same* table values."""
-    dense = FusedEmbeddingCollection(SPEC)
+    dense = FusedEmbeddingCollection(spec)
     params_d = dense.init(jax.random.PRNGKey(0))
-    store = HostBackedStore(SPEC, capacity=capacity,
+    store = HostBackedStore(spec, capacity=capacity,
                             staging_capacity=staging_capacity,
                             backing_path=backing_path)
-    hosted = FusedEmbeddingCollection(SPEC, store=store)
+    hosted = FusedEmbeddingCollection(spec, store=store)
     params_h = store.from_dense(params_d)
     return dense, params_d, hosted, params_h, store
 
@@ -100,8 +103,9 @@ def test_host_store_bit_exact_onehot(exponent):
 
 @pytest.mark.parametrize("exponent", [None, 1.3])
 def test_host_store_bit_exact_multihot(exponent):
-    dense, pd, hosted, ph, store = make_pair()
     h = 3
+    spec = dataclasses.replace(SPEC, hotness=(h,) * SPEC.k)
+    dense, pd, hosted, ph, store = make_pair(spec=spec)
     rng = np.random.default_rng(1)
     if exponent is None:
         ids = np.stack([rng.integers(0, s, size=(64, h))
@@ -110,18 +114,16 @@ def test_host_store_bit_exact_multihot(exponent):
         ids = np.stack([np.asarray(zipf_ids(jax.random.PRNGKey(t), 64,
                                             SPEC.field_sizes, exponent))
                         for t in range(h)], axis=-1)
-    ids = jnp.asarray(ids, dtype=jnp.int32)
-    mask = jnp.asarray(rng.integers(0, 2, size=ids.shape), jnp.float32)
-    ph = store.stage(ph, np.asarray(ids), np.asarray(mask))
-    want = np.asarray(dense.apply_multihot(pd, ids, mask, strategy="jnp"))
-    got = np.asarray(hosted.apply_multihot(ph, ids, mask, strategy="jnp"))
+    # each field's h ids side by side: the hotness spec's slot layout
+    ids = jnp.asarray(ids.reshape(64, -1), dtype=jnp.int32)
+    ph = store.stage(ph, np.asarray(ids))
+    want = np.asarray(dense.apply(pd, ids, strategy="jnp"))
+    got = np.asarray(hosted.apply(ph, ids, strategy="jnp"))
     np.testing.assert_array_equal(got, want)
-    want_pl = np.asarray(dense.apply_multihot(pd, ids[:8], mask[:8],
-                                              strategy="pallas",
-                                              interpret=True))
-    got_pl = np.asarray(hosted.apply_multihot(ph, ids[:8], mask[:8],
-                                              strategy="pallas",
-                                              interpret=True))
+    want_pl = np.asarray(dense.apply(pd, ids[:8], strategy="pallas",
+                                     interpret=True))
+    got_pl = np.asarray(hosted.apply(ph, ids[:8], strategy="pallas",
+                                     interpret=True))
     np.testing.assert_array_equal(got_pl, want_pl)
 
 

@@ -136,23 +136,22 @@ def test_quantized_store_within_grid_bound_of_dense(store_cls):
 
 def test_quantized_store_multihot_within_pooled_bound():
     h = 3
-    dense = FusedEmbeddingCollection(SPEC)
+    spec = dataclasses.replace(SPEC, hotness=(h,) * SPEC.k)
+    dense = FusedEmbeddingCollection(spec)
     pd = dense.init(jax.random.PRNGKey(0))
-    store = CachedStore(SPEC, capacity=48, row_dtype="int8")
-    coll = FusedEmbeddingCollection(SPEC, store=store)
+    store = CachedStore(spec, capacity=48, row_dtype="int8")
+    coll = FusedEmbeddingCollection(spec, store=store)
     pq = store.from_dense(pd)
     rng = np.random.default_rng(3)
-    ids = jnp.asarray(
-        np.stack([rng.integers(0, s, size=(32, h))
-                  for s in SPEC.field_sizes], axis=1), dtype=jnp.int32)
-    mask = jnp.asarray(rng.integers(0, 2, size=(32, SPEC.k, h)),
-                       dtype=jnp.float32)
-    want = np.asarray(dense.apply_multihot(pd, ids, mask, strategy="jnp"))
-    got = np.asarray(coll.apply_multihot(pq, ids, mask, strategy="jnp"))
+    ids = np.stack([rng.integers(0, s, size=(32, h))
+                    for s in SPEC.field_sizes], axis=1)     # (b, k, h)
+    # each field's h ids side by side: the hotness spec's slot layout
+    slots = jnp.asarray(ids.reshape(32, -1), dtype=jnp.int32)
+    want = np.asarray(dense.apply(pd, slots, strategy="jnp"))
+    got = np.asarray(coll.apply(pq, slots, strategy="jnp"))
     scale = np.asarray(quant.absmax_scale(np.asarray(dense.dense_view(pd))))
-    rows = np.asarray(ids) + np.asarray(SPEC.offsets)[None, :, None]
-    pooled = ((scale[rows][..., 0] * 0.5 + 1e-6)
-              * np.asarray(mask)).sum(axis=-1, keepdims=True)
+    rows = ids + np.asarray(SPEC.offsets)[None, :, None]
+    pooled = (scale[rows][..., 0] * 0.5 + 1e-6).sum(axis=-1, keepdims=True)
     err = np.abs(got - want).reshape(32, SPEC.k, SPEC.dim)
     assert np.all(err <= pooled + 1e-6)
 

@@ -9,6 +9,8 @@ placement regression — sharding is derived from the store's
 embedding params still shard correctly.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 import jax
@@ -20,7 +22,8 @@ from repro.core import compile_plan
 from repro.core.plan import _shard_params
 from repro.data.synthetic import CRITEO, synthetic_batch, zipf_ids
 from repro.embedding import (CachedStore, DenseStore,
-                             FusedEmbeddingCollection, FusedEmbeddingSpec)
+                             FusedEmbeddingCollection, FusedEmbeddingSpec,
+                             HostBackedStore)
 from repro.models.ctr import CTR_MODELS
 
 SPEC = FusedEmbeddingSpec(field_sizes=(60, 7, 350, 90), dim=8)
@@ -28,12 +31,12 @@ SCHEMA = CRITEO.scaled(2_000)
 SPEC_KW = dict(embed_dim=8, hidden=64, max_field=2_000)
 
 
-def make_pair(capacity=48):
+def make_pair(capacity=48, spec=SPEC):
     """Dense and cached collections over the *same* table values."""
-    dense = FusedEmbeddingCollection(SPEC)
+    dense = FusedEmbeddingCollection(spec)
     params_d = dense.init(jax.random.PRNGKey(0))
-    store = CachedStore(SPEC, capacity=capacity)
-    cached = FusedEmbeddingCollection(SPEC, store=store)
+    store = CachedStore(spec, capacity=capacity)
+    cached = FusedEmbeddingCollection(spec, store=store)
     params_c = store.from_dense(params_d)
     return dense, params_d, cached, params_c, store
 
@@ -66,8 +69,9 @@ def test_cached_store_bit_exact_onehot(exponent):
 
 @pytest.mark.parametrize("exponent", [None, 1.3])
 def test_cached_store_bit_exact_multihot(exponent):
-    dense, pd, cached, pc, _ = make_pair()
     h = 3
+    spec = dataclasses.replace(SPEC, hotness=(h,) * SPEC.k)
+    dense, pd, cached, pc, _ = make_pair(spec=spec)
     rng = np.random.default_rng(1)
     if exponent is None:
         ids = np.stack([rng.integers(0, s, size=(64, h))
@@ -76,18 +80,46 @@ def test_cached_store_bit_exact_multihot(exponent):
         ids = np.stack([np.asarray(zipf_ids(jax.random.PRNGKey(t), 64,
                                             SPEC.field_sizes, exponent))
                         for t in range(h)], axis=-1)
-    ids = jnp.asarray(ids, dtype=jnp.int32)
-    mask = jnp.asarray(rng.integers(0, 2, size=ids.shape), jnp.float32)
-    want = np.asarray(dense.apply_multihot(pd, ids, mask, strategy="jnp"))
-    got = np.asarray(cached.apply_multihot(pc, ids, mask, strategy="jnp"))
+    # each field's h ids side by side: the hotness spec's slot layout
+    ids = jnp.asarray(ids.reshape(64, -1), dtype=jnp.int32)
+    want = np.asarray(dense.apply(pd, ids, strategy="jnp"))
+    got = np.asarray(cached.apply(pc, ids, strategy="jnp"))
     np.testing.assert_array_equal(got, want)
-    want_pl = np.asarray(dense.apply_multihot(pd, ids[:8], mask[:8],
-                                              strategy="pallas",
-                                              interpret=True))
-    got_pl = np.asarray(cached.apply_multihot(pc, ids[:8], mask[:8],
-                                              strategy="pallas",
-                                              interpret=True))
+    want_pl = np.asarray(dense.apply(pd, ids[:8], strategy="pallas",
+                                     interpret=True))
+    got_pl = np.asarray(cached.apply(pc, ids[:8], strategy="pallas",
+                                     interpret=True))
     np.testing.assert_array_equal(got_pl, want_pl)
+
+
+@pytest.mark.parametrize("kind,row_dtype", [
+    ("dense", None), ("cached", None), ("cached", "int8"),
+    ("host", None), ("host", "int8")])
+def test_lookup_pallas_matches_jnp(kind, row_dtype):
+    """Each store's one lookup — its ``_route`` into ``gather_tiers`` —
+    reads the same rows whichever gather runs: the Pallas kernel
+    (interpret mode) equals the jnp twin bitwise for fp32 rows, and within
+    rtol 1e-6 for int8 rows (dequantized in the kernel)."""
+    params = DenseStore(SPEC).init(jax.random.PRNGKey(5))
+    store = {"dense": lambda: DenseStore(SPEC),
+             "cached": lambda: CachedStore(SPEC, capacity=48,
+                                           row_dtype=row_dtype),
+             "host": lambda: HostBackedStore(SPEC, capacity=48,
+                                             staging_capacity=256,
+                                             row_dtype=row_dtype)}[kind]()
+    params = store.adopt(params)
+    ids = traffic(batch=32, exponent=1.3)
+    if store.needs_staging:
+        params = store.stage(params, np.asarray(ids))
+    offsets = jnp.asarray(SPEC.offsets)
+    got = np.asarray(store.lookup(params, ids, offsets, strategy="pallas",
+                                  interpret=True))
+    want = np.asarray(store.lookup(params, ids, offsets, strategy="jnp"))
+    assert got.shape == (32, SPEC.k * SPEC.dim)
+    if row_dtype is None:
+        np.testing.assert_array_equal(got, want)
+    else:
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
 
 
 def test_cached_store_bit_exact_after_refresh():

@@ -1,7 +1,10 @@
 """Per-kernel allclose validation against the pure-jnp oracles.
 
 Sweeps shapes and dtypes per the deliverable: every Pallas kernel is executed
-in interpret mode (CPU) and compared against repro.kernels.ref.
+in interpret mode (CPU) and compared against repro.kernels.ref. Lookups are
+checked where they run: the one gather (``ops.gather_tiers`` /
+``mtl_gather_tiered``) against its twin ``ref.ref_tiered_gather``, and each
+store's routing through ``store.lookup`` over hand-built tiers.
 """
 
 import numpy as np
@@ -9,9 +12,11 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.embedding import (CachedStore, DenseStore, FusedEmbeddingSpec,
+                             HostBackedStore)
 from repro.kernels import ops, ref
 from repro.kernels.multi_table_lookup import (
-    mtl_gather,
+    mtl_gather_tiered,
     mtl_onehot,
     pack_rows,
     unpack_rows,
@@ -35,6 +40,58 @@ def make_ids(rng, sizes, b):
         dtype=jnp.int32)
 
 
+def plain_lookup(ids, mega, offsets):
+    """The plain reference: one take per slot off the logical table."""
+    rows = (ids + offsets[None, :]).reshape(-1)
+    return jnp.take(mega, rows, axis=0).reshape(ids.shape[0], -1)
+
+
+def plain_pooled(ids, mask, mega, offsets):
+    """Plain multi-hot reference: (b, k, h) ids, masked slots dropped,
+    each field's slots summed."""
+    b, k, h = ids.shape
+    rows = (ids + offsets[None, :, None]).reshape(-1)
+    vals = jnp.take(mega, rows, axis=0).reshape(b, k, h, -1)
+    return jnp.sum(vals * mask[..., None], axis=2).reshape(b, -1)
+
+
+def one_tier(ids, offsets):
+    """(tier, row) of a single-table gather of (b, k) ids."""
+    rows = (ids + offsets[None, :]).reshape(-1)
+    return jnp.zeros_like(rows), rows
+
+
+def make_store(kind, sizes, d, fmt="fp32", capacity=1):
+    """A store of ``kind`` over ``sizes``; the tests hand it its tiers."""
+    spec = FusedEmbeddingSpec(field_sizes=tuple(sizes), dim=d,
+                              row_dtype="int8" if fmt == "int8" else None)
+    if kind == "dense":
+        return DenseStore(spec)
+    cls = CachedStore if kind == "cached" else HostBackedStore
+    return cls(spec, capacity=capacity)
+
+
+def pooled_lookup(store, params, ids, mask, offsets, strategy):
+    """Multi-hot (b, k, h) ids through ``store``'s route into the pooled
+    gather: a masked slot becomes tier -1 (the zero guard) and each output
+    row sums its ``h`` slots — in ``mtl_gather_tiered(hot=h)`` for
+    "pallas", after ``ref_tiered_gather`` for "jnp"."""
+    b, k, h = ids.shape
+    d = store.spec.dim
+    rows = (ids + offsets[None, :, None]).reshape(-1)
+    tier, row, tables, scale = store._route(
+        params, rows, rows, jnp.ones(rows.shape, bool), True)
+    tier = jnp.where(mask.reshape(-1) > 0, tier, -1)
+    if strategy == "pallas":
+        out = mtl_gather_tiered(tier, row, tables, scale, hot=h, dim=d,
+                                dtype=store.wire_dtype, interpret=True)
+    else:
+        out = ref.ref_tiered_gather(
+            tier, row, tuple(store.unpack(t) for t in tables), scale)
+        out = jnp.sum(out.reshape(-1, h, d), axis=1)
+    return out.reshape(b, k * d)
+
+
 # ---------------------------------------------------------------------------
 # Algorithm 1 anchoring: literal paper pseudocode == vectorized oracle
 # ---------------------------------------------------------------------------
@@ -46,12 +103,13 @@ def test_alg1_literal_matches_vectorized():
     ids = make_ids(rng, sizes, b)
     lit = ref.multi_table_lookup_alg1(np.asarray(ids),
                                       [np.asarray(t) for t in tables])
-    vec = ref.ref_multi_table_lookup(ids, mega, offsets, len(sizes))
+    tier, rows = one_tier(ids, offsets)
+    vec = ref.ref_tiered_gather(tier, rows, (mega,)).reshape(b, -1)
     np.testing.assert_allclose(lit, vec, **TOL)
 
 
 # ---------------------------------------------------------------------------
-# mtl_gather (output-first, the paper's kernel) — shape × dtype sweep
+# the one gather (output-first, the paper's kernel) — shape × dtype sweep
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("d", [8, 16, 32, 128])
@@ -62,10 +120,10 @@ def test_mtl_gather_sweep(b, k, d, dtype):
     sizes = list(rng.integers(2, 50, size=k))
     _, mega, offsets = make_tables(rng, sizes, d, dtype)
     ids = make_ids(rng, sizes, b)
-    want = ref.ref_multi_table_lookup(ids, mega, offsets, k)
-    rows = (ids + offsets[None, :]).reshape(-1)
-    got = mtl_gather(rows, pack_rows(mega), dim=d, dtype=dtype,
-                     interpret=True).reshape(b, k * d)
+    tier, rows = one_tier(ids, offsets)
+    want = ref.ref_tiered_gather(tier, rows, (mega,))
+    got = mtl_gather_tiered(tier, rows, (pack_rows(mega),), hot=1, dim=d,
+                            dtype=np.dtype(dtype), interpret=True)
     tol = BF16_TOL if dtype == jnp.bfloat16 else TOL
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **tol)
@@ -77,15 +135,17 @@ def test_ops_dispatch_equivalence(strategy):
     sizes, d, b = [11, 3, 40, 8], 16, 24
     _, mega, offsets = make_tables(rng, sizes, d, jnp.float32)
     ids = make_ids(rng, sizes, b)
-    want = ref.ref_multi_table_lookup(ids, mega, offsets, len(sizes))
+    want = plain_lookup(ids, mega, offsets)
     if strategy == "serial":
         got = ops.multi_table_lookup_serial(ids, mega, offsets)
     elif strategy == "input_first":
         got = ops.multi_table_lookup_input_first(ids, mega, offsets,
                                                  interpret=True)
     else:
-        got = ops.multi_table_lookup(ids, pack_rows(mega), offsets, dim=d,
-                                     strategy=strategy, interpret=True)
+        tier, rows = one_tier(ids, offsets)
+        got = ops.gather_tiers(tier, rows, (pack_rows(mega),), dim=d,
+                               dtype=jnp.float32, strategy=strategy,
+                               interpret=True).reshape(b, -1)
     np.testing.assert_allclose(got, want, **TOL)
 
 
@@ -95,8 +155,10 @@ def test_input_first_matches_output_first():
     sizes, d, b = [9, 21], 8, 10
     _, mega, offsets = make_tables(rng, sizes, d, jnp.float32)
     ids = make_ids(rng, sizes, b)
-    a = ops.multi_table_lookup(ids, pack_rows(mega), offsets, dim=d,
-                               strategy="pallas", interpret=True)
+    tier, rows = one_tier(ids, offsets)
+    a = ops.gather_tiers(tier, rows, (pack_rows(mega),), dim=d,
+                         dtype=jnp.float32, strategy="pallas",
+                         interpret=True).reshape(b, -1)
     z = ops.multi_table_lookup_input_first(ids, mega, offsets,
                                            interpret=True)
     np.testing.assert_allclose(a, z, **TOL)
@@ -122,29 +184,32 @@ def test_mtl_onehot_sweep(d, n_pad, dtype):
 
 
 # ---------------------------------------------------------------------------
-# multi-hot pooling
+# multi-hot pooling (the gather's ``hot`` slots a row; masked slot = tier -1)
 # ---------------------------------------------------------------------------
+
+def _multihot_ids(rng, sizes, b, h):
+    ids = jnp.asarray(
+        np.stack([rng.integers(0, n, size=(b, h)) for n in sizes], axis=1),
+        dtype=jnp.int32)
+    mask = jnp.asarray(rng.integers(0, 2, size=ids.shape), dtype=jnp.float32)
+    return ids, mask
+
 
 @pytest.mark.parametrize("h", [1, 3, 5])
 def test_multihot(h):
     rng = np.random.default_rng(h)
     sizes, d, b = [13, 29, 6], 16, 12
-    k = len(sizes)
     _, mega, offsets = make_tables(rng, sizes, d, jnp.float32)
-    mega_z = jnp.concatenate([mega, jnp.zeros((1, d), jnp.float32)], axis=0)
-    ids = jnp.asarray(
-        np.stack([rng.integers(0, n, size=(b, h)) for n in sizes], axis=1),
-        dtype=jnp.int32)
-    mask = jnp.asarray(rng.integers(0, 2, size=(b, k, h)), dtype=jnp.float32)
-    want = ref.ref_multi_hot_lookup(ids, mask, mega_z, offsets)
-    got = ops.multi_table_lookup_multihot(ids, mask, pack_rows(mega_z),
-                                          offsets, dim=d, strategy="pallas",
-                                          interpret=True)
+    ids, mask = _multihot_ids(rng, sizes, b, h)
+    want = plain_pooled(ids, mask, mega, offsets)
+    store = make_store("dense", sizes, d)
+    got = pooled_lookup(store, {"mega_table": pack_rows(mega)}, ids, mask,
+                        offsets, "pallas")
     np.testing.assert_allclose(got, want, **TOL)
 
 
 # ---------------------------------------------------------------------------
-# two-level (cache + backing) gather
+# two-level (cache + backing): CachedStore's route
 # ---------------------------------------------------------------------------
 
 def _split_cache(rng, mega, capacity):
@@ -163,17 +228,17 @@ def test_two_level_gather_matches_dense(capacity):
     sizes, d, b = [13, 29, 6], 16, 24
     _, mega, offsets = make_tables(rng, sizes, d, jnp.float32)
     cache, slot_of_row = _split_cache(rng, mega, capacity)
-    ids = jnp.asarray(
-        np.stack([rng.integers(0, n, size=b) for n in sizes], axis=1),
-        dtype=jnp.int32)
-    mega, cache = pack_rows(mega), pack_rows(cache)
-    want = ops.multi_table_lookup(ids, mega, offsets, dim=d, strategy="jnp")
-    got = ops.multi_table_lookup_cached(ids, cache, mega, slot_of_row,
-                                        offsets, strategy="jnp", dim=d)
+    ids = make_ids(rng, sizes, b)
+    dense = {"mega_table": pack_rows(mega)}
+    cached = {"cache": pack_rows(cache), "backing": pack_rows(mega),
+              "slot_of_row": slot_of_row}
+    want = make_store("dense", sizes, d).lookup(dense, ids, offsets,
+                                                strategy="jnp")
+    store = make_store("cached", sizes, d, capacity=capacity)
+    got = store.lookup(cached, ids, offsets, strategy="jnp")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    got_pl = ops.multi_table_lookup_cached(ids, cache, mega, slot_of_row,
-                                           offsets, strategy="pallas",
-                                           interpret=True, dim=d)
+    got_pl = store.lookup(cached, ids, offsets, strategy="pallas",
+                          interpret=True)
     np.testing.assert_array_equal(np.asarray(got_pl), np.asarray(want))
 
 
@@ -181,32 +246,21 @@ def test_two_level_gather_matches_dense(capacity):
 def test_two_level_multihot_matches_dense(h):
     rng = np.random.default_rng(h)
     sizes, d, b = [13, 29, 6], 16, 12
-    k = len(sizes)
     _, mega, offsets = make_tables(rng, sizes, d, jnp.float32)
-    mega_z = jnp.concatenate([mega, jnp.zeros((1, d), jnp.float32)], axis=0)
-    cache, slot_of_row = _split_cache(rng, mega_z, 16)
-    ids = jnp.asarray(
-        np.stack([rng.integers(0, n, size=(b, h)) for n in sizes], axis=1),
-        dtype=jnp.int32)
-    mask = jnp.asarray(rng.integers(0, 2, size=(b, k, h)), dtype=jnp.float32)
-    mega_z, cache = pack_rows(mega_z), pack_rows(cache)
-    want_jnp = ops.multi_table_lookup_multihot(ids, mask, mega_z, offsets,
-                                               strategy="jnp", dim=d)
-    got_jnp = ops.multi_table_lookup_cached_multihot(
-        ids, mask, cache, mega_z, slot_of_row, offsets, strategy="jnp",
-        dim=d)
-    np.testing.assert_array_equal(np.asarray(got_jnp), np.asarray(want_jnp))
-    want_pl = ops.multi_table_lookup_multihot(ids, mask, mega_z, offsets,
-                                              strategy="pallas",
-                                              interpret=True, dim=d)
-    got_pl = ops.multi_table_lookup_cached_multihot(
-        ids, mask, cache, mega_z, slot_of_row, offsets, strategy="pallas",
-        interpret=True, dim=d)
-    np.testing.assert_array_equal(np.asarray(got_pl), np.asarray(want_pl))
+    cache, slot_of_row = _split_cache(rng, mega, 16)
+    ids, mask = _multihot_ids(rng, sizes, b, h)
+    dense = (make_store("dense", sizes, d), {"mega_table": pack_rows(mega)})
+    cached = (make_store("cached", sizes, d, capacity=16),
+              {"cache": pack_rows(cache), "backing": pack_rows(mega),
+               "slot_of_row": slot_of_row})
+    for strategy in ("jnp", "pallas"):
+        want = pooled_lookup(*dense, ids, mask, offsets, strategy)
+        got = pooled_lookup(*cached, ids, mask, offsets, strategy)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
-# three-level (cache / staging / zero-guard) gather
+# three-level (cache / staging / zero-guard): HostBackedStore's route
 # ---------------------------------------------------------------------------
 
 def _split_tiers(rng, mega, capacity, staged):
@@ -224,6 +278,11 @@ def _split_tiers(rng, mega, capacity, staged):
     return cache, staging, jnp.asarray(slot_of_row), jnp.asarray(smap)
 
 
+def _host_params(cache, staging, slot_of_row, smap):
+    return {"cache": pack_rows(cache), "staging": pack_rows(staging),
+            "slot_of_row": slot_of_row, "staging_slot_of_row": smap}
+
+
 @pytest.mark.parametrize("capacity", [1, 16, 40])
 def test_three_level_gather_matches_dense_when_fully_staged(capacity):
     """Every row in some tier -> bitwise equal to the dense gather (the
@@ -231,19 +290,17 @@ def test_three_level_gather_matches_dense_when_fully_staged(capacity):
     rng = np.random.default_rng(capacity)
     sizes, d, b = [13, 29, 6], 16, 24
     _, mega, offsets = make_tables(rng, sizes, d, jnp.float32)
-    cache, staging, slot_of_row, smap = _split_tiers(
-        rng, mega, capacity, mega.shape[0] - capacity)   # all rows covered
-    ids = jnp.asarray(
-        np.stack([rng.integers(0, n, size=b) for n in sizes], axis=1),
-        dtype=jnp.int32)
-    mega, cache, staging = (pack_rows(t) for t in (mega, cache, staging))
-    want = ops.multi_table_lookup(ids, mega, offsets, dim=d, strategy="jnp")
-    got = ops.multi_table_lookup_host(ids, cache, staging, slot_of_row,
-                                      smap, offsets, strategy="jnp", dim=d)
+    tiers = _split_tiers(rng, mega, capacity,
+                         mega.shape[0] - capacity)       # all rows covered
+    ids = make_ids(rng, sizes, b)
+    want = make_store("dense", sizes, d).lookup(
+        {"mega_table": pack_rows(mega)}, ids, offsets, strategy="jnp")
+    store = make_store("host", sizes, d, capacity=capacity)
+    params = _host_params(*tiers)
+    got = store.lookup(params, ids, offsets, strategy="jnp")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    got_pl = ops.multi_table_lookup_host(ids, cache, staging, slot_of_row,
-                                         smap, offsets, strategy="pallas",
-                                         interpret=True, dim=d)
+    got_pl = store.lookup(params, ids, offsets, strategy="pallas",
+                          interpret=True)
     np.testing.assert_array_equal(np.asarray(got_pl), np.asarray(want))
 
 
@@ -252,23 +309,20 @@ def test_three_level_gather_zero_guards_unresolved_rows():
     sizes, d, b = [13, 29, 6], 16, 24
     _, mega, offsets = make_tables(rng, sizes, d, jnp.float32)
     cache, staging, slot_of_row, smap = _split_tiers(rng, mega, 8, 8)
-    ids = jnp.asarray(
-        np.stack([rng.integers(0, n, size=b) for n in sizes], axis=1),
-        dtype=jnp.int32)
-    mega, cache, staging = (pack_rows(t) for t in (mega, cache, staging))
+    ids = make_ids(rng, sizes, b)
+    store = make_store("host", sizes, d, capacity=8)
+    params = _host_params(cache, staging, slot_of_row, smap)
     for strategy in ("jnp", "pallas"):
-        got = np.asarray(ops.multi_table_lookup_host(
-            ids, cache, staging, slot_of_row, smap, offsets,
-            strategy=strategy, interpret=True,
-            dim=d)).reshape(b, len(sizes), d)
+        got = np.asarray(store.lookup(
+            params, ids, offsets, strategy=strategy,
+            interpret=True)).reshape(b, len(sizes), d)
         rows = np.asarray(ids) + np.asarray(offsets)[None, :]
         unresolved = ((np.asarray(slot_of_row)[rows] < 0)
                       & (np.asarray(smap)[rows] < 0))
         assert unresolved.any()
         assert np.all(got[unresolved] == 0.0)
-        want = np.asarray(ops.multi_table_lookup(
-            ids, mega, offsets, dim=d,
-            strategy="jnp")).reshape(b, len(sizes), d)
+        want = np.asarray(plain_lookup(
+            ids, mega, offsets)).reshape(b, len(sizes), d)
         np.testing.assert_array_equal(got[~unresolved], want[~unresolved])
 
 
@@ -276,24 +330,15 @@ def test_three_level_gather_zero_guards_unresolved_rows():
 def test_three_level_multihot_matches_dense(h):
     rng = np.random.default_rng(h)
     sizes, d, b = [13, 29, 6], 16, 12
-    k = len(sizes)
     _, mega, offsets = make_tables(rng, sizes, d, jnp.float32)
-    mega_z = jnp.concatenate([mega, jnp.zeros((1, d), jnp.float32)], axis=0)
-    cache, staging, slot_of_row, smap = _split_tiers(
-        rng, mega_z, 16, mega_z.shape[0] - 16)           # all rows covered
-    ids = jnp.asarray(
-        np.stack([rng.integers(0, n, size=(b, h)) for n in sizes], axis=1),
-        dtype=jnp.int32)
-    mask = jnp.asarray(rng.integers(0, 2, size=(b, k, h)), dtype=jnp.float32)
-    mega_z, cache, staging = (pack_rows(t) for t in (mega_z, cache, staging))
+    tiers = _split_tiers(rng, mega, 16, mega.shape[0] - 16)  # all covered
+    ids, mask = _multihot_ids(rng, sizes, b, h)
+    dense = (make_store("dense", sizes, d), {"mega_table": pack_rows(mega)})
+    hosted = (make_store("host", sizes, d, capacity=16), _host_params(*tiers))
     # compare per strategy: jnp and pallas pool in different f32 orders
     for strategy in ("jnp", "pallas"):
-        want = ops.multi_table_lookup_multihot(ids, mask, mega_z, offsets,
-                                               strategy=strategy,
-                                               interpret=True, dim=d)
-        got = ops.multi_table_lookup_host_multihot(
-            ids, mask, cache, staging, slot_of_row, smap, offsets,
-            strategy=strategy, interpret=True, dim=d)
+        want = pooled_lookup(*dense, ids, mask, offsets, strategy)
+        got = pooled_lookup(*hosted, ids, mask, offsets, strategy)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -339,7 +384,7 @@ def test_fused_fm(b, k, d, dtype):
 
 
 # ---------------------------------------------------------------------------
-# quantized (int8 rows + per-row fp32 scale) gathers
+# quantized (int8 rows + per-row fp32 scale) stores
 # ---------------------------------------------------------------------------
 
 from repro import quant  # noqa: E402
@@ -347,16 +392,37 @@ from repro import quant  # noqa: E402
 
 def _q8_split_cache(rng, mega, capacity):
     """Quantize the mega-table once, carve a random hot set out of the
-    shared int8 grid (cache and backing hold verbatim copies + scales)."""
+    shared int8 grid (cache and backing hold verbatim copies + scales):
+    a quantized CachedStore's params."""
     q, scale = quant.quantize_rows(mega)
     n = mega.shape[0]
     hot = np.sort(rng.choice(n, size=capacity, replace=False))
     slot_of_row = np.full(n, -1, dtype=np.int32)
     slot_of_row[hot] = np.arange(capacity, dtype=np.int32)
-    cache = jnp.take(q, jnp.asarray(hot), axis=0)
-    cache_scale = jnp.take(scale, jnp.asarray(hot), axis=0)
-    return (pack_rows(q), scale, pack_rows(cache), cache_scale,
-            jnp.asarray(slot_of_row))
+    return {"backing": pack_rows(q), "backing_scale": scale,
+            "cache": pack_rows(jnp.take(q, jnp.asarray(hot), axis=0)),
+            "cache_scale": jnp.take(scale, jnp.asarray(hot), axis=0),
+            "slot_of_row": jnp.asarray(slot_of_row)}
+
+
+def _q8_split_tiers(rng, mega, capacity, staged):
+    """Quantized HostBackedStore params over disjoint cache + staging
+    tiers of one int8 grid, plus the (rows, 1) scales of that grid."""
+    q, scale = quant.quantize_rows(mega)
+    n = mega.shape[0]
+    pick = rng.choice(n, size=capacity + staged, replace=False)
+    hot, warm = np.sort(pick[:capacity]), np.sort(pick[capacity:])
+    slot_of_row = np.full(n, -1, dtype=np.int32)
+    slot_of_row[hot] = np.arange(capacity, dtype=np.int32)
+    smap = np.full(n, -1, dtype=np.int32)
+    smap[warm] = np.arange(staged, dtype=np.int32)
+    params = {"cache": pack_rows(jnp.take(q, jnp.asarray(hot), axis=0)),
+              "cache_scale": jnp.take(scale, jnp.asarray(hot), axis=0),
+              "staging": pack_rows(jnp.take(q, jnp.asarray(warm), axis=0)),
+              "staging_scale": jnp.take(scale, jnp.asarray(warm), axis=0),
+              "slot_of_row": jnp.asarray(slot_of_row),
+              "staging_slot_of_row": jnp.asarray(smap)}
+    return params, scale
 
 
 @pytest.mark.parametrize("capacity", [1, 16, 48])
@@ -366,17 +432,15 @@ def test_two_level_q8_round_trip_bound(capacity):
     rng = np.random.default_rng(capacity)
     sizes, d, b = [13, 29, 6], 16, 24
     _, mega, offsets = make_tables(rng, sizes, d, jnp.float32)
-    q, scale, cache, cscale, slot_of_row = _q8_split_cache(
-        rng, mega, capacity)
+    params = _q8_split_cache(rng, mega, capacity)
     ids = make_ids(rng, sizes, b)
-    want = np.asarray(ops.multi_table_lookup(
-        ids, pack_rows(mega), offsets, dim=d,
-        strategy="jnp")).reshape(b, len(sizes), d)
-    got = np.asarray(ops.multi_table_lookup_cached_q8(
-        ids, cache, cscale, q, scale, slot_of_row, offsets,
-        strategy="jnp", dim=d)).reshape(b, len(sizes), d)
+    want = np.asarray(plain_lookup(ids, mega, offsets)).reshape(
+        b, len(sizes), d)
+    store = make_store("cached", sizes, d, "int8", capacity)
+    got = np.asarray(store.lookup(params, ids, offsets,
+                                  strategy="jnp")).reshape(b, len(sizes), d)
     rows = np.asarray(ids) + np.asarray(offsets)[None, :]
-    bound = np.asarray(scale)[rows] * 0.5 + 1e-7      # (b, k, 1) per row
+    bound = np.asarray(params["backing_scale"])[rows] * 0.5 + 1e-7
     assert np.all(np.abs(got - want) <= bound)
 
 
@@ -387,42 +451,33 @@ def test_two_level_q8_kernel_matches_ref(capacity):
     rng = np.random.default_rng(capacity)
     sizes, d, b = [13, 29, 6], 16, 24
     _, mega, offsets = make_tables(rng, sizes, d, jnp.float32)
-    q, scale, cache, cscale, slot_of_row = _q8_split_cache(
-        rng, mega, capacity)
+    params = _q8_split_cache(rng, mega, capacity)
     ids = make_ids(rng, sizes, b)
-    got_jnp = ops.multi_table_lookup_cached_q8(
-        ids, cache, cscale, q, scale, slot_of_row, offsets, strategy="jnp",
-        dim=d)
-    got_pl = ops.multi_table_lookup_cached_q8(
-        ids, cache, cscale, q, scale, slot_of_row, offsets,
-        strategy="pallas", interpret=True, dim=d)
+    store = make_store("cached", sizes, d, "int8", capacity)
+    got_jnp = store.lookup(params, ids, offsets, strategy="jnp")
+    got_pl = store.lookup(params, ids, offsets, strategy="pallas",
+                          interpret=True)
     np.testing.assert_array_equal(np.asarray(got_pl), np.asarray(got_jnp))
 
 
 @pytest.mark.parametrize("h", [1, 3])
 def test_two_level_q8_multihot_pooled(h):
     """Pooled multi-hot: fp32 pooling after per-row dequant, masked slots
-    hit the zero row (int8 payload 0 -> exact 0.0), and the pooled error
-    stays within the sum of the contributing rows' half grid steps."""
+    read the zero guard (an exact 0.0), and the pooled error stays within
+    the sum of the contributing rows' half grid steps."""
     rng = np.random.default_rng(h)
     sizes, d, b = [13, 29, 6], 16, 12
     k = len(sizes)
     _, mega, offsets = make_tables(rng, sizes, d, jnp.float32)
-    mega_z = jnp.concatenate([mega, jnp.zeros((1, d), jnp.float32)], axis=0)
-    q, scale, cache, cscale, slot_of_row = _q8_split_cache(rng, mega_z, 16)
-    ids = jnp.asarray(
-        np.stack([rng.integers(0, n, size=(b, h)) for n in sizes], axis=1),
-        dtype=jnp.int32)
-    mask = jnp.asarray(rng.integers(0, 2, size=(b, k, h)), dtype=jnp.float32)
-    want = np.asarray(ops.multi_table_lookup_multihot(
-        ids, mask, pack_rows(mega_z), offsets, strategy="jnp",
-        dim=d)).reshape(b, k, d)
+    params = _q8_split_cache(rng, mega, 16)
+    ids, mask = _multihot_ids(rng, sizes, b, h)
+    want = np.asarray(plain_pooled(ids, mask, mega, offsets)).reshape(b, k, d)
+    store = make_store("cached", sizes, d, "int8", 16)
     for strategy in ("jnp", "pallas"):
-        got = np.asarray(ops.multi_table_lookup_cached_q8_multihot(
-            ids, mask, cache, cscale, q, scale, slot_of_row, offsets,
-            strategy=strategy, interpret=True, dim=d)).reshape(b, k, d)
+        got = np.asarray(pooled_lookup(store, params, ids, mask, offsets,
+                                       strategy)).reshape(b, k, d)
         rows = np.asarray(ids) + np.asarray(offsets)[None, :, None]
-        row_scale = np.asarray(scale)[rows][..., 0]    # (b, k, h)
+        row_scale = np.asarray(params["backing_scale"])[rows][..., 0]
         bound = ((row_scale * 0.5 + 1e-7)
                  * np.asarray(mask)).sum(axis=-1, keepdims=True)
         assert np.all(np.abs(got - want) <= bound + 1e-6)
@@ -435,29 +490,15 @@ def test_three_level_q8_staged_round_trip(capacity):
     rng = np.random.default_rng(capacity)
     sizes, d, b = [13, 29, 6], 16, 24
     _, mega, offsets = make_tables(rng, sizes, d, jnp.float32)
-    q, scale = quant.quantize_rows(mega)
-    n = mega.shape[0]
-    pick = rng.choice(n, size=n, replace=False)
-    hot, warm = np.sort(pick[:capacity]), np.sort(pick[capacity:])
-    slot_of_row = np.full(n, -1, dtype=np.int32)
-    slot_of_row[hot] = np.arange(capacity, dtype=np.int32)
-    smap = np.full(n, -1, dtype=np.int32)
-    smap[warm] = np.arange(n - capacity, dtype=np.int32)
-    cache = pack_rows(jnp.take(q, jnp.asarray(hot), axis=0))
-    cscale = jnp.take(scale, jnp.asarray(hot), axis=0)
-    staging = pack_rows(jnp.take(q, jnp.asarray(warm), axis=0))
-    sscale = jnp.take(scale, jnp.asarray(warm), axis=0)
+    params, scale = _q8_split_tiers(rng, mega, capacity,
+                                    mega.shape[0] - capacity)
     ids = make_ids(rng, sizes, b)
-    want = np.asarray(ops.multi_table_lookup(
-        ids, pack_rows(mega), offsets, dim=d,
-        strategy="jnp")).reshape(b, len(sizes), d)
-    got_jnp = ops.multi_table_lookup_host_q8(
-        ids, cache, cscale, staging, sscale, jnp.asarray(slot_of_row),
-        jnp.asarray(smap), offsets, strategy="jnp", dim=d)
-    got_pl = ops.multi_table_lookup_host_q8(
-        ids, cache, cscale, staging, sscale, jnp.asarray(slot_of_row),
-        jnp.asarray(smap), offsets, strategy="pallas", interpret=True,
-        dim=d)
+    want = np.asarray(plain_lookup(ids, mega, offsets)).reshape(
+        b, len(sizes), d)
+    store = make_store("host", sizes, d, "int8", capacity)
+    got_jnp = store.lookup(params, ids, offsets, strategy="jnp")
+    got_pl = store.lookup(params, ids, offsets, strategy="pallas",
+                          interpret=True)
     np.testing.assert_array_equal(np.asarray(got_pl), np.asarray(got_jnp))
     rows = np.asarray(ids) + np.asarray(offsets)[None, :]
     bound = np.asarray(scale)[rows] * 0.5 + 1e-7
@@ -469,26 +510,16 @@ def test_three_level_q8_zero_guards_unresolved_rows():
     rng = np.random.default_rng(0)
     sizes, d, b = [13, 29, 6], 16, 24
     _, mega, offsets = make_tables(rng, sizes, d, jnp.float32)
-    q, scale = quant.quantize_rows(mega)
-    n = mega.shape[0]
-    pick = rng.choice(n, size=16, replace=False)
-    hot, warm = np.sort(pick[:8]), np.sort(pick[8:])
-    slot_of_row = np.full(n, -1, dtype=np.int32)
-    slot_of_row[hot] = np.arange(8, dtype=np.int32)
-    smap = np.full(n, -1, dtype=np.int32)
-    smap[warm] = np.arange(8, dtype=np.int32)
-    cache = pack_rows(jnp.take(q, jnp.asarray(hot), axis=0))
-    cscale = jnp.take(scale, jnp.asarray(hot), axis=0)
-    staging = pack_rows(jnp.take(q, jnp.asarray(warm), axis=0))
-    sscale = jnp.take(scale, jnp.asarray(warm), axis=0)
+    params, _ = _q8_split_tiers(rng, mega, 8, 8)
     ids = make_ids(rng, sizes, b)
+    store = make_store("host", sizes, d, "int8", 8)
     for strategy in ("jnp", "pallas"):
-        got = np.asarray(ops.multi_table_lookup_host_q8(
-            ids, cache, cscale, staging, sscale, jnp.asarray(slot_of_row),
-            jnp.asarray(smap), offsets, strategy=strategy,
-            interpret=True, dim=d)).reshape(b, len(sizes), d)
+        got = np.asarray(store.lookup(
+            params, ids, offsets, strategy=strategy,
+            interpret=True)).reshape(b, len(sizes), d)
         rows = np.asarray(ids) + np.asarray(offsets)[None, :]
-        unresolved = (slot_of_row[rows] < 0) & (smap[rows] < 0)
+        unresolved = ((np.asarray(params["slot_of_row"])[rows] < 0)
+                      & (np.asarray(params["staging_slot_of_row"])[rows] < 0))
         assert unresolved.any()
         assert np.all(got[unresolved] == 0.0)
 
@@ -497,31 +528,12 @@ def test_three_level_q8_zero_guards_unresolved_rows():
 def test_three_level_q8_multihot_matches_jnp_twin(h):
     rng = np.random.default_rng(h)
     sizes, d, b = [13, 29, 6], 16, 12
-    k = len(sizes)
     _, mega, offsets = make_tables(rng, sizes, d, jnp.float32)
-    mega_z = jnp.concatenate([mega, jnp.zeros((1, d), jnp.float32)], axis=0)
-    q, scale = quant.quantize_rows(mega_z)
-    n = mega_z.shape[0]
-    pick = rng.choice(n, size=n, replace=False)
-    hot, warm = np.sort(pick[:16]), np.sort(pick[16:])
-    slot_of_row = np.full(n, -1, dtype=np.int32)
-    slot_of_row[hot] = np.arange(16, dtype=np.int32)
-    smap = np.full(n, -1, dtype=np.int32)
-    smap[warm] = np.arange(n - 16, dtype=np.int32)
-    cache = pack_rows(jnp.take(q, jnp.asarray(hot), axis=0))
-    cscale = jnp.take(scale, jnp.asarray(hot), axis=0)
-    staging = pack_rows(jnp.take(q, jnp.asarray(warm), axis=0))
-    sscale = jnp.take(scale, jnp.asarray(warm), axis=0)
-    ids = jnp.asarray(
-        np.stack([rng.integers(0, n_, size=(b, h)) for n_ in sizes], axis=1),
-        dtype=jnp.int32)
-    mask = jnp.asarray(rng.integers(0, 2, size=(b, k, h)), dtype=jnp.float32)
-    got_jnp = ops.multi_table_lookup_host_q8_multihot(
-        ids, mask, cache, cscale, staging, sscale, jnp.asarray(slot_of_row),
-        jnp.asarray(smap), offsets, strategy="jnp", dim=d)
-    got_pl = ops.multi_table_lookup_host_q8_multihot(
-        ids, mask, cache, cscale, staging, sscale, jnp.asarray(slot_of_row),
-        jnp.asarray(smap), offsets, strategy="pallas", interpret=True, dim=d)
+    params, _ = _q8_split_tiers(rng, mega, 16, mega.shape[0] - 16)
+    ids, mask = _multihot_ids(rng, sizes, b, h)
+    store = make_store("host", sizes, d, "int8", 16)
+    got_jnp = pooled_lookup(store, params, ids, mask, offsets, "jnp")
+    got_pl = pooled_lookup(store, params, ids, mask, offsets, "pallas")
     np.testing.assert_allclose(np.asarray(got_pl), np.asarray(got_jnp),
                                rtol=1e-6, atol=1e-6)
 
@@ -630,15 +642,13 @@ def test_set_rows_touches_only_the_given_rows():
 
 
 def _tiered_case(rng, d, fmt):
+    """Tables of three fields at a paper width, quantized for int8, with
+    disjoint random cache and staging sets over them: the params of each
+    store, the (b, k) ids and the plain expectation of every slot."""
     sizes = [300, 45, 700]
     n = sum(sizes) + 1
     table = (rng.normal(size=(n, d)) * 0.05).astype(np.float32)
     table[-1] = 0
-    scale = None
-    if fmt == "int8":
-        from repro import quant
-        q, scale = quant.quantize_rows(table)
-        table, scale = np.asarray(q), np.asarray(scale)
     offsets = jnp.asarray(np.cumsum([0] + sizes[:-1]), jnp.int32)
     pick = rng.choice(n, size=240, replace=False)
     hot, warm = pick[:96], pick[96:]
@@ -648,59 +658,66 @@ def _tiered_case(rng, d, fmt):
     smap[warm] = np.arange(warm.size)
     ids = jnp.asarray(np.stack([rng.integers(0, s, size=40) for s in sizes],
                                axis=1), jnp.int32)
-    return table, scale, offsets, hot, warm, cmap, smap, ids
+    pk = lambda a: pack_rows(jnp.asarray(a))
+    wire, values = table, table
+    cached = {"cache": pk(table[hot]), "backing": pk(table),
+              "slot_of_row": jnp.asarray(cmap)}
+    host = {"cache": pk(table[hot]), "staging": pk(table[warm]),
+            "slot_of_row": jnp.asarray(cmap),
+            "staging_slot_of_row": jnp.asarray(smap)}
+    if fmt == "int8":
+        q, scale = quant.quantize_rows(table)
+        wire, scale = np.asarray(q), np.asarray(scale)
+        values = wire.astype(np.float32) * scale
+        cached.update(cache=pk(wire[hot]), backing=pk(wire),
+                      cache_scale=jnp.asarray(scale[hot]),
+                      backing_scale=jnp.asarray(scale))
+        host.update(cache=pk(wire[hot]), staging=pk(wire[warm]),
+                    cache_scale=jnp.asarray(scale[hot]),
+                    staging_scale=jnp.asarray(scale[warm]))
+    params = {"dense": {"mega_table": pk(wire)}, "cached": cached,
+              "host": host}
+    rows = np.asarray(ids + offsets[None, :]).reshape(-1)
+    staged = (cmap[rows] >= 0) | (smap[rows] >= 0)
+    return sizes, offsets, ids, params, values, rows, staged
 
 
 @pytest.mark.parametrize("d", [16, 32])
 @pytest.mark.parametrize("fmt", ["fp32", "int8"])
 @pytest.mark.parametrize("tier", ["dense", "two_level", "three_level"])
 def test_gather_matches_ref_twin_bitwise(tier, fmt, d):
-    """Every production gather over *packed* tables (the store layout),
-    interpret mode, is bitwise equal to its jnp twin in ``kernels/ref.py``
-    at the paper's widths d ∈ {16, 32}."""
+    """Every store's route over *packed* tables (the store layout) feeds
+    ``gather_tiers``, whose Pallas kernel (interpret mode) is bitwise
+    equal to its jnp twin ``ref_tiered_gather`` and to the plain
+    expectation (int8: the dequantized grid; host: zero where no tier
+    holds the row) at the paper's widths d ∈ {16, 32}. The dense int8
+    case is the raw-row gather refreshes use (``gather_rows``)."""
     rng = np.random.default_rng(d + len(tier) + len(fmt))
-    table, scale, offsets, hot, warm, cmap, smap, ids = _tiered_case(
+    sizes, offsets, ids, params, values, rows, staged = _tiered_case(
         rng, d, fmt)
-    rows = (ids + offsets[None, :]).reshape(-1)
-    pk = lambda a: pack_rows(jnp.asarray(a))
-    if tier == "dense":
-        got = mtl_gather(rows, pk(table), dim=d, dtype=table.dtype,
-                         interpret=True)
-        want = jnp.take(jnp.asarray(table), rows, axis=0)
-    elif tier == "two_level" and fmt == "fp32":
-        got = ops.multi_table_lookup_cached(
-            ids, pk(table[hot]), pk(table), jnp.asarray(cmap), offsets,
-            strategy="pallas", interpret=True, dim=d)
-        want = ref.ref_two_level_gather(rows, jnp.asarray(cmap),
-                                        jnp.asarray(table[hot]),
-                                        jnp.asarray(table))
-    elif tier == "two_level":
-        got = ops.multi_table_lookup_cached_q8(
-            ids, pk(table[hot]), jnp.asarray(scale[hot]), pk(table),
-            jnp.asarray(scale), jnp.asarray(cmap), offsets,
-            strategy="pallas", interpret=True, dim=d)
-        want = ref.ref_two_level_gather_q8(
-            rows, jnp.asarray(cmap), jnp.asarray(table[hot]),
-            jnp.asarray(scale[hot]), jnp.asarray(table), jnp.asarray(scale))
-    elif fmt == "fp32":
-        got = ops.multi_table_lookup_host(
-            ids, pk(table[hot]), pk(table[warm]), jnp.asarray(cmap),
-            jnp.asarray(smap), offsets, strategy="pallas", interpret=True,
-            dim=d)
-        want = ref.ref_three_level_gather(
-            rows, jnp.asarray(cmap), jnp.asarray(smap),
-            jnp.asarray(table[hot]), jnp.asarray(table[warm]))
+    kind = {"dense": "dense", "two_level": "cached",
+            "three_level": "host"}[tier]
+    store = make_store(kind, sizes, d, fmt, capacity=96)
+    env = params[kind]
+    flat = jnp.asarray(rows)
+    tier_, row, tables, scale = store._route(
+        env, flat, flat, jnp.ones(flat.shape, bool), True)
+    got = ops.gather_tiers(tier_, row, tables, scale, dim=d,
+                           dtype=store.wire_dtype, strategy="pallas",
+                           interpret=True)
+    twin = ref.ref_tiered_gather(
+        tier_, row, tuple(store.unpack(t) for t in tables), scale)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(twin))
+    if kind == "dense":
+        want = np.asarray(store.unpack(env["mega_table"]))[rows]
     else:
-        got = ops.multi_table_lookup_host_q8(
-            ids, pk(table[hot]), jnp.asarray(scale[hot]), pk(table[warm]),
-            jnp.asarray(scale[warm]), jnp.asarray(cmap), jnp.asarray(smap),
-            offsets, strategy="pallas", interpret=True, dim=d)
-        want = ref.ref_three_level_gather_q8(
-            rows, jnp.asarray(cmap), jnp.asarray(smap),
-            jnp.asarray(table[hot]), jnp.asarray(scale[hot]),
-            jnp.asarray(table[warm]), jnp.asarray(scale[warm]))
-    np.testing.assert_array_equal(np.asarray(got).reshape(want.shape),
-                                  np.asarray(want))
+        want = values[rows]
+        if kind == "host":
+            want = np.where(staged[:, None], want, 0)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(
+        np.asarray(store.lookup(env, ids, offsets, strategy="pallas",
+                                interpret=True)).reshape(want.shape), want)
 
 
 @pytest.mark.parametrize("d", [16, 32])
@@ -708,46 +725,25 @@ def test_gather_matches_ref_twin_bitwise(tier, fmt, d):
                                       ("two_level", "fp32"),
                                       ("three_level", "fp32")])
 def test_pooled_gather_matches_ref_twin_bitwise(tier, fmt, d):
-    """The multi-hot (sum-pooled) fp32 gathers over packed tables equal
-    their jnp twins bitwise: both add a row's slots in slot order, and a
-    masked slot adds an exact zero. (The int8 forms differ from their
-    twins in the last bits in interpret mode on CPU: XLA's CPU backend
-    fuses the kernel's dequantizing multiply and the pooled add into one
-    multiply-add, rounded once. Their tests above compare at rtol 1e-6;
-    on a TPU they matched bitwise.)"""
+    """The multi-hot (sum-pooled) fp32 gather over each store's packed
+    tiers equals its jnp twin bitwise: both add a row's slots in slot
+    order, and a masked slot adds an exact zero. (The int8 forms differ
+    from their twins in the last bits in interpret mode on CPU: XLA's CPU
+    backend fuses the kernel's dequantizing multiply and the pooled add
+    into one multiply-add, rounded once. Their tests above compare at
+    rtol 1e-6; on a TPU they matched bitwise.)"""
     rng = np.random.default_rng(d + len(tier) + len(fmt))
-    table, scale, offsets, hot, warm, cmap, smap, _ = _tiered_case(
-        rng, d, fmt)
+    sizes, offsets, _, params, _, _, _ = _tiered_case(rng, d, fmt)
     h = 3
     ids = jnp.asarray(np.stack(
-        [rng.integers(0, s, size=(40, h)) for s in (300, 45, 700)], axis=1),
+        [rng.integers(0, s, size=(40, h)) for s in sizes], axis=1),
         jnp.int32)
     mask = jnp.asarray(rng.integers(0, 2, size=ids.shape), jnp.float32)
-    pk = lambda a: pack_rows(jnp.asarray(a))
-    cm, sm = jnp.asarray(cmap), jnp.asarray(smap)
-    if fmt == "int8":
-        cs, ss, bs = (jnp.asarray(scale[hot]), jnp.asarray(scale[warm]),
-                      jnp.asarray(scale))
-
-    def run(strategy):
-        kw = dict(strategy=strategy, interpret=True, dim=d)
-        if tier == "dense":
-            return ops.multi_table_lookup_multihot(ids, mask, pk(table),
-                                                   offsets, **kw)
-        if tier == "two_level" and fmt == "fp32":
-            return ops.multi_table_lookup_cached_multihot(
-                ids, mask, pk(table[hot]), pk(table), cm, offsets, **kw)
-        if tier == "two_level":
-            return ops.multi_table_lookup_cached_q8_multihot(
-                ids, mask, pk(table[hot]), cs, pk(table), bs, cm, offsets,
-                **kw)
-        if fmt == "fp32":
-            return ops.multi_table_lookup_host_multihot(
-                ids, mask, pk(table[hot]), pk(table[warm]), cm, sm, offsets,
-                **kw)
-        return ops.multi_table_lookup_host_q8_multihot(
-            ids, mask, pk(table[hot]), cs, pk(table[warm]), ss, cm, sm,
-            offsets, **kw)
-
-    np.testing.assert_array_equal(np.asarray(run("pallas")),
-                                  np.asarray(run("jnp")))
+    kind = {"dense": "dense", "two_level": "cached",
+            "three_level": "host"}[tier]
+    store = make_store(kind, sizes, d, fmt, capacity=96)
+    np.testing.assert_array_equal(
+        np.asarray(pooled_lookup(store, params[kind], ids, mask, offsets,
+                                 "pallas")),
+        np.asarray(pooled_lookup(store, params[kind], ids, mask, offsets,
+                                 "jnp")))
